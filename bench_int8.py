@@ -1,8 +1,8 @@
 """int8 decode at scale: does it pay at ~1B params?
 
-The round-2 lookahead probe found int8 neutral-to-slightly-slower at GPT-2
-small (124M): dequant overhead ~= weight-traffic savings
-(TPU_PROBES.log 2026-07-29T14:3xZ). The claim that it PAYS where decode is
+An earlier lookahead probe found int8 neutral-to-slightly-slower at GPT-2
+small (124M): dequant overhead ~= weight-traffic savings. The claim that it
+PAYS where decode is
 weight-bound — >=1B params — has never been measured. This harness builds a
 ~1.3B-param randomly-initialized GPT (weight TRAFFIC is what decode time
 measures; weight values are irrelevant), runs the continuous engine's
@@ -13,7 +13,6 @@ and records tokens/s and resident bytes for all three into
 (``quantized_bytes``) and the engine's ``kv_pool_stats()`` — the same
 numbers the serving telemetry gauges export.
 
-Run by tools/tpu_window.sh last (it is the battery's most expensive phase).
 CPU smoke uses the tiny config so the harness itself stays testable.
 """
 
@@ -22,25 +21,15 @@ import os
 import sys
 import time
 
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR", os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache")
-)
-
 TOTAL_BUDGET_S = float(os.getenv("UNIONML_INT8_BUDGET", "540"))
 
 
 def run():
-    from __graft_entry__ import _honor_cpu_request
-
-    _honor_cpu_request()
-
     import jax
 
-    try:
-        if jax.config.jax_compilation_cache_dir is None:
-            jax.config.update("jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"])
-    except Exception:  # graftlint: disable=swallowed-exception -- the compilation cache is an optimization, never a failure
-        pass
+    from unionml_tpu.utils import configure_compile_cache
+
+    configure_compile_cache()
 
     import jax.numpy as jnp
     import numpy as np

@@ -1,4 +1,4 @@
-"""Serving-latency microbench: resident-predictor p50/p99 (BASELINE.md metric 2).
+"""Serving-latency microbench: resident-predictor p50/p99.
 
 Three measurements, single-row requests each:
 
@@ -62,23 +62,15 @@ class _RetraceCounter:
             self.count += 1
 
     def __enter__(self) -> "_RetraceCounter":
-        try:
-            from jax._src import monitoring
-        except ImportError:  # jax moved the module: report None, never crash a bench
-            self._monitoring = None
-            self.count = None
-            return self
-        self._monitoring = monitoring
-        monitoring.register_event_duration_secs_listener(self._listener)
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(self._listener)
         return self
 
     def __exit__(self, *exc) -> None:
-        if self._monitoring is None:
-            return
-        try:
-            self._monitoring._unregister_event_duration_listener_by_callback(self._listener)
-        except Exception:  # listener API drift: a leaked counter only overcounts retraces
-            pass
+        import jax.monitoring
+
+        jax.monitoring.unregister_event_duration_listener(self._listener)
 
 
 def _build_mlp_model(name: str):
@@ -1180,7 +1172,7 @@ def bench_slo_mix(n_batch: int = 24, n_interactive: int = 8, num_slots: int = 4,
 def bench_chaos(n_requests: int = 8, max_new_tokens: int = 24, num_slots: int = 4,
                 mesh_devices: int = 0):
     """Chaos smoke: recovery latency + recovered-token parity under injected
-    engine failures (ISSUE 7's `tpu_window.sh` gate).
+    engine failures (ISSUE 7's gate).
 
     A flood of requests runs twice on identically-seeded engines: once clean,
     once with a ``FaultPlan`` that kills a decode dispatch mid-flood and NaNs
@@ -1717,7 +1709,7 @@ def main():
         default="SERVING_BENCH.json",
         help="artifact path; CPU runs divert to a _cpu-suffixed sibling "
         "(bench_util.resolve_artifact_path) so a local smoke run cannot overwrite "
-        "the committed TPU measurements BASELINE.md quotes",
+        "the committed TPU measurements",
     )
     args = parser.parse_args()
 

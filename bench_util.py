@@ -1,9 +1,8 @@
 """Side-effect-free helpers shared by the bench scripts.
 
 Deliberately free of module-level configuration: ``bench.py`` sets process-wide
-logging levels and a persistent XLA compile-cache env var at import, which the
-other bench scripts must NOT inherit just to reuse a path-policy function
-(a warm compile cache silently flatters first-request/warmup timings).
+logging levels at import, which the other bench scripts must NOT inherit just
+to reuse a path-policy function.
 """
 
 import os
@@ -14,8 +13,7 @@ def resolve_artifact_path(out_path: str, backend: str) -> str:
 
     One policy for every bench script: accelerator runs own the canonical
     artifact name; CPU smoke runs divert to a ``_cpu``-suffixed sibling
-    (gitignored) so host timings can never overwrite the TPU measurements
-    BASELINE.md quotes as the one source of truth.
+    (gitignored) so host timings can never overwrite a TPU measurement.
     """
     if backend != "cpu":
         return out_path

@@ -453,8 +453,8 @@ def test_tuning_overlay_non_dict_file_falls_through(tmp_path, monkeypatch):
     path.write_text("[]")
     monkeypatch.setenv("UNIONML_TUNING_OVERLAY", str(path))
     with _tuning_tables() as tuning:
-        tuning._apply_measured_overlay()  # falls through to the repo root overlay
-        # the repo-root TUNING_MEASURED.json still applies (it records xla verdicts)
+        tuning._apply_measured_overlay()  # falls through to the repo root (no overlay committed)
+        # the static table's verdict stands
         assert tuning.MEASURED_IMPL.get((128, 128, 64)) == "xla"
 
 

@@ -298,3 +298,23 @@ def test_cancel_pending_chunked_prefill_frees_slot(gpt):
     assert not engine.has_pending_prefill and engine.free_slots == [slot]
     # the freed slot serves the next request exactly
     assert engine.generate([3, 1, 4], 4) == solo(model, variables, [3, 1, 4], 4)
+
+
+def test_vocab_the_tensor_axis_does_not_divide_is_replicated():
+    """GPT-2's real vocabulary (50257 rows) does not divide over ``tensor=4``;
+    the spec table asks for it anyway (it sees axis names, not shapes). The
+    engine's placement replicates that dimension and serves the same tokens."""
+    import jax.numpy as jnp
+
+    from unionml_tpu.models.gpt import GPTConfig, GPTLMHeadModel, init_params
+
+    config = GPTConfig.tiny(vocab_size=509, dropout=0.0, dtype=jnp.float32, attention_impl="xla")
+    model, variables = GPTLMHeadModel(config), init_params(config, seq_len=8)
+    kw = dict(num_slots=2, max_len=32, prefill_buckets=(8,))
+    sharded = DecodeEngine(model, variables, mesh=_mesh({"data": 1, "tensor": 4}), **kw)
+    embedding = sharded._variables["params"]["wte"]["embedding"]
+    assert embedding.sharding.is_fully_replicated
+    qkv = sharded._variables["params"]["layer_0"]["qkv"]["kernel"]
+    assert not qkv.sharding.is_fully_replicated  # everything that divides still shards
+    prompt = [3, 1, 4, 1, 5]
+    assert sharded.generate(prompt, 5) == DecodeEngine(model, variables, **kw).generate(prompt, 5)

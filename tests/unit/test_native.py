@@ -217,78 +217,50 @@ def test_prefetch_deferred_release_python_fallback():
     loader.close()
 
 
-def _build_stale_lib(tmp_path):
-    """A cached .so from an 'older package version': prefetch.cpp only (no upk_*
-    symbols), mtime pushed past every source so the staleness check passes it."""
+def test_library_path_keys_on_source_bytes_not_mtime(tmp_path, monkeypatch):
+    """The build key is a hash of the sources: editing a byte moves the library
+    path (an old build can never be loaded for new sources), touching an mtime
+    does not (no rebuild, and no "newer file wins")."""
     import os
-    import subprocess
-    import time
+    import shutil
 
     import unionml_tpu.native as native_mod
 
-    home = tmp_path / "home"
-    lib_dir = home / "native"
-    lib_dir.mkdir(parents=True)
-    lib_path = lib_dir / "libunionml_prefetch.so"
+    sources = tuple(
+        shutil.copy(src, tmp_path / src.name) for src in native_mod._SOURCES
+    )
+    monkeypatch.setattr(native_mod, "_SOURCES", sources)
+    original = native_mod._library_path()
+    assert original.parent == native_mod.Path(native_mod.__file__).parent / "_build"
+
+    future = sources[0].stat().st_mtime + 3600
+    os.utime(sources[0], (future, future))
+    assert native_mod._library_path() == original
+
+    with open(sources[1], "ab") as fh:
+        fh.write(b"\n// edited\n")
+    assert native_mod._library_path() != original
+
+
+def test_library_missing_symbols_degrades_to_python(tmp_path, monkeypatch):
+    """A library at the keyed path that lacks a symbol (a corrupt or tampered
+    build) degrades to the Python paths — never an AttributeError at a caller."""
+    import subprocess
+
+    import unionml_tpu.native as native_mod
+
+    lib_path = tmp_path / "libunionml_native-test.so"
     subprocess.run(
         ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-         str(native_mod._SOURCES[0]), "-o", str(lib_path)],
+         str(native_mod._SOURCES[0]), "-o", str(lib_path)],  # prefetch.cpp only: no upk_*
         check=True, capture_output=True,
     )
-    future = time.time() + 3600
-    os.utime(lib_path, (future, future))
-    return home, lib_path
-
-
-def test_stale_library_missing_symbols_self_heals(tmp_path, monkeypatch):
-    """A cached .so from an older package version (no upk_pack) with a fresh
-    mtime is deleted and rebuilt ONCE from the current sources — the native
-    path comes back without anyone hand-deleting the cache."""
-    import unionml_tpu.native as native_mod
-
-    home, lib_path = _build_stale_lib(tmp_path)
-    monkeypatch.setenv("UNIONML_TPU_HOME", str(home))
+    monkeypatch.setattr(native_mod, "_library_path", lambda: lib_path)
     monkeypatch.setattr(native_mod, "_lib", None)
     monkeypatch.setattr(native_mod, "_build_failed", False)
     try:
-        lib = native_mod.load_native_library()
-        assert lib is not None and hasattr(lib, "upk_pack")  # healed, full symbol set
-        assert native_mod.native_available()
-        out = native_mod.pack_sequences_native(
-            np.arange(1, 5, dtype=np.int32), np.array([4], dtype=np.int64), 8, 0, 0
-        )
-        assert out is not None and out["input_ids"].shape == (1, 8)
-    finally:
-        monkeypatch.setattr(native_mod, "_lib", None)
-        monkeypatch.setattr(native_mod, "_build_failed", False)
-
-
-def test_stale_library_degrades_when_rebuild_stays_stale(tmp_path, monkeypatch):
-    """If the rebuild ALSO lacks the symbols (wedged toolchain/cache), one retry
-    then degrade to the Python paths — never an AttributeError, never a loop."""
-    import ctypes
-
-    import unionml_tpu.native as native_mod
-
-    home, lib_path = _build_stale_lib(tmp_path)
-    calls = {"n": 0}
-
-    def rebuild_stale(path):
-        # stands in for a wedged rebuild that keeps producing the old library
-        calls["n"] += 1
-        if not path.exists():
-            _build_stale_lib(tmp_path)
-        return ctypes.CDLL(str(path))
-
-    monkeypatch.setenv("UNIONML_TPU_HOME", str(home))
-    monkeypatch.setattr(native_mod, "_lib", None)
-    monkeypatch.setattr(native_mod, "_build_failed", False)
-    monkeypatch.setattr(native_mod, "_rebuild_and_load_fresh", rebuild_stale)
-    try:
-        assert native_mod.load_native_library() is None  # degraded, no AttributeError
-        assert calls["n"] == 1  # exactly one rebuild attempt, then give up
+        assert native_mod.load_native_library() is None
         assert not native_mod.native_available()
-        # the public packing entrypoint still works via the Python path
         from unionml_tpu.ops.packing import pack_sequences
 
         out = pack_sequences([np.arange(1, 5)], 8, impl="native")

@@ -165,7 +165,7 @@ def test_pick_impl_measured_and_default():
     """auto dispatch consults measured verdicts; unmeasured shapes use the default."""
     from unionml_tpu.ops.tuning import DEFAULT_TPU_IMPL, MEASURED_IMPL, pick_impl
 
-    assert pick_impl(128, 128, 64) == "xla"  # end-to-end arbiter, TPU_PROBES.log
+    assert pick_impl(128, 128, 64) == "xla"  # the committed verdict for the BERT-base shape
     for shape, impl in MEASURED_IMPL.items():
         assert pick_impl(*shape) == impl
     assert pick_impl(384, 384, 64) == DEFAULT_TPU_IMPL

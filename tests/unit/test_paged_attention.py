@@ -1,6 +1,6 @@
 """Fused paged-attention kernel: interpret-mode parity + int8 edge cases.
 
-Kernel level: the pallas arm (interpret mode on CPU) against the XLA gather
+Kernel level: the pallas arm (``interpret=True`` on CPU) against the XLA gather
 reference — random pools first, then the three int8 edge shapes the pool
 discipline actually produces: an EMPTY block (scale 0), a freshly RESCALED
 tail block after a monotone scale grow, and a SPLICED shared-prefix block
@@ -18,12 +18,14 @@ with telemetry on (zero host→device uploads, ISSUE-18 acceptance).
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from unionml_tpu.models import gpt
 from unionml_tpu.models.gpt import GPTLMHeadModel, _paged_append_quantized
 from unionml_tpu.ops.paged_attention import paged_attention, xla_paged_attention
 from unionml_tpu.parallel import make_mesh
@@ -59,7 +61,7 @@ def _q(seed, batch, S=1):
 def _both(q, k, v, table, base, ks=None, vs=None):
     args = dict(k_scale=ks, v_scale=vs, out_dtype=jnp.float32)
     ref = paged_attention(q, k, v, table, base, impl="xla", **args)
-    out = paged_attention(q, k, v, table, base, impl="pallas", **args)
+    out = paged_attention(q, k, v, table, base, impl="pallas", interpret=True, **args)
     return np.asarray(ref), np.asarray(out)
 
 
@@ -163,9 +165,23 @@ def test_impl_validation():
     with pytest.raises(ValueError, match="together"):
         paged_attention(_q(0, 1), k, v, jnp.zeros((1, 1), jnp.int32),
                         jnp.zeros((1,), jnp.int32), k_scale=ks)
+    # the kernel off a TPU is an error, never a silent interpreter run: only an
+    # explicit interpret=True (these tests) may execute it on the CPU
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        paged_attention(_q(0, 1), k, v, jnp.zeros((1, 1), jnp.int32),
+                        jnp.zeros((1,), jnp.int32), k_scale=ks, v_scale=vs, impl="pallas")
 
 
 # --------------------------------------------------------------- engine level
+
+
+@pytest.fixture(autouse=True)
+def interpret_kernel(monkeypatch):
+    """The model calls ``paged_attention`` with no ``interpret`` argument (the
+    serving path never derives it); these CPU tests bind it on."""
+    monkeypatch.setattr(
+        gpt, "paged_attention", functools.partial(paged_attention, interpret=True)
+    )
 
 
 ENGINE_KW = dict(

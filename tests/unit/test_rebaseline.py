@@ -1,6 +1,6 @@
 """tools/rebaseline.py guardrails: the bench-baseline ratchet must be safe unattended.
 
-The tool runs only during rare hardware windows (tools/tpu_window.sh), so every
+The tool runs only after a successful on-TPU bench run, so every
 branch is pinned here on CPU against a temp copy of bench.py: wrong-metric and
 CPU results refused, out-of-band values refused, within-2%/downward kept, real
 improvements rewritten atomically with mode preserved.
@@ -25,7 +25,6 @@ def workdir(tmp_path):
     shutil.copy(REPO / "tools" / "rebaseline.py", tmp_path / "tools" / "rebaseline.py")
     shutil.copy(REPO / "bench.py", tmp_path / "bench.py")
     os.chmod(tmp_path / "bench.py", 0o644)
-    (tmp_path / "TPU_PROBES.log").write_text("")
     return tmp_path
 
 
@@ -91,9 +90,7 @@ def test_ratchets_upward_and_preserves_file_integrity(workdir):
     ast.parse(bench.read_text())  # still valid python
     assert (os.stat(bench).st_mode & 0o777) == 0o644  # mode preserved through the swap
     assert not list(workdir.glob(".bench.py.*"))  # no stray temp files
-    assert f"rebaseline: BASELINE_EXAMPLES_PER_S {before:.1f} -> {target:.1f}" in (
-        (workdir / "TPU_PROBES.log").read_text()
-    )
+    assert f"rebaseline: BASELINE_EXAMPLES_PER_S {before:.1f} -> {target:.1f}" in proc.stderr
     # the ratchet composes: a second, slower "window" keeps the new baseline
     proc = run_tool(workdir, {"metric": "bert_base_finetune_throughput", "value": before, "mfu": 0.3})
     assert proc.returncode == 0

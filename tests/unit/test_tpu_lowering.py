@@ -1,8 +1,9 @@
 """TPU-platform lowering gate for the pallas kernels — runs on CPU.
 
-Round-2 precedent (TPU_PROBES.log 10:25Z): interpret-mode-correct pallas code
-failed MOSAIC LOWERING on first hardware contact (rank-1 SMEM block size 1) —
-a class of bug CPU interpret tests cannot see. ``jax.export`` with
+Interpret-mode-correct pallas code has failed MOSAIC LOWERING on first hardware
+contact twice (the flash kernel's rank-1 SMEM block of size 1; the paged
+kernel's (1, heads) scale blocks) — a class of bug CPU interpret tests cannot
+see. ``jax.export`` with
 ``platforms=["tpu"]`` runs the real pallas→Mosaic lowering (where that failure
 occurred) without needing a TPU device, so these tests catch lowering
 regressions in every CPU CI run. Every FLASH-KERNEL check asserts
@@ -12,9 +13,10 @@ unliftable configs and that exports fine too. The two PROGRAM-level checks
 differ deliberately: the headline train step asserts Mosaic-kernel
 presence/absence CONSISTENT with the measured dispatch verdict, and the
 sharded-parallelism programs (pure XLA collectives, no pallas) assert export
-success only. What none of these prove: Mosaic→machine-code compilation and
-runtime numerics, which remain hardware-gated (``bench_kernels.py`` on a live
-window).
+success only. The paged-attention cases go one step further where libtpu can
+describe a v5e host without one being attached: they run the Mosaic compiler
+itself, ahead of time. What none of these prove is runtime numerics, which
+``chip_smoke.py`` checks on the chip.
 """
 
 import jax
@@ -299,3 +301,135 @@ def test_tuned_block_tables_lower_for_tpu():
             )
 
         _assert_mosaic_lowered(packed_fwd, q, k, v, seg)
+
+
+# ------------------------------------------------------------ paged attention
+
+#: (heads, head_dim, block_size, table_width, batch): GPT-2 small as the server
+#: pages it (max_len 1024, 16-token blocks, 8 slots) and the unit tests' pool
+PAGED_SHAPES = {"gpt2_small": (12, 64, 16, 65, 8), "tiny": (2, 16, 4, 4, 3)}
+
+
+def _paged_case(shape, pool, mode):
+    """Abstract operands of one paged call: ``pool`` bf16|int8, ``mode``
+    decode (S=1) | chunk (batch 1, S=64 or 6) | verify (identity table over
+    batch*width local blocks, int8 codes carried as f32)."""
+    heads, head_dim, block_size, width, batch = PAGED_SHAPES[shape]
+    compute = jnp.bfloat16 if shape == "gpt2_small" else jnp.float32
+    blocks, seq, code_dtype = batch * (width - 1) + 1, 1, jnp.int8
+    if mode == "chunk":
+        batch, seq = 1, 64 if shape == "gpt2_small" else 6
+    elif mode == "verify":
+        blocks, code_dtype = batch * width, jnp.float32
+    quantized = pool == "int8"
+    leaf = jax.ShapeDtypeStruct(
+        (blocks, heads, block_size, head_dim), code_dtype if quantized else compute
+    )
+    args = [
+        jax.ShapeDtypeStruct((batch, heads, seq, head_dim), compute), leaf, leaf,
+        jax.ShapeDtypeStruct((batch, width), jnp.int32),
+        jax.ShapeDtypeStruct((batch,), jnp.int32),
+    ]
+    if quantized:
+        args += [jax.ShapeDtypeStruct((blocks, heads, 1, 1), jnp.float32)] * 2
+    return compute, args
+
+
+def _paged_fn(compute, mesh=None):
+    from unionml_tpu.ops.paged_attention import paged_attention
+
+    def fn(q, k, v, table, base, k_scale=None, v_scale=None):
+        return paged_attention(
+            q, k, v, table, base, k_scale=k_scale, v_scale=v_scale,
+            out_dtype=compute, impl="pallas", mesh=mesh,
+        )
+
+    return fn
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """``impl="pallas"`` refuses a non-TPU backend; these tests build the TPU
+    program on the CPU box, so the dispatcher is told it is on the chip."""
+    import importlib
+
+    # by module path: the ops package re-exports same-named FUNCTIONS over its submodules
+    module = importlib.import_module("unionml_tpu.ops.paged_attention")
+    monkeypatch.setattr(module, "on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("mode", ["decode", "chunk", "verify"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("shape", sorted(PAGED_SHAPES))
+def test_paged_attention_lowers_for_tpu(as_on_tpu, shape, pool, mode):
+    """The TPU-default decode kernel, every variant the engine traces. The int8
+    variants are the regression gate for the (1, heads) scale block spec the
+    Mosaic lowering refused."""
+    compute, args = _paged_case(shape, pool, mode)
+    _assert_mosaic_lowered(_paged_fn(compute), *args)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_attention_lowers_under_tensor_mesh(as_on_tpu, pool):
+    """The serving mesh's form of the call: the kernel shard_mapped over
+    ``tensor`` (heads local) still lowers to a Mosaic call."""
+    from unionml_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"data": 1, "tensor": 4}, devices=jax.devices()[:4])
+    compute, args = _paged_case("gpt2_small", pool, "decode")
+    _assert_mosaic_lowered(_paged_fn(compute, mesh=mesh), *args)
+
+
+@pytest.fixture(scope="module")
+def v5e_host():
+    """The four devices of a v5e 2x2 host as libtpu describes them — no chip
+    attached — so ``lower().compile()`` runs the real Mosaic and XLA:TPU
+    compilers on this CPU box. Skips where libtpu cannot describe one."""
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as env:
+        # libtpu reads these once, when it loads: no metadata server, no workers
+        env.setenv("TPU_SKIP_MDS_QUERY", "1")
+        env.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+        env.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+        try:
+            return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
+        except Exception as exc:  # no libtpu / refused topology: environment, not a defect
+            pytest.skip(f"libtpu cannot describe a v5e host here: {exc}")
+
+
+@pytest.mark.parametrize("mode", ["decode", "chunk", "verify"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, pool, mode):
+    """Lowering is the first gate; this is the second: Mosaic compiles every
+    GPT-2-small variant to machine code for a v5e."""
+    from jax.sharding import SingleDeviceSharding
+
+    compute, args = _paged_case("gpt2_small", pool, mode)
+    on_chip = SingleDeviceSharding(v5e_host[0])
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip) for a in args]
+    assert jax.jit(_paged_fn(compute)).lower(*args).compile() is not None
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_attention_partitions_over_tensor_mesh(as_on_tpu, v5e_host, pool):
+    """Under the serving mesh the kernel sits inside a multi-device jit on a
+    head-sharded pool. Bare, the partitioner refuses it ("Mosaic kernels cannot
+    be automatically partitioned"); under ``mesh=`` it is shard_mapped with
+    heads local, and the compiled four-chip program moves no pool bytes: no
+    all-gather anywhere in it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(v5e_host).reshape(1, 4), ("data", "tensor"))
+    by_head = NamedSharding(mesh, P(None, "tensor", None, None))
+    compute, args = _paged_case("gpt2_small", pool, "decode")
+    args = [
+        jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=by_head if len(a.shape) == 4 else NamedSharding(mesh, P())
+        )
+        for a in args
+    ]
+    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+        jax.jit(_paged_fn(compute)).lower(*args).compile()
+    compiled = jax.jit(_paged_fn(compute, mesh=mesh), out_shardings=by_head).lower(*args).compile()
+    assert "all-gather" not in compiled.as_text()

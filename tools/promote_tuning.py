@@ -1,7 +1,7 @@
 """Distill kernel-sweep artifacts into the TUNING_MEASURED.json dispatch overlay.
 
-Run by ``tools/tpu_window.sh`` after the sweeps so a live hardware window
-promotes its winners into the auto-dispatch tables
+Run after the kernel sweeps so a hardware run promotes its winners into the
+auto-dispatch tables
 (:mod:`unionml_tpu.ops.tuning` loads the overlay at import). Only
 ``timing_valid: true`` artifacts contribute — a CPU correctness sweep must
 never overwrite on-device verdicts.
@@ -19,7 +19,7 @@ import sys
 REPO = pathlib.Path(__file__).resolve().parents[1]
 MAX_PROMOTABLE_ERR = 0.25  # bf16 attention outputs: observed rounding is ~0.06
 #: pallas must beat XLA by >2% to displace the default: single-window timings
-#: carry noise at that scale (TPU_PROBES.log), and a tie must break toward the
+#: carry noise at that scale, and a tie must break toward the
 #: path the end-to-end arbiter validated
 TIE_MARGIN = 0.98
 

@@ -308,7 +308,12 @@ class LocalBackend:
         Jobs whose resource spec declares ``host_count > 1`` spawn one worker per host
         with ``jax.distributed`` coordination env (the local stand-in for a multi-host
         TPU slice, where each host runs the same entrypoint); host 0 owns outputs and
-        status.
+        status. The local multi-worker fleet is a CPU test path — on a machine
+        with one accelerator only one of its processes could hold the chip.
+
+        A worker that needs the chip can only take it if this (parent) process
+        never initialised a JAX backend: launching is pickling and file writes,
+        nothing here or in ``Model.remote_train`` touches a device.
         """
         host_count = int((execution.metadata.get("resources") or {}).get("host_count", 1) or 1)
         if host_count <= 1:

@@ -48,7 +48,9 @@ class LocalShellTransport:
     """Loopback transport: each "host" is a local subprocess.
 
     The command line, env plumbing, and store round-trip are byte-identical to the
-    SSH path — only the machine boundary is faked (VERDICT round-1 next-step #4).
+    SSH path — only the machine boundary is faked. With more than one host this
+    is a CPU test path: a chip belongs to one process at a time, so several
+    loopback workers on one machine cannot each take the same accelerator.
     """
 
     def __init__(self, host_count: int = 1, scratch: Optional[str] = None):

@@ -75,7 +75,9 @@ def run_execution(execution_dir: Path, module_file_override: str = None) -> int:
     """
     from unionml_tpu._logging import logger
     from unionml_tpu.tracker import load_tracked_instance
+    from unionml_tpu.utils import configure_compile_cache
 
+    configure_compile_cache()
     with (execution_dir / "meta.json").open() as f:
         raw = f.read()
     meta = json.loads(raw.decode() if isinstance(raw, bytes) else raw)

@@ -323,6 +323,9 @@ def serve(
         os.environ["UNIONML_MODEL_PATH"] = str(model_path)
     if replicas < 1:
         raise click.BadParameter("--replicas must be >= 1")
+    from unionml_tpu.utils import configure_compile_cache
+
+    configure_compile_cache()  # before the app module loads: it may compile at import
     model = _load_model(app)
     from unionml_tpu.serving import run_app, serving_app
 

@@ -46,7 +46,7 @@ class Resources:
 
 DEFAULT_RESOURCES = Resources(cpu="1", mem="1Gi")
 
-#: Single-host v5e-8 slice — the baseline data-parallel target (BASELINE.md).
+#: Single-host v5e-8 slice — the baseline data-parallel target.
 TPU_V5E_8 = Resources(cpu="8", mem="16Gi", accelerator="v5litepod-8", topology="2x4", host_count=1)
 
 #: Single v5e chip — serving target.

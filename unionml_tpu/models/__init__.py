@@ -2,7 +2,7 @@
 
 The reference owns no models (users bring sklearn/torch/keras callables); here the
 digits/MNIST/BERT baseline configs ship as compiled flax modules with train steps,
-shardings, and checkpointing (BASELINE.md configs 1-4).
+shardings, and checkpointing.
 """
 
 from unionml_tpu.models.bert import (

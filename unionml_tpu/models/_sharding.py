@@ -5,6 +5,7 @@ function; the path flattening / key normalization / tree reconstruction live her
 a fix for new jax key types lands once for every family.
 """
 
+import math
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -36,7 +37,23 @@ def place_by_specs(params: Any, mesh: Any, spec_tree: Any) -> Any:
     layout: parameters arrive as host (or single-device) arrays and are committed
     to the mesh in one transfer, so the resident executables compile against
     already-sharded weights instead of replicating them per call.
+
+    The spec tables are written from axis NAMES and never see a shape, so a
+    dimension its mesh axes do not divide (GPT-2's 50257-row vocabulary over
+    ``tensor=4``) is replicated here instead of failing the transfer.
     """
+    from jax.sharding import PartitionSpec
+
     from unionml_tpu.parallel.mesh import named_sharding_tree
 
+    def fit(leaf, spec):
+        def divides(dim, axes):
+            names = axes if isinstance(axes, tuple) else () if axes is None else (axes,)
+            return dim % math.prod(mesh.shape[name] for name in names) == 0
+
+        return PartitionSpec(
+            *(axes if divides(dim, axes) else None for dim, axes in zip(leaf.shape, tuple(spec)))
+        )
+
+    spec_tree = jax.tree_util.tree_map(fit, params, spec_tree)
     return jax.device_put(params, named_sharding_tree(mesh, spec_tree))

@@ -43,6 +43,10 @@ class GPTConfig:
     #: :mod:`unionml_tpu.ops.paged_attention`. "auto" = pallas on TPU
     #: (measured verdicts override per shape class), XLA elsewhere.
     paged_attn_impl: str = "auto"
+    #: the serving mesh, set by ``DecodeEngine(mesh=...)`` on its own copy of
+    #: the model: the paged kernel runs under ``shard_map`` over it, heads
+    #: local to each ``tensor`` shard (a Mosaic call cannot be auto-partitioned)
+    tp_mesh: Any = None
     #: mesh carrying a "sequence" axis for ring/ulysses attention
     sp_mesh: Any = None
     #: remat (jax.checkpoint) decoder blocks during TRAINING forwards: activations
@@ -154,7 +158,7 @@ def _paged_chunk_quantized(pool_q, pool_scale, table_row, position, vals):
     return pool_q.at[dst].set(new_q), pool_scale.at[dst].set(new_scale)
 
 
-def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="auto"):
+def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="auto", mesh=None):
     """(jit-traceable) Speculative verify: attention context for ``S`` chunk
     tokens per row over the row's paged prefix, WITHOUT writing the pool.
 
@@ -236,6 +240,7 @@ def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="
             ctx = paged_attention(
                 qj, flat(kc), flat(vc), local_table, pos,
                 k_scale=flat(ks), v_scale=flat(vs), out_dtype=out_dtype, impl=impl,
+                mesh=mesh,
             )
         else:
             kb, vb = state
@@ -244,6 +249,7 @@ def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="
             state = (kb, vb)
             ctx = paged_attention(
                 qj, flat(kb), flat(vb), local_table, pos, out_dtype=out_dtype, impl=impl,
+                mesh=mesh,
             )
         return state, ctx[:, :, 0, :]
 
@@ -394,7 +400,7 @@ class DecoderBlock(nn.Module):
                 # K/V stashed alongside the untouched pool leaves
                 context = _paged_verify_chunk(
                     cache, block_table, position, q, k, v, cfg.dtype,
-                    impl=cfg.paged_attn_impl,
+                    impl=cfg.paged_attn_impl, mesh=cfg.tp_mesh,
                 )
                 new_cache = {**cache, "ck": k, "cv": v}
             else:
@@ -458,7 +464,7 @@ class DecoderBlock(nn.Module):
                 context = paged_attention(
                     q, k_cache, v_cache, block_table, base,
                     k_scale=k_scale, v_scale=v_scale,
-                    out_dtype=cfg.dtype, impl=cfg.paged_attn_impl,
+                    out_dtype=cfg.dtype, impl=cfg.paged_attn_impl, mesh=cfg.tp_mesh,
                 )
                 new_cache = {"k": k_cache, "v": v_cache}
                 if quantized:

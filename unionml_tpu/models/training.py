@@ -26,7 +26,7 @@ from unionml_tpu.parallel.mesh import (
     batch_sharding,
     wrapped_row_indices,
 )
-from unionml_tpu.utils import hard_sync
+from unionml_tpu.utils import configure_compile_cache
 
 
 class TrainState(train_state.TrainState):
@@ -397,6 +397,7 @@ def fit(
     ``prefetch=True`` — silently skipping a requested conversion would be a
     correctness trap.
     """
+    configure_compile_cache()
     if step_fn is not None and grad_accum != 1:
         # silently ignoring a requested option is a correctness trap (same
         # stance as prefetch_convert below): accumulation belongs to the step
@@ -424,16 +425,15 @@ def fit(
             # copy=False feeds the loader's python-owned slot buffers straight to
             # device_put (zero host copies after the native gather) — safe ONLY for
             # real accelerators, where the transfer lands in separate device memory
-            # and hard_sync fences it (block_until_ready is not a real barrier on
-            # remote-TPU platforms — see utils.hard_sync). The CPU backend may ALIAS
-            # an aligned host array instead of copying, so slot recycling would
+            # and block_until_ready fences it. The CPU backend may ALIAS an
+            # aligned host array instead of copying, so slot recycling would
             # corrupt "transferred" batches — keep the host copy there.
             zero_copy = jax.default_backend() != "cpu"
 
             def transfers():
                 # deferred slot release lets batch N+1's host->device transfer fly
-                # while step N computes: the slot recycles only after hard_sync
-                # proves its transfer landed
+                # while step N computes: the slot recycles only after
+                # block_until_ready proves its transfer landed
                 for views, release in prefetch_loader.epoch(
                     rng=epoch_rng, copy=not zero_copy, defer_release=True
                 ):
@@ -450,13 +450,13 @@ def fit(
             for batch_and_release in transfers():
                 if pending is not None:
                     batch, release = pending
-                    hard_sync(batch)
+                    jax.block_until_ready(batch)
                     release()
                     yield batch
                 pending = batch_and_release
             if pending is not None:
                 batch, release = pending
-                hard_sync(batch)
+                jax.block_until_ready(batch)
                 release()
                 yield batch
             return
@@ -480,7 +480,7 @@ def fit(
     # compile outside the timed region so wall-clock measures steady-state steps
     first_batch = next(iter(batch_iterator(rng)))
     state, metrics = step_fn(state, first_batch)
-    float(metrics["loss"])  # host fetch = real barrier (see utils.hard_sync)
+    jax.block_until_ready(metrics)
     step += 1
 
     t0 = time.perf_counter()
@@ -502,7 +502,7 @@ def fit(
                 break
         if done:
             break
-    float(metrics["loss"])  # host fetch = real barrier for the timed region
+    jax.block_until_ready(metrics)  # the timed region ends when the last step has run
     wall = time.perf_counter() - t0
     if checkpointer is not None:
         checkpointer.flush()

@@ -1,16 +1,15 @@
 """Native runtime components (C++), consumed via ctypes.
 
 The shared library builds lazily on first use with the system toolchain (g++); when no
-compiler is available the callers fall back to the pure-Python path, so the framework
-never hard-depends on the native build.
+compiler is available (or the checkout is read-only) the callers fall back to the
+pure-Python path, so the framework never hard-depends on the native build.
 """
 
 import ctypes
+import hashlib
 import os
-import shutil
 import subprocess
 import threading
-import time
 from pathlib import Path
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -22,7 +21,6 @@ _SOURCES = (
     Path(__file__).parent / "prefetch.cpp",
     Path(__file__).parent / "pack.cpp",
 )
-_LIB_NAME = "libunionml_prefetch.so"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
@@ -36,60 +34,44 @@ _CONV_CODES = {
 }
 
 
-def _build_dir() -> Path:
-    return Path(os.getenv("UNIONML_TPU_HOME", Path.home() / ".unionml-tpu")) / "native"
+def _library_path() -> Path:
+    """Where the library built from the CURRENT sources lives: under the
+    checkout (``native/_build/``, git-ignored), named by a hash of the source
+    bytes. The name is the freshness check — a library built from other
+    sources, by another tree or an older version, has another name and is
+    never loaded, whatever its mtime."""
+    digest = hashlib.sha256()
+    for src in _SOURCES:
+        digest.update(src.read_bytes())
+    return Path(__file__).parent / "_build" / f"libunionml_native-{digest.hexdigest()[:16]}.so"
 
 
 def _compile(lib_path: Path) -> None:
     """Compile every native source into ``lib_path`` with the system toolchain."""
     lib_path.parent.mkdir(parents=True, exist_ok=True)
-    subprocess.run(
-        [
-            "g++",
-            "-O3",
-            "-shared",
-            "-fPIC",
-            "-pthread",
-            "-std=c++17",
-            *[str(src) for src in _SOURCES],
-            "-o",
-            str(lib_path),
-        ],
-        check=True,
-        capture_output=True,
-    )
-    logger.info("Built native prefetcher -> %s", lib_path)
-
-
-def _build_and_load(lib_path: Path) -> ctypes.CDLL:
-    """Compile (when stale/missing) and dlopen the native library.
-
-    Raises ``subprocess.CalledProcessError`` / ``OSError`` on toolchain or
-    loader failure — the caller decides the fallback policy.
-    """
-    newest_src = max(src.stat().st_mtime for src in _SOURCES)
-    if not lib_path.exists() or lib_path.stat().st_mtime < newest_src:
-        _compile(lib_path)
-    return ctypes.CDLL(str(lib_path))
-
-
-def _rebuild_and_load_fresh(lib_path: Path) -> ctypes.CDLL:
-    """Replace a bad cached library and dlopen the REBUILT code in this process.
-
-    The canonical path gets the fresh build (future processes load it normally),
-    but glibc dedupes ``dlopen`` by pathname — reopening ``lib_path`` here would
-    hand back the stale mapping we are replacing — so this process maps the
-    healed build through a unique alias (unlinked immediately; the mapping
-    outlives the name).
-    """
-    lib_path.unlink(missing_ok=True)
-    _compile(lib_path)
-    alias = lib_path.with_name(f"{lib_path.stem}.heal-{os.getpid()}-{time.monotonic_ns()}.so")
+    # build beside the target, then rename: the name alone says "complete and
+    # current", so a half-written file must never carry it
+    partial = lib_path.with_name(f"{lib_path.name}.{os.getpid()}.partial")
     try:
-        shutil.copy2(lib_path, alias)
-        return ctypes.CDLL(str(alias))
+        subprocess.run(
+            [
+                "g++",
+                "-O3",
+                "-shared",
+                "-fPIC",
+                "-pthread",
+                "-std=c++17",
+                *[str(src) for src in _SOURCES],
+                "-o",
+                str(partial),
+            ],
+            check=True,
+            capture_output=True,
+        )
+        os.replace(partial, lib_path)
     finally:
-        alias.unlink(missing_ok=True)
+        partial.unlink(missing_ok=True)
+    logger.info("Built native prefetcher -> %s", lib_path)
 
 
 def load_native_library() -> Optional[ctypes.CDLL]:
@@ -98,11 +80,14 @@ def load_native_library() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        lib_path = _build_dir() / _LIB_NAME
         try:
-            # graftlint: disable=lock-order -- the lock intentionally serializes the ONE-TIME g++ build: concurrent first callers must wait for the compile rather than race it; every later call returns the cached handle without blocking
-            lib = _build_and_load(lib_path)
-        except (subprocess.CalledProcessError, OSError, FileNotFoundError) as exc:
+            lib_path = _library_path()
+            if not lib_path.exists():
+                # graftlint: disable=lock-order -- the lock intentionally serializes the ONE-TIME g++ build: concurrent first callers must wait for the compile rather than race it; every later call returns the cached handle without blocking
+                _compile(lib_path)
+            lib = ctypes.CDLL(str(lib_path))
+            _bind_symbols(lib)
+        except (subprocess.CalledProcessError, OSError, AttributeError) as exc:
             detail = getattr(exc, "stderr", b"")
             logger.warning(
                 "Native prefetcher unavailable (%s %s); falling back to Python batching.",
@@ -111,39 +96,6 @@ def load_native_library() -> Optional[ctypes.CDLL]:
             )
             _build_failed = True
             return None
-
-        for attempt in (0, 1):
-            try:
-                _bind_symbols(lib)
-                break
-            except AttributeError as exc:
-                # a stale cached library from an older package version can lack
-                # newer symbols while carrying a fresher mtime than the sources
-                # (e.g. a reinstalled wheel). Self-heal: delete the cache and
-                # rebuild from the current sources ONCE before giving up.
-                if attempt == 0:
-                    logger.warning(
-                        "Native library at %s is missing symbols (%s); rebuilding from source.",
-                        lib_path,
-                        exc,
-                    )
-                    try:
-                        # graftlint: disable=lock-order -- same one-time-build serialization as above: the stale-cache self-heal rebuild must also complete before any caller proceeds
-                        lib = _rebuild_and_load_fresh(lib_path)
-                        continue
-                    except (subprocess.CalledProcessError, OSError, FileNotFoundError) as build_exc:
-                        logger.warning(
-                            "Native rebuild failed (%s); falling back to Python.", build_exc
-                        )
-                else:
-                    logger.warning(
-                        "Rebuilt native library still missing symbols (%s); falling back to "
-                        "Python. Delete %s to force another rebuild.",
-                        exc,
-                        lib_path,
-                    )
-                _build_failed = True
-                return None
         _lib = lib
         return _lib
 
@@ -296,7 +248,7 @@ class PrefetchLoader:
     Wraps a mapping of name -> contiguous host array; each epoch yields dict batches
     in shuffled order with gathering overlapped against the consumer's compute.
 
-    Round-2 hot-path upgrades (NEXT.md item 6):
+    Hot-path design:
 
     - Slot buffers are numpy arrays OWNED BY PYTHON; the C++ workers gather straight
       into them, so ``copy=False`` consumers hand the batch to ``jax.device_put``
@@ -401,7 +353,7 @@ class PrefetchLoader:
         consumer, including fully-async device transfers. ``copy=False`` yields the
         python-owned slot arrays themselves — ZERO host copies after the worker
         gather — which recycle after the generator resumes: the consumer must finish
-        reading (e.g. a ``hard_sync`` on the device transfer) inside the loop body.
+        reading (e.g. ``jax.block_until_ready`` on the device transfer) inside the loop body.
 
         ``defer_release=True`` yields ``(views, release)`` pairs instead: the slot
         is recycled only when ``release()`` is called, so a consumer may hold a

@@ -11,7 +11,7 @@
 // gather rows, and mark the slot ready. The consumer (`upf_next`) takes batches in
 // order and releases slots after the device transfer commits.
 //
-// Round-2 additions (NEXT.md item 6):
+// Hot-path additions:
 //  - slot buffers are owned by PYTHON (numpy arrays registered via `upf_set_buffers`),
 //    so the consumer hands the gathered batch straight to jax.device_put with no
 //    extra host copy; the slot is released only after the transfer commits.

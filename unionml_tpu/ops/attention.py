@@ -757,13 +757,8 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
 def on_tpu() -> bool:
-    """True only for genuine TPU devices (incl. remote-TPU plugin backends)."""
-    if jax.default_backend() == "tpu":
-        return True
-    try:
-        return "tpu" in jax.devices()[0].device_kind.lower()
-    except Exception:  # graftlint: disable=swallowed-exception -- backend without device_kind: "not a TPU" is the correct total answer
-        return False
+    """Whether JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"
 
 
 def attention(
@@ -780,9 +775,7 @@ def attention(
     """Dispatching attention entrypoint used by the model zoo.
 
     ``impl="auto"`` consults the measured per-shape verdicts
-    (:data:`unionml_tpu.ops.tuning.MEASURED_IMPL` — on v5e, XLA's fused attention
-    wins or ties the pallas kernel at every measured practical shape, confirmed
-    end-to-end by a 24% faster BERT-base train step; TPU_PROBES.log 2026-07-29).
+    (:data:`unionml_tpu.ops.tuning.MEASURED_IMPL` — "xla" at every listed shape).
     Dense ``mask`` arrays and non-TPU backends always take the XLA path;
     ``impl="pallas"`` forces the flash kernel with its tuned block sizes.
 

@@ -20,14 +20,18 @@ Two implementations behind one dispatcher (the ``ops/attention.py`` contract):
   block.
 - ``impl="xla"``: gather-dequant-attend, arithmetic-identical to the historical
   ``gather_table`` + ``xla_attention`` path (the reference the kernel is pinned
-  against, and the fallback off-TPU).
-- ``impl="auto"``: pallas on TPU, XLA elsewhere. Unlike the dense-attention
-  tables (where XLA's fused attention measured ahead), the paged default is
-  pallas: the XLA arm's dense dequant copy is a modeled ~4x HBM write+read the
-  kernel provably never issues (see :func:`fused_hbm_bytes` /
-  :func:`gather_hbm_bytes`), and a measured verdict per shape class
-  (:func:`unionml_tpu.ops.tuning.pick_paged_impl`, ``TUNING_MEASURED.json``)
-  overrides the default as windows land.
+  against, and what runs off-TPU).
+- ``impl="auto"``: on a TPU backend the verdict of
+  :func:`unionml_tpu.ops.tuning.pick_paged_impl` for the shape class (pallas
+  unless the table says otherwise), XLA on every other backend. The choice is
+  made once, from the backend and the shapes — there is no runtime fallback
+  between the arms: ``impl="pallas"`` off a TPU raises unless the caller asked
+  for the Pallas interpreter (``interpret=True``, a test argument).
+
+Under a device mesh the kernel runs inside ``shard_map`` with the pool's heads
+local to each ``tensor`` shard (``mesh=``): a Mosaic custom call is opaque to
+the SPMD partitioner, which refuses it ("Mosaic kernels cannot be
+automatically partitioned") wherever a multi-device ``jit`` meets it bare.
 
 Layout contract (matches ``init_block_pool``): pool leaves are
 ``(num_blocks, heads, block_size, head_dim)``; scales ``(num_blocks, heads, 1,
@@ -136,11 +140,10 @@ def _paged_kernel(
     k = k_ref[0]
     v = v_ref[0]
     if quantized:
-        # per-(block, head) scalar scales, shaped (1, gh) by the block spec
-        ks = k_scale_ref[0][:, None, None]
-        vs = v_scale_ref[0][:, None, None]
-        k = (k.astype(jnp.float32) * ks).astype(out_dtype)
-        v = (v.astype(jnp.float32) * vs).astype(out_dtype)
+        # per-(block, head) scales, (gh, 1, 1) by the block spec: they broadcast
+        # over the block's (bs, hd) tile with no in-kernel reshape
+        k = (k.astype(jnp.float32) * k_scale_ref[0]).astype(out_dtype)
+        v = (v.astype(jnp.float32) * v_scale_ref[0]).astype(out_dtype)
     k = k.astype(jnp.float32)  # (gh, bs, hd)
     v = v.astype(jnp.float32)
 
@@ -215,10 +218,13 @@ def _paged_forward(
     ]
     operands = [q, k, v]
     if quantized:
-        scale2 = lambda s: s.reshape(s.shape[0], heads)
-        in_specs.append(pl.BlockSpec((1, gh), lambda b, h, w, tbl, base: (tbl[b, w], h)))
-        in_specs.append(pl.BlockSpec((1, gh), lambda b, h, w, tbl, base: (tbl[b, w], h)))
-        operands.extend([scale2(k_scale), scale2(v_scale)])
+        # the scales keep the pool's own rank-4 (blocks, heads, 1, 1) layout: a
+        # (1, gh, 1, 1) block's last two dims equal the array's, which is the
+        # one sub-(8, 128) block shape the Mosaic lowering accepts (a (1, gh)
+        # block of a (blocks, heads) view is refused)
+        scale_spec = pl.BlockSpec((1, gh, 1, 1), lambda b, h, w, tbl, base: (tbl[b, w], h, 0, 0))
+        in_specs.extend([scale_spec, scale_spec])
+        operands.extend([k_scale, v_scale])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -269,6 +275,18 @@ def resolve_paged_impl(
     raise ValueError(f"Unknown paged attention impl {impl!r}; expected 'auto', 'pallas', or 'xla'")
 
 
+def _head_spec(mesh, heads: int):
+    """Spec of every rank-4 kernel operand under ``mesh``: heads on ``tensor``
+    when the axis divides them — the engine's pool layout
+    (:func:`unionml_tpu.models.gpt.kv_block_spec`) — else replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    from unionml_tpu.parallel.mesh import TENSOR_AXIS
+
+    size = int(mesh.shape.get(TENSOR_AXIS, 1))
+    return P(None, TENSOR_AXIS, None, None) if size > 1 and heads % size == 0 else P()
+
+
 def paged_attention(
     q: jax.Array,
     k: jax.Array,
@@ -279,7 +297,8 @@ def paged_attention(
     v_scale: Optional[jax.Array] = None,
     out_dtype=None,
     impl: str = "auto",
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
+    mesh=None,
 ) -> jax.Array:
     """Attend ``q`` over a row's paged KV through its block-table row.
 
@@ -300,10 +319,14 @@ def paged_attention(
         scales (int8 pools); ``None`` selects the full-precision variant.
     :param out_dtype: dequant target (the compute dtype); defaults to
         ``q.dtype``. Matches the XLA arm's value quantization exactly.
-    :param impl: ``"auto"`` (pallas on TPU, XLA elsewhere — measured verdicts
-        override per shape class), ``"pallas"``, or ``"xla"``.
-    :param interpret: force pallas interpret mode; ``None`` auto-selects it off
-        TPU, so CPU tests can pin ``impl="pallas"`` with no extra plumbing.
+    :param impl: ``"auto"`` (the shape class's verdict on TPU, XLA elsewhere),
+        ``"pallas"``, or ``"xla"``.
+    :param interpret: run the kernel under the Pallas interpreter — how CPU
+        tests exercise ``impl="pallas"``. Never derived from the backend:
+        without it the kernel off a TPU is an error, not a silent slow path.
+    :param mesh: the serving mesh when the call sits inside a multi-device
+        ``jit``: the kernel runs under ``shard_map`` with heads local to each
+        ``tensor`` shard (replicated when the axis does not divide them).
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
@@ -317,15 +340,37 @@ def paged_attention(
             q, k, v, block_table, base_positions,
             k_scale=k_scale, v_scale=v_scale, out_dtype=out_dtype,
         )
-    if interpret is None:
-        interpret = not on_tpu()
+    if not interpret and not on_tpu():
+        raise RuntimeError(
+            f"paged_attention(impl='pallas') needs a TPU backend, found "
+            f"{jax.default_backend()!r}; use impl='auto'/'xla', or interpret=True in tests"
+        )
     from unionml_tpu.ops.tuning import pick_paged_heads
 
     heads_per_step = pick_paged_heads(width, block_size, heads, head_dim)
-    return _paged_forward(
-        q, k, v, block_table, base_positions, k_scale, v_scale, out_dtype,
-        heads_per_step, interpret,
-    )
+
+    def kernel(q, k, v, block_table, base_positions, *scales):
+        k_scale, v_scale = scales or (None, None)
+        return _paged_forward(
+            q, k, v, block_table, base_positions, k_scale, v_scale, out_dtype,
+            heads_per_step, interpret,
+        )
+
+    operands = [q, k, v, block_table, jnp.asarray(base_positions, jnp.int32).reshape(batch)]
+    if k_scale is not None:
+        operands += [k_scale, v_scale]
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        from unionml_tpu.parallel._compat import shard_map
+
+        by_head = _head_spec(mesh, heads)
+        in_specs = (by_head, by_head, by_head, P(), P()) + (by_head,) * (len(operands) - 5)
+        # check_vma=False: pallas_call has no replication rule to check against
+        kernel = shard_map(
+            kernel, mesh=mesh, in_specs=in_specs, out_specs=by_head, check_vma=False
+        )
+    return kernel(*operands)
 
 
 def fused_hbm_bytes(

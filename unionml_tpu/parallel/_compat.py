@@ -1,30 +1,9 @@
-"""jax API compatibility shims for the parallelism engine.
+"""The one import point for ``shard_map``.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` (where its
-replication-check flag is ``check_rep``) to ``jax.shard_map`` (flag renamed
-``check_vma``). Every per-device program in this package routes through this
-one wrapper so the version probe lives in exactly one place.
+Every per-device program in this package routes through ``jax.shard_map``
+(replication check spelled ``check_vma``) via this module.
 """
-
-from typing import Any, Callable
 
 import jax
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(
-        f: Callable, *, mesh: Any, in_specs: Any, out_specs: Any, check_vma: bool = True
-    ) -> Callable:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-
-else:  # jax <= 0.4.x: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(
-        f: Callable, *, mesh: Any, in_specs: Any, out_specs: Any, check_vma: bool = True
-    ) -> Callable:
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
+shard_map = jax.shard_map

@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from unionml_tpu._logging import logger
+
 DATA_AXIS = "data"
 FSDP_AXIS = "fsdp"
 TENSOR_AXIS = "tensor"
@@ -80,8 +82,10 @@ def make_mesh(
     shape = spec.resolve_shape(len(devices))
     try:
         device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except (ValueError, AssertionError):
-        # non-TPU or irregular topologies: plain reshape is still a valid mesh
+    except (ValueError, AssertionError) as exc:
+        # non-TPU or irregular topologies: plain reshape is still a valid mesh,
+        # but its neighbours are no longer ICI neighbours — say so
+        logger.warning("make_mesh: no topology-aware layout for %s (%s); using device order", shape, exc)
         device_array = np.asarray(devices).reshape(shape)
     return Mesh(device_array, spec.axis_names)
 

@@ -236,7 +236,7 @@ def build_aiohttp_app(
     async def on_startup(app):
         load_model_artifact(model, remote=remote, app_version=app_version, model_version=model_version)
         if predictor is not None:
-            # graftlint: disable=async-blocking -- startup hook: the warmup compile+hard_sync runs before the server accepts any traffic, so blocking the (idle) loop here is the point
+            # graftlint: disable=async-blocking -- startup hook: the warmup compile+sync runs before the server accepts any traffic, so blocking the (idle) loop here is the point
             predictor.setup()
         if generator is not None:
             import inspect
@@ -860,4 +860,7 @@ def build_aiohttp_app(
 def run_app(app, host: str = "127.0.0.1", port: int = 8000) -> None:
     from aiohttp import web
 
+    from unionml_tpu.utils import configure_compile_cache
+
+    configure_compile_cache()  # the startup hook compiles the predictor / engine
     web.run_app(app, host=host, port=port)

@@ -107,6 +107,16 @@ def block_demand(prompt_len: int, budget: int, *, max_len: int, block_size: int)
     return -(-need // int(block_size))
 
 
+def bind_serving_mesh(model: Any, mesh: Optional[Any]) -> Any:
+    """A copy of ``model`` whose config carries the serving ``mesh`` — how the
+    paged attention kernel learns, at trace time, the mesh it must
+    ``shard_map`` over (see ``GPTConfig.tp_mesh``). The caller's model is left
+    alone; models whose config has no such field pass through."""
+    if mesh is None or not hasattr(model.config, "tp_mesh"):
+        return model
+    return model.clone(config=dataclasses.replace(model.config, tp_mesh=mesh))
+
+
 @dataclasses.dataclass(frozen=True)
 class StepEvent:
     """One slot's outcome for one engine step."""
@@ -284,6 +294,7 @@ class DecodeEngine:
     ) -> None:
         from unionml_tpu.models.gpt import init_cache
 
+        model = bind_serving_mesh(model, mesh)
         config = model.config
         max_len = max_len or config.max_position_embeddings
         if max_len > config.max_position_embeddings:
@@ -920,9 +931,11 @@ class DecodeEngine:
         (construction, :meth:`reset`, :meth:`abort_all`); per-admission changes
         go through the point-update path in :meth:`_activate` instead.
         """
-        self._temp_dev = jnp.asarray(self._slot_temp)
-        self._top_k_dev = jnp.asarray(self._slot_top_k)
-        self._top_p_dev = jnp.asarray(self._slot_top_p)
+        # under a mesh the mirrors live replicated on it, like every other piece
+        # of slot state (``_replicated`` is None without one: the default device)
+        self._temp_dev, self._top_k_dev, self._top_p_dev = jax.device_put(
+            (self._slot_temp, self._slot_top_k, self._slot_top_p), self._replicated
+        )
 
     def _sync_slot_mirrors(self) -> None:
         """Re-upload the device slot lifecycle (``active``/``remaining``) from
@@ -2578,7 +2591,7 @@ class DecodeEngine:
             # while the host blocks on (and then applies) the previous one
             events.extend(self._replay_burst(previous, prev_skip))
         if not self.pipeline:
-            events.extend(self._fetch_inflight())  # hard sync (see utils.hard_sync)
+            events.extend(self._fetch_inflight())  # blocks until the burst's tokens are on the host
         return events
 
     def abort_all(self) -> None:

@@ -58,7 +58,7 @@ def attach_fastapi(
     async def setup_model():
         load_model_artifact(model, remote=remote, app_version=app_version, model_version=model_version)
         if predictor is not None:
-            # graftlint: disable=async-blocking -- startup hook: the warmup compile+hard_sync runs before the server accepts any traffic, so blocking the (idle) loop here is the point
+            # graftlint: disable=async-blocking -- startup hook: the warmup compile+sync runs before the server accepts any traffic, so blocking the (idle) loop here is the point
             predictor.setup()
 
     @app.get("/", response_class=HTMLResponse)

@@ -4,7 +4,7 @@ Reference behavior: the FastAPI path routes every request through
 ``model.predict(features=...)`` interpreted Python (``unionml/fastapi.py:50-64``). The
 TPU-native rebuild pre-lowers and compiles the predictor at server startup for a ladder
 of padded batch shapes ("bucketing"), so the request path is: host->device transfer,
-run resident executable, device->host — the p50-latency metric in BASELINE.md.
+run resident executable, device->host — the p50-latency metric.
 
 Dynamic request sizes vs XLA static shapes (SURVEY.md §7 "hard parts"): request batches
 pad up to the nearest bucket; predictions slice back down. Two bucketing axes:
@@ -84,7 +84,7 @@ class ResidentPredictor:
         self._ready = False  # guarded-by: _setup_lock
         # per-request device-side latency (dispatch + device->host fetch), ms —
         # the server-side half of the device/HTTP latency split (VERDICT r3 #8):
-        # /stats quotes these so tunnel/client RTT never masquerades as model time.
+        # /stats quotes these so client/network RTT never masquerades as model time.
         # predict() appends from executor threads while /stats reads on the event
         # loop; the lock keeps the snapshot safe (deques error on mutation mid-iter)
         self._device_times_ms: deque = deque(maxlen=2048)
@@ -157,9 +157,7 @@ class ResidentPredictor:
                     "No warmup template (pass example_features to serve()); first request will compile."
                 )
                 return
-            from unionml_tpu.utils import hard_sync
-
-            hard_sync(self._compiled(self._device_model_object, example))
+            jax.block_until_ready(self._compiled(self._device_model_object, example))
             logger.info("Resident predictor warmed (bucket=%d).", self._buckets[0])
         except Exception as exc:
             # keep the compiled predictor: the synthetic example may simply have the

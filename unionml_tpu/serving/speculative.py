@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from unionml_tpu._logging import logger
-from unionml_tpu.serving.continuous import DecodeEngine
+from unionml_tpu.serving.continuous import DecodeEngine, bind_serving_mesh
 
 __all__ = ["SpeculativeBatcher", "SpeculativeEngine"]
 
@@ -130,6 +130,7 @@ class SpeculativeEngine(DecodeEngine):
                 f"< engine max_len ({eff_max_len})"
             )
         # everything _init_device_state (called inside super().__init__) reads
+        draft = bind_serving_mesh(draft, kwargs.get("mesh"))
         self._draft_model = draft
         self._draft_config = draft.config
         self._draft_cache_sharding = None
