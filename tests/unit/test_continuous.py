@@ -350,7 +350,7 @@ def test_stream_route_ndjson(gpt):
 
     lines = asyncio.run(main())
     assert [l["token"] for l in lines[:-1]] == expected
-    assert lines[-1] == {"done": True, "tokens": expected}
+    assert lines[-1] == {"done": True, "tokens": expected, "request_id": lines[-1]["request_id"]}
 
 
 def test_abandoned_stream_frees_slot_and_worker_survives(gpt):

@@ -312,15 +312,24 @@ def test_preempt_without_prefix_cache_raises(gpt):
 
 def test_queue_wait_rides_first_step_event_only(gpt):
     model, variables = gpt
+    from unionml_tpu.serving.telemetry import Telemetry
+
+    telemetry = Telemetry()
+    sched = SLOScheduler(telemetry=telemetry)
+    ticket = _ticket(sched, now=100.0)
+    sched.submit(ticket, now=100.0)
+    assert sched.pop(1, now=100.0125) == [ticket]
     engine = DecodeEngine(model, variables, num_slots=1, max_len=64, prefill_buckets=(8,))
     slot = engine.add_request([3, 1, 4], 4)
-    engine.note_queue_wait(slot, 12.5)
+    engine.note_queue_wait(slot, ticket.queue_wait_ms)
     events = []
     while engine.num_active or engine.has_pending_events:
         events.extend(engine.step())
     waits = [ev.queue_wait_ms for ev in events]
-    assert waits[0] == 12.5 and all(w is None for w in waits[1:])
-    assert engine.pipeline_stats()["ema_queue_wait_ms"] == 12.5
+    assert waits[0] == pytest.approx(12.5) and all(w is None for w in waits[1:])
+    # the aggregate of queue waits is the histogram's sum and count
+    waited = telemetry.metrics.snapshot()["unionml_queue_wait_ms"]["standard"]
+    assert waited["count"] == 1 and waited["sum"] == pytest.approx(12.5)
 
 
 # ------------------------------------------------------- batcher integration

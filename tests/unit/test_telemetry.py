@@ -495,6 +495,44 @@ def test_http_metrics_trace_and_request_id_echo(gpt):
     asyncio.run(main())
 
 
+def test_stream_trailer_carries_the_request_id(gpt):
+    """The stream's ``done`` line names the request like every other response,
+    so a client can join its own stamps to ``GET /trace/{request_id}``."""
+    import json
+    import types
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from unionml_tpu.serving import build_aiohttp_app
+
+    model, variables = gpt
+    stub = types.SimpleNamespace(name="obs-app", artifact=object())
+    app = build_aiohttp_app(
+        stub, resident=False, coalesce=False,
+        generator=lambda: _engine(model, variables), generate_drain_s=2.0,
+    )
+
+    async def main():
+        client = TestClient(TestServer(app))
+        await client.start_server()
+        try:
+            resp = await client.post(
+                "/generate", json={"prompt_ids": PROMPT_A, "max_new_tokens": 4, "stream": True}
+            )
+            assert resp.status == 200, await resp.text()
+            lines = [json.loads(line) for line in (await resp.text()).strip().splitlines()]
+            trailer = lines[-1]
+            assert trailer["done"] is True and len(trailer["tokens"]) == 4
+            trace = await (await client.get(f"/trace/{trailer['request_id']}")).json()
+            assert trace["request_id"] == trailer["request_id"] and trace["status"] == "ok"
+            kinds = [s["kind"] for s in trace["spans"]]
+            assert kinds[0] == "admission" and "decode" in kinds and kinds[-1] == "end"
+        finally:
+            await client.close()
+
+    asyncio.run(main())
+
+
 def test_engine_recovery_trace_has_salvage_span(gpt, gpt_tiny_solo):
     """A recoverable engine failure (rebuild succeeds) keeps the trace OPEN
     across the death: the salvaged span marks the checkpoint and the request

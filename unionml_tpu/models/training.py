@@ -26,6 +26,7 @@ from unionml_tpu.parallel.mesh import (
     batch_sharding,
     wrapped_row_indices,
 )
+from unionml_tpu.profiling import PhaseTimeline
 from unionml_tpu.utils import configure_compile_cache
 
 
@@ -330,6 +331,21 @@ class FitResult:
     wall_time_s: float = 0.0
     steps_per_s: float = 0.0
     examples_per_s: float = 0.0
+    #: seconds the call spent in each of :data:`FIT_PHASES`; they add up to
+    #: the whole call, first (compile) step included
+    phase_seconds: Dict[str, float] = field(default_factory=dict)
+
+
+#: the phases of one :func:`fit` call (spans ``fit.<phase>`` on the profiler's
+#: clock, sums in ``FitResult.phase_seconds``): ``start`` entry to the timed
+#: loop (loader, checkpoint restore, first batch, first step, blocked on);
+#: ``input_wait`` the loop obtaining its next batch (the prefetcher's
+#: hand-over, ``device_put``, the fence on the transfer, slot release);
+#: ``dispatch`` the call into the step; ``log`` and ``checkpoint`` where they
+#: run; ``drain`` the closing ``block_until_ready`` (nothing in the loop waits
+#: for a step, so the host runs ahead and the device's backlog is waited for
+#: here: slack, not work); ``finish`` checkpoint flush and loader close
+FIT_PHASES = ("start", "input_wait", "dispatch", "log", "checkpoint", "drain", "finish")
 
 
 def dict_batches(
@@ -397,20 +413,22 @@ def fit(
     ``prefetch=True`` — silently skipping a requested conversion would be a
     correctness trap.
     """
-    configure_compile_cache()
     if step_fn is not None and grad_accum != 1:
         # silently ignoring a requested option is a correctness trap (same
         # stance as prefetch_convert below): accumulation belongs to the step
         # builder, so pass grad_accum to make_*_train_step instead
         raise ValueError("grad_accum applies to the built-in step; pass it to your step builder")
+    if prefetch_convert and not prefetch:
+        raise ValueError("prefetch_convert requires prefetch=True (conversion runs in the native gather workers)")
+    # the arguments hold: from here the call is on its timeline
+    timeline = PhaseTimeline("fit", FIT_PHASES)
+    timeline.enter("start")
+    configure_compile_cache()
     if step_fn is None:
         step_fn = make_classifier_train_step(
             mesh=mesh, param_spec=param_spec, input_signature=input_signature,
             grad_accum=grad_accum,
         )
-
-    if prefetch_convert and not prefetch:
-        raise ValueError("prefetch_convert requires prefetch=True (conversion runs in the native gather workers)")
 
     prefetch_loader = None
     if prefetch:
@@ -483,42 +501,55 @@ def fit(
     jax.block_until_ready(metrics)
     step += 1
 
-    t0 = time.perf_counter()
+    # the loop is in input_wait whenever the iterator runs (the for statement's
+    # own next() included), and in dispatch, log or checkpoint inside the body
+    t0 = timeline.enter("input_wait")
     done = False
     # an explicit step budget overrides the epoch count (loops data as needed)
     epochs = num_epochs if num_steps is None else max(num_epochs, 10**9)
     for epoch in range(epochs):
         for batch in batch_iterator(rng):
+            timeline.enter("dispatch")
             state, metrics = step_fn(state, batch)
             step += 1
             if step % log_every == 0:
+                timeline.enter("log")
                 metrics_host = {k: float(v) for k, v in metrics.items()}
                 history.append({"step": step, **metrics_host})
                 logger.info("step %d: %s", step, metrics_host)
             if checkpointer is not None:
+                timeline.enter("checkpoint")
                 checkpointer.save(step, state)
             if num_steps is not None and step - start_step >= num_steps:
                 done = True
                 break
+            timeline.enter("input_wait")
         if done:
             break
+    timeline.enter("drain")
     jax.block_until_ready(metrics)  # the timed region ends when the last step has run
-    wall = time.perf_counter() - t0
+    wall = timeline.enter("finish") - t0
     if checkpointer is not None:
         checkpointer.flush()
     if prefetch_loader is not None:
         prefetch_loader.close()
+    timeline.leave()
 
     executed = step - start_step - 1  # first (compile) step excluded from the timing
-    result = FitResult(
+    phase_seconds = {phase: totals["seconds"] for phase, totals in timeline.snapshot().items()}
+    logger.info(
+        "fit: %d steps in %.3f s after the first; seconds by phase: %s", executed, wall,
+        ", ".join(f"{phase} {seconds:.3f}" for phase, seconds in phase_seconds.items()),
+    )
+    return FitResult(
         state=state,
         metrics_history=history,
         steps=step,
         wall_time_s=wall,
         steps_per_s=executed / wall if wall > 0 else 0.0,
         examples_per_s=executed * batch_size / wall if wall > 0 else 0.0,
+        phase_seconds=phase_seconds,
     )
-    return result
 
 
 def fit_lm(

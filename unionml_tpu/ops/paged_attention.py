@@ -335,11 +335,14 @@ def paged_attention(
     block_size = k.shape[2]
     width = block_table.shape[1]
     impl = resolve_paged_impl(impl, width, block_size, heads, head_dim)
+    # both arms carry one scope name, so that a trace finds the kernel's
+    # operations whichever arm ran
     if impl == "xla":
-        return xla_paged_attention(
-            q, k, v, block_table, base_positions,
-            k_scale=k_scale, v_scale=v_scale, out_dtype=out_dtype,
-        )
+        with jax.named_scope("paged_attention"):
+            return xla_paged_attention(
+                q, k, v, block_table, base_positions,
+                k_scale=k_scale, v_scale=v_scale, out_dtype=out_dtype,
+            )
     if not interpret and not on_tpu():
         raise RuntimeError(
             f"paged_attention(impl='pallas') needs a TPU backend, found "
@@ -370,7 +373,8 @@ def paged_attention(
         kernel = shard_map(
             kernel, mesh=mesh, in_specs=in_specs, out_specs=by_head, check_vma=False
         )
-    return kernel(*operands)
+    with jax.named_scope("paged_attention"):
+        return kernel(*operands)
 
 
 def fused_hbm_bytes(

@@ -674,7 +674,8 @@ def build_aiohttp_app(
                             tokens.append(token)
                             await response.write((_json.dumps({"token": token}) + "\n").encode())
                 await response.write(
-                    (_json.dumps({"done": True, "tokens": tokens}) + "\n").encode()
+                    (_json.dumps({"done": True, "tokens": tokens, "request_id": request_id})
+                     + "\n").encode()
                 )
             except Exception as exc:
                 logger.warning(
@@ -747,9 +748,9 @@ def build_aiohttp_app(
                 payload["generation"]["speculation"] = spec_stats()
             pipeline_stats = getattr(gen.engine, "pipeline_stats", None)
             if callable(pipeline_stats):
-                # pipelined-decode observability: depth, host-gap EMA (ms the
-                # device queue sat empty before a dispatch), fetch-block EMA,
-                # and device-idle dispatch counters
+                # pipelined-decode observability: depth, dispatch and idle
+                # counters, the active-slot integral and the loop thread's
+                # phase counters (seconds, entries, duration buckets)
                 payload["generation"]["pipeline"] = pipeline_stats()
             if getattr(gen.engine, "prefix_cache", None) is not None:
                 # hit rate + eviction churn for the KV prefix cache, plus the

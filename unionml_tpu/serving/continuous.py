@@ -89,6 +89,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from unionml_tpu._logging import logger
+from unionml_tpu.profiling import PhaseTimeline
 from unionml_tpu.serving.faults import EngineFailure, FaultPlan
 
 #: default prompt-prefill bucket lengths (right-padded; one XLA compile each)
@@ -115,6 +116,17 @@ def bind_serving_mesh(model: Any, mesh: Optional[Any]) -> Any:
     if mesh is None or not hasattr(model.config, "tp_mesh"):
         return model
     return model.clone(config=dataclasses.replace(model.config, tp_mesh=mesh))
+
+
+#: the serving loop's phases (spans ``loop.<phase>``, counters in
+#: ``pipeline_stats()["phases"]``): ``idle`` no active slot and nothing queued;
+#: ``admit`` deadlines, preemption, scheduler pop, validation, block-demand
+#: gate, registration; ``prefill`` padded rows, block allocation, the prefill
+#: and insert dispatches, activation; ``plan`` pending events, headroom and
+#: lookahead planning; ``dispatch`` enqueueing the decode program;
+#: ``fetch_wait`` the host blocked in the fused token fetch (slack, not work);
+#: ``apply`` tokens into the host mirrors; ``fan_out`` delivery to the sinks
+LOOP_PHASES = ("idle", "admit", "prefill", "plan", "dispatch", "fetch_wait", "apply", "fan_out")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -444,16 +456,18 @@ class DecodeEngine:
         #: per-slot queue wait (ms) noted by the batcher at admission
         #: (:meth:`note_queue_wait`); attached to the slot's first StepEvent
         self._slot_queue_wait: Dict[int, float] = {}
-        self.ema_queue_wait_ms: Optional[float] = None
         #: device-idle accounting: a dispatch is "idle" when the device queue
-        #: was empty when it was enqueued (no in-flight step); the EMAs track
-        #: the host gap the device sat idle (ms) and the time the host spent
-        #: blocked in the token fetch (ms)
+        #: was empty when it was enqueued (no in-flight step)
         self.step_dispatches = 0
         self.idle_dispatches = 0
-        self.ema_host_gap_ms: Optional[float] = None
-        self.ema_fetch_block_ms: Optional[float] = None
+        #: sum over dispatches of (host-active slots x steps in the burst):
+        #: with ``tokens_decoded`` and ``step_dispatches`` it differences into
+        #: a window's occupancy
+        self.active_slot_steps = 0
         self._last_fetch_done: Optional[float] = None
+        #: what the loop thread that drives this engine is doing (see
+        #: :data:`LOOP_PHASES`); the batcher drives the same instance
+        self.timeline = PhaseTimeline("loop", LOOP_PHASES)
 
         # prefix cache (disabled until enable_prefix_cache): host radix index +
         # device KV block pool + per-slot held node paths / token transcripts
@@ -1174,7 +1188,7 @@ class DecodeEngine:
         ids = self._allocator.alloc_blocks(need)
         if ids is None:
             if self._inflight is not None:
-                self._pending_events.extend(self._fetch_inflight())
+                self._flush_inflight()
                 ids = self._allocator.alloc_blocks(need)
             if ids is None:
                 raise EngineFailure(
@@ -1400,7 +1414,7 @@ class DecodeEngine:
             # replayed yet: fetch it before refusing, so admission is exactly as
             # responsive as an unpipelined engine (the events reach the caller
             # through the next step())
-            self._pending_events.extend(self._fetch_inflight())
+            self._flush_inflight()
             free = self.free_slots
         if len(normalized) > len(free):
             raise RuntimeError("no free decode slots")
@@ -1452,6 +1466,7 @@ class DecodeEngine:
             for start in range(0, len(idxs), self.prefill_batch):
                 chunk = idxs[start : start + self.prefill_batch]
                 rows = len(chunk)
+                self.timeline.enter("prefill", rows=rows, bucket=int(bucket))
                 padded = np.zeros((rows, bucket), dtype=np.int32)
                 lengths = np.zeros((rows,), dtype=np.int32)
                 for r, slot in enumerate(chunk):
@@ -1556,6 +1571,7 @@ class DecodeEngine:
             return False
         suffix_len = int(prompt.size) - matched
         bucket = self.bucket_for(suffix_len)
+        self.timeline.enter("prefill", rows=1, bucket=int(bucket))
         ids = np.zeros((1, bucket), dtype=np.int32)
         ids[0, :suffix_len] = prompt[matched:]
         if self.paged:
@@ -1835,6 +1851,7 @@ class DecodeEngine:
         consumer — while every other slot keeps prefilling and decoding. Only
         the slot-insert dispatch (which donates the shared engine cache) can
         escalate to a whole-engine failure."""
+        self.timeline.enter("prefill", rows=len(self._partials), bucket=int(self.prefill_chunk))
         for slot in list(self._partials):
             state = self._partials[slot]
             prompt, consumed = state["prompt"], state["consumed"]
@@ -2225,22 +2242,17 @@ class DecodeEngine:
 
     def pipeline_stats(self) -> Dict[str, Any]:
         """Pipeline observability for ``GET /stats``: configured depth, whether a
-        step is currently in flight, dispatch/idle counters, and the host-gap /
-        fetch-block EMAs (ms)."""
+        step is currently in flight, the dispatch/idle counters, the active-slot
+        integral and the loop thread's phase counters
+        (:meth:`~unionml_tpu.profiling.PhaseTimeline.snapshot`). Everything but
+        ``inflight`` only grows, so two reads difference into a window."""
         return {
             "depth": 1 if self.pipeline else 0,
             "inflight": self._inflight is not None,
             "step_dispatches": self.step_dispatches,
             "idle_dispatches": self.idle_dispatches,
-            "ema_host_gap_ms": None
-            if self.ema_host_gap_ms is None
-            else round(self.ema_host_gap_ms, 3),
-            "ema_fetch_block_ms": None
-            if self.ema_fetch_block_ms is None
-            else round(self.ema_fetch_block_ms, 3),
-            "ema_queue_wait_ms": None
-            if self.ema_queue_wait_ms is None
-            else round(self.ema_queue_wait_ms, 3),
+            "active_slot_steps": self.active_slot_steps,
+            "phases": self.timeline.snapshot(),
         }
 
     def robustness_stats(self) -> Dict[str, Any]:
@@ -2259,8 +2271,8 @@ class DecodeEngine:
     def note_queue_wait(self, slot: int, wait_ms: Optional[float]) -> None:
         """Record how long ``slot``'s request sat queued before admission (the
         batcher calls this right after ``admit_many``). The value rides on the
-        slot's first :class:`StepEvent` and feeds the queue-wait EMA that
-        :meth:`pipeline_stats` (and ``GET /stats``) report.
+        slot's first :class:`StepEvent`; the ``unionml_queue_wait_ms``
+        histogram (sum and count) is what aggregates queue waits.
 
         .. deprecated:: PR-11
             ``StepEvent.queue_wait_ms`` (populated only on the first token)
@@ -2270,11 +2282,6 @@ class DecodeEngine:
         if wait_ms is None:
             return
         self._slot_queue_wait[slot] = float(wait_ms)
-        self.ema_queue_wait_ms = (
-            float(wait_ms)
-            if self.ema_queue_wait_ms is None
-            else 0.8 * self.ema_queue_wait_ms + 0.2 * float(wait_ms)
-        )
 
     def note_request_id(self, slot: int, request_id: Optional[str]) -> None:
         """Bind ``slot``'s occupant to its trace (batcher-set at registration,
@@ -2314,6 +2321,17 @@ class DecodeEngine:
         self._inflight, self._inflight_skip = None, set()
         return self._replay_burst(burst, skip)
 
+    def _flush_inflight(self) -> None:
+        """Out-of-band flush (admission short of slots or blocks, cancel,
+        preempt): replay the in-flight step now and buffer its events for the
+        next :meth:`step`. The loop passes through ``fetch_wait`` and ``apply``
+        and returns to the phase that asked for the flush."""
+        if self._inflight is None:
+            return
+        asked_from = self.timeline.current
+        self._pending_events.extend(self._fetch_inflight())
+        self.timeline.enter(asked_from)
+
     def _replay_burst(
         self, burst: Tuple[Any, Any, Any, int], skip: frozenset = frozenset()
     ) -> List[StepEvent]:
@@ -2325,7 +2343,7 @@ class DecodeEngine:
         ``(step, slot)`` quarantines THAT slot (its sampled token is garbage
         and never delivered) while every other slot's tokens apply normally."""
         tokens, masks, bads, _ = burst
-        t0 = time.perf_counter()
+        t0 = self.timeline.enter("fetch_wait")
         try:
             if self._faults is not None:
                 stall_ms = self._faults.take_fetch_stall_ms()
@@ -2339,14 +2357,9 @@ class DecodeEngine:
         except Exception:
             self._on_failure()
             raise
-        done = time.perf_counter()
+        done = self.timeline.enter("apply")
         self.last_heartbeat = time.monotonic()
         block_ms = (done - t0) * 1e3
-        self.ema_fetch_block_ms = (
-            block_ms
-            if self.ema_fetch_block_ms is None
-            else 0.8 * self.ema_fetch_block_ms + 0.2 * block_ms
-        )
         self._last_fetch_done = done
         events: List[StepEvent] = []
         telemetry = self._telemetry
@@ -2374,8 +2387,8 @@ class DecodeEngine:
                 if telemetry is not None and event.emit:
                     emitted[rid] = emitted.get(rid, 0) + 1
         if telemetry is not None and emitted:
-            # per-burst decode timing piggybacks on the stamps this fetch took
-            # anyway (t0/done/block_ms above): ZERO new host<->device syncs —
+            # per-burst decode timing piggybacks on the fetch_wait phase's two
+            # stamps (t0/done/block_ms above): ZERO new host<->device syncs —
             # everything here reads the already-fetched host arrays
             telemetry.decode_fetch_ms.observe(block_ms)
             for rid, n in emitted.items():
@@ -2508,6 +2521,8 @@ class DecodeEngine:
         re-raises — the engine stays usable either way.
         """
         self._ensure_usable()
+        timeline = self.timeline
+        timeline.enter("plan")
         events: List[StepEvent] = []
         if self._pending_events:
             # replayed by an out-of-band flush (cancel / contended admission):
@@ -2524,6 +2539,7 @@ class DecodeEngine:
             except Exception:
                 self._on_failure()
                 raise
+            timeline.enter("plan")
         if not self._active.any():
             return events
         lookahead = max(1, int(lookahead))
@@ -2556,7 +2572,8 @@ class DecodeEngine:
         # device-resident mirrors (refreshed in _activate/cancel/reset), so a
         # steady-state tick performs ZERO host→device transfers (pinned by the
         # transfer-guard regression test).
-        t0 = time.perf_counter()
+        active = int(np.count_nonzero(self._active))
+        timeline.enter("dispatch", active=active)
         device_was_idle = self._inflight is None
         try:
             tokens, masks, bads, lookahead = self._dispatch_step(lookahead)
@@ -2570,20 +2587,9 @@ class DecodeEngine:
                 # in-program finiteness flag trips and the host quarantines it
                 self._last_logits = self._last_logits.at[bad_slot].set(jnp.nan)
         self.step_dispatches += 1
+        self.active_slot_steps += active * lookahead
         if device_was_idle and self._last_fetch_done is not None:
             self.idle_dispatches += 1
-        if self._last_fetch_done is not None:
-            # host gap = how long the device queue sat EMPTY before this
-            # dispatch (0 when a step was still in flight — the pipelined case).
-            # Clamped so a genuine idle wait for traffic cannot poison the EMA.
-            gap_ms = (
-                min((t0 - self._last_fetch_done) * 1e3, 250.0) if device_was_idle else 0.0
-            )
-            self.ema_host_gap_ms = (
-                gap_ms
-                if self.ema_host_gap_ms is None
-                else 0.8 * self.ema_host_gap_ms + 0.2 * gap_ms
-            )
         previous, prev_skip = self._inflight, self._inflight_skip
         self._inflight, self._inflight_skip = (tokens, masks, bads, lookahead), set()
         if previous is not None:
@@ -2634,7 +2640,7 @@ class DecodeEngine:
         to inactive so the device stops decoding it.
         """
         self._ensure_usable()
-        self._pending_events.extend(self._fetch_inflight())
+        self._flush_inflight()
         # the flush may have buffered this slot's own tokens: its consumer is
         # gone, and delivering them later could credit them to the slot's NEXT
         # occupant — drop them (survivors' events stay queued)
@@ -2686,7 +2692,7 @@ class DecodeEngine:
         # flush the in-flight step under the OLD slot mapping (same rule as
         # cancel): its tokens are real — they extend this slot's transcript
         # and reach its consumer through the buffered events
-        self._pending_events.extend(self._fetch_inflight())
+        self._flush_inflight()
         if not self._active[slot]:
             return None  # retired during the flush: nothing left to preempt
         transcript = self._slot_tokens.get(slot)
@@ -2890,6 +2896,8 @@ class ContinuousBatcher:
         from unionml_tpu.serving.scheduler import SchedulerConfig, SLOScheduler
 
         self._engine = engine
+        #: the engine's phase timeline: this batcher's worker is the loop thread
+        self._timeline = engine.timeline
         self._lookahead = max(1, int(lookahead))
         #: span/metrics collector shared by the whole request path; the batcher
         #: is the wiring hub — it propagates one instance into the engine, the
@@ -3230,10 +3238,9 @@ class ContinuousBatcher:
         for _, _, slot, ticket in victims:
             state = self._engine.preempt(slot)
             try:
-                if self._engine.has_pending_events:
-                    # the preempt flush ran under the OLD mapping: deliver the
-                    # victim's (and survivors') flushed tokens before re-keying
-                    self._dispatch_events(self._engine.take_pending_events())
+                # the preempt flush ran under the OLD mapping: deliver the
+                # victim's (and survivors') flushed tokens before re-keying
+                self._drain_flush_events()
                 if state is None:
                     # retired during the flush (a slot freed anyway) or not
                     # checkpointable — the dispatch above reconciled either way
@@ -3284,6 +3291,7 @@ class ContinuousBatcher:
         return self._engine.block_demand(len(head.prompt), head.budget) > avail
 
     def _admit(self) -> None:  # graftlint: off-path (admission, not steady-state decode)
+        self._timeline.enter("admit")
         self._drain_orphans()
         self._enforce_deadlines()
         self._maybe_preempt()
@@ -3339,6 +3347,18 @@ class ContinuousBatcher:
         the OLD sink mapping, BEFORE any new sink takes over a slot."""
         if getattr(self._engine, "has_pending_events", False):
             self._dispatch_events(self._engine.take_pending_events())
+            self._timeline.enter("admit")
+
+    def _engine_admit(self, tickets: Sequence[Any]) -> List[int]:
+        """``admit_many`` for ``tickets``; the engine's prefill waves are the
+        ``prefill`` phase, and the loop is back in ``admit`` when this returns
+        or raises."""
+        try:
+            return self._engine.admit_many(
+                [(t.prompt, t.budget, self._spec_sampling(t)) for t in tickets]
+            )
+        finally:
+            self._timeline.enter("admit")
 
     def _register(self, slot: int, ticket: Any) -> None:
         """Bind an admitted ticket to its slot (and retire its resume pin:
@@ -3391,9 +3411,7 @@ class ContinuousBatcher:
         """
         failures_before = getattr(self._engine, "failure_count", 0)
         try:
-            slots = self._engine.admit_many(
-                [(t.prompt, t.budget, self._spec_sampling(t)) for t in admissible]
-            )
+            slots = self._engine_admit(admissible)
         except Exception as exc:
             if getattr(self._engine, "failure_count", 0) != failures_before:
                 self._handle_engine_failure(exc, pending=admissible)
@@ -3410,9 +3428,7 @@ class ContinuousBatcher:
             for ticket in admissible:
                 failures_before = getattr(self._engine, "failure_count", 0)
                 try:
-                    (slot,) = self._engine.admit_many(
-                        [(ticket.prompt, ticket.budget, self._spec_sampling(ticket))]
-                    )
+                    (slot,) = self._engine_admit([ticket])
                 except Exception as one_exc:
                     if getattr(self._engine, "failure_count", 0) != failures_before:
                         self._handle_engine_failure(one_exc, pending=[ticket])
@@ -3581,6 +3597,7 @@ class ContinuousBatcher:
     def _dispatch_events(self, events) -> None:
         """Fan one step's events out to their sinks (cancel on dead consumers;
         engine-terminated requests fail with their structured reason)."""
+        self._timeline.enter("fan_out")
         for event in events:
             sink = self._sinks.get(event.slot)
             if sink is None:
@@ -3640,6 +3657,7 @@ class ContinuousBatcher:
                 done = self._closed and not self.scheduler.depth and not self._sinks
             if done:
                 self._drain_orphans()
+                self._timeline.leave()
                 return
             try:
                 self._admit()
@@ -3665,6 +3683,7 @@ class ContinuousBatcher:
                 self._dispatch_events(events)
                 continue
             if self._engine.num_active == 0:
+                self._timeline.enter("idle")
                 self._work.clear()
                 # re-check under the flag: a request may have landed just now.
                 # The bounded 0.5s wait doubles as the deadline-expiry tick for
