@@ -352,28 +352,29 @@ def _paged_operands(batch, width, bs, heads, hd, quantized, dtype):
     return q, k, v, table, base, ks, vs
 
 
-def sweep_paged_tpu(shapes, head_candidates):
-    """Paged-decode arm on hardware: fused kernel (heads-per-step sweep) vs the
-    XLA gather-dequant-attend arm, int8 AND dense pools, per pool shape."""
-    import functools
-
+def sweep_paged_tpu(shapes):
+    """Paged-decode arm on hardware: the fused kernel, called as the engine
+    calls it (it sizes its own grid), vs the XLA gather-dequant-attend arm,
+    int8 AND dense pools, per pool shape."""
     import jax
     import jax.numpy as jnp
 
     from unionml_tpu.ops.paged_attention import (
-        _paged_forward,
         fused_hbm_bytes,
         gather_hbm_bytes,
-        xla_paged_attention,
+        paged_attention,
     )
 
     SCAN_N = 64  # decode launches are microseconds: time a chained scan
 
-    def scanned(fn):
+    def scanned(impl):
         @jax.jit
-        def run(q, *rest):
+        def run(q, k, v, table, base, ks, vs):
             def body(c, _):
-                return fn(c, *rest), None
+                out = paged_attention(
+                    c, k, v, table, base, k_scale=ks, v_scale=vs, out_dtype=c.dtype, impl=impl
+                )
+                return out, None
 
             return jax.lax.scan(body, q, None, length=SCAN_N)[0]
 
@@ -382,62 +383,38 @@ def sweep_paged_tpu(shapes, head_candidates):
     results = {}
     for batch, width, bs, heads, hd in shapes:
         for quantized in (True, False):
-            q, k, v, table, base, ks, vs = _paged_operands(
-                batch, width, bs, heads, hd, quantized, jnp.bfloat16
-            )
+            operands = _paged_operands(batch, width, bs, heads, hd, quantized, jnp.bfloat16)
             name = f"w{width}_bs{bs}_h{heads}_d{hd}_{'int8' if quantized else 'bf16'}"
-            xla_fn = scanned(
-                lambda c, k, v, t, b, ks, vs: xla_paged_attention(
-                    c, k, v, t, b, k_scale=ks, v_scale=vs, out_dtype=c.dtype
-                )
-            )
-            xla_ms = _time(xla_fn, q, k, v, table, base, ks, vs, iters=8, reps=5) / SCAN_N
-            rows, best = [], None
-            for gh in head_candidates:
-                if heads % gh:
-                    continue
-                fused = scanned(
-                    functools.partial(
-                        lambda c, k, v, t, b, ks, vs, gh: _paged_forward(
-                            c, k, v, t, b, ks, vs, c.dtype, gh, False
-                        ),
-                        gh=gh,
-                    )
-                )
-                try:
-                    ms = _time(fused, q, k, v, table, base, ks, vs, iters=8, reps=5) / SCAN_N
-                except Exception as exc:  # Mosaic lowering failure at this tiling
-                    rows.append({"heads_per_step": gh, "error": str(exc)[:200]})
-                    continue
-                rows.append({"heads_per_step": gh, "fwd_ms": round(ms, 5)})
-                if best is None or ms < best["fwd_ms"]:
-                    best = rows[-1]
+            xla_ms = _time(scanned("xla"), *operands, iters=8, reps=5) / SCAN_N
+            try:
+                best = {"fwd_ms": round(_time(scanned("pallas"), *operands, iters=8, reps=5) / SCAN_N, 5)}
+            except Exception as exc:  # Mosaic refused the shape
+                best = None
+                print(f"[paged] {name}: kernel failed: {str(exc)[:200]}", file=sys.stderr)
             results[name] = {
                 "xla_fwd_ms": round(xla_ms, 5),
-                "sweep": rows,
                 "best": best,
                 "verdict": (
-                    "use_pallas" if best and best["fwd_ms"] < xla_ms else "use_xla"
+                    "use_pallas" if best["fwd_ms"] < xla_ms else "use_xla"
                 ) if best is not None else "pallas_failed_use_xla",
                 "fused_hbm_bytes": fused_hbm_bytes(width, bs, heads, hd, quantized),
                 "gather_hbm_bytes": gather_hbm_bytes(width, bs, heads, hd, quantized),
             }
-            print(f"[paged] {name}: xla {xla_ms:.5f}ms best "
+            print(f"[paged] {name}: xla {xla_ms:.5f}ms kernel "
                   f"{best['fwd_ms'] if best else float('nan'):.5f}ms "
                   f"-> {results[name]['verdict']}", file=sys.stderr)
     return results
 
 
-def correctness_sweep_paged_cpu(shapes, head_candidates):
-    """CPU fallback for --paged: interpret-mode parity per heads-per-step
-    tiling, both pool dtypes, against the XLA gather reference."""
+def correctness_sweep_paged_cpu(shapes):
+    """CPU fallback for --paged: interpret-mode parity of the kernel, both pool
+    dtypes, against the XLA gather reference."""
     import jax.numpy as jnp
 
     from unionml_tpu.ops.paged_attention import (
-        _paged_forward,
         fused_hbm_bytes,
         gather_hbm_bytes,
-        xla_paged_attention,
+        paged_attention,
     )
 
     results = {}
@@ -447,27 +424,18 @@ def correctness_sweep_paged_cpu(shapes, head_candidates):
                 batch, width, bs, heads, hd, quantized, jnp.float32
             )
             name = f"w{width}_bs{bs}_h{heads}_d{hd}_{'int8' if quantized else 'f32'}"
-            ref = xla_paged_attention(
-                q, k, v, table, base, k_scale=ks, v_scale=vs, out_dtype=jnp.float32
-            )
-            rows = []
-            for gh in head_candidates:
-                if heads % gh:
-                    continue
-                out = _paged_forward(
-                    q, k, v, table, base, ks, vs, jnp.float32, gh, True
-                )
-                err = float(jnp.max(jnp.abs(out - ref)))
-                rows.append({"heads_per_step": gh, "max_err": err, "ok": err < 1e-4})
+            args = dict(k_scale=ks, v_scale=vs, out_dtype=jnp.float32)
+            ref = paged_attention(q, k, v, table, base, impl="xla", **args)
+            out = paged_attention(q, k, v, table, base, impl="pallas", interpret=True, **args)
+            err = float(jnp.max(jnp.abs(out - ref)))
             results[name] = {
                 "mode": "cpu-interpret-correctness-only",
-                "sweep": rows,
-                "all_ok": all(r["ok"] for r in rows),
+                "max_err": err,
+                "all_ok": err < 1e-4,
                 "fused_hbm_bytes": fused_hbm_bytes(width, bs, heads, hd, quantized),
                 "gather_hbm_bytes": gather_hbm_bytes(width, bs, heads, hd, quantized),
             }
-            print(f"[paged] {name}: {len(rows)} tilings validated, "
-                  f"all_ok={results[name]['all_ok']}", file=sys.stderr)
+            print(f"[paged] {name}: all_ok={results[name]['all_ok']}", file=sys.stderr)
     return results
 
 
@@ -575,13 +543,12 @@ def main():
             (8, 32, 16, 12, 64),
             (4, 16, 16, 16, 128),
         ]
-        head_candidates = (1, 2, 4)
         if backend == "cpu":
             paged_shapes = [(2, 4, 4, 2, 16), (2, 6, 4, 4, 16)]
-            results = correctness_sweep_paged_cpu(paged_shapes, head_candidates)
+            results = correctness_sweep_paged_cpu(paged_shapes)
             payload = {"backend": backend, "timing_valid": False, "results": results}
         else:
-            results = sweep_paged_tpu(paged_shapes, head_candidates)
+            results = sweep_paged_tpu(paged_shapes)
             payload = {"backend": backend, "timing_valid": True, "results": results}
         # the acceptance gate runs in BOTH modes: the traffic model is static
         payload["traffic_gate"] = gate_paged_traffic(paged_shapes)

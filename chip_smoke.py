@@ -4,8 +4,10 @@
 
 - ``kernels``: every Pallas entry point compiled by Mosaic (``interpret=False``)
   at one real shape and compared with its XLA reference — flash attention
-  forward, forward+backward and packed; the paged kernel as decode (S=1), chunk
-  (S>1) and verify (identity table) over bf16 and int8 pools.
+  forward, forward+backward and packed; the paged kernel as decode (S=1, ragged
+  rows, one retired), chunk (S>1) and verify (identity table) over bf16 and int8
+  pools, at GPT-2 small's shape (12 heads, 8 slots) and GPT-2 medium's (16
+  heads, 48 slots: the benchmark's serving cell).
 - ``server_bf16`` / ``server_int8``: GPT-2 small at full width (12 layers, hidden
   768, 12 heads, 1024 positions, vocab 50257, bf16; random weights from a seed)
   behind ``build_aiohttp_app`` on a real socket, engine defaults (paged,
@@ -161,12 +163,12 @@ def phase_kernels() -> None:
         (q, k, v), BF16_TOL,
     )
 
-    # the paged kernel at the shapes the server phase traces: 16-token blocks,
-    # table width 65 (max_len 1024 + scratch column), 8 slots
-    block_size, width, slots = 16, 65, 8
-    blocks = slots * (width - 1) + 1
+    # the paged kernel at the shapes the server phase traces (GPT-2 small: 12
+    # heads, 8 slots) and at the benchmark's serving cell (GPT-2 medium: 16
+    # heads, 48 slots): 16-token blocks, table width 65 (max_len 1024 + scratch)
+    block_size, width = 16, 65
 
-    def pool_leaves(n_blocks, quantized, code_dtype):
+    def pool_leaves(n_blocks, heads, quantized, code_dtype):
         """((k, v), scales) of a random pool; ``scales`` is empty for bf16."""
         shape = (n_blocks, heads, block_size, head_dim)
         if not quantized:
@@ -189,26 +191,32 @@ def phase_kernels() -> None:
     kernel = positional(functools.partial(paged_attention, impl="pallas"))
     reference = positional(xla_paged_attention)
 
-    def queries(rows, seq):
-        return jnp.asarray(rng.normal(size=(rows, heads, seq, head_dim)), jnp.bfloat16)
+    for model, heads, slots in (("gpt2-small", 12, 8), ("gpt2-medium", 16, 48)):
+        blocks = slots * (width - 1) + 1
 
-    for pool in ("bf16", "int8"):
-        (k, v), scales = pool_leaves(blocks, pool == "int8", jnp.int8)
-        # every slot owns a shuffled run of blocks; the last column is scratch
-        table = rng.permutation(blocks - 1).reshape(slots, width - 1).astype(np.int32)
-        table = jnp.asarray(np.concatenate([table, np.full((slots, 1), blocks - 1, np.int32)], 1))
-        base = jnp.asarray(rng.integers(0, (width - 1) * block_size - 64, slots), jnp.int32)
-        check_kernel(f"paged decode S=1 ({pool})", kernel, reference,
-                     (queries(slots, 1), k, v, table, base, *scales), BF16_TOL)
-        for chunk in CHUNKS:
-            check_kernel(f"paged chunk S={chunk} ({pool})", kernel, reference,
-                         (queries(1, chunk), k, v, table[:1], base[:1], *scales), BF16_TOL)
-        # speculative verify: the row's gathered blocks as a local pool behind an
-        # identity table, int8 codes carried as exact integers in f32
-        (k, v), scales = pool_leaves(slots * width, pool == "int8", jnp.float32)
-        identity = jnp.arange(slots * width, dtype=jnp.int32).reshape(slots, width)
-        check_kernel(f"paged verify ({pool})", kernel, reference,
-                     (queries(slots, 1), k, v, identity, base, *scales), BF16_TOL)
+        def queries(rows, seq):
+            return jnp.asarray(rng.normal(size=(rows, heads, seq, head_dim)), jnp.bfloat16)
+
+        for pool in ("bf16", "int8"):
+            (k, v), scales = pool_leaves(blocks, heads, pool == "int8", jnp.int8)
+            # every slot owns a shuffled run of blocks; the last column is scratch
+            table = rng.permutation(blocks - 1).reshape(slots, width - 1).astype(np.int32)
+            table = jnp.asarray(np.concatenate([table, np.full((slots, 1), blocks - 1, np.int32)], 1))
+            # ragged rows, the first a retired one on the sentinel base
+            base = rng.integers(0, (width - 1) * block_size - 64, slots).astype(np.int32)
+            base[0] = (width - 1) * block_size
+            base = jnp.asarray(base)
+            check_kernel(f"paged decode S=1 ({model}, {pool})", kernel, reference,
+                         (queries(slots, 1), k, v, table, base, *scales), BF16_TOL)
+            for chunk in CHUNKS:
+                check_kernel(f"paged chunk S={chunk} ({model}, {pool})", kernel, reference,
+                             (queries(1, chunk), k, v, table[1:2], base[1:2], *scales), BF16_TOL)
+            # speculative verify: the row's gathered blocks as a local pool behind an
+            # identity table, int8 codes carried as exact integers in f32
+            (k, v), scales = pool_leaves(slots * width, heads, pool == "int8", jnp.float32)
+            identity = jnp.arange(slots * width, dtype=jnp.int32).reshape(slots, width)
+            check_kernel(f"paged verify ({model}, {pool})", kernel, reference,
+                         (queries(slots, 1), k, v, identity, base, *scales), BF16_TOL)
 
 
 class Server:

@@ -58,11 +58,16 @@ def _q(seed, batch, S=1):
     return jnp.asarray(rng.normal(size=(batch, HEADS, S, HD)), jnp.float32)
 
 
+def _run(impl, q, k, v, table, base, scales=(None, None), compute=jnp.float32):
+    extra = {"interpret": True} if impl == "pallas" else {}
+    return np.asarray(paged_attention(
+        q, k, v, table, base, k_scale=scales[0], v_scale=scales[1],
+        out_dtype=compute, impl=impl, **extra,
+    ), np.float32)
+
+
 def _both(q, k, v, table, base, ks=None, vs=None):
-    args = dict(k_scale=ks, v_scale=vs, out_dtype=jnp.float32)
-    ref = paged_attention(q, k, v, table, base, impl="xla", **args)
-    out = paged_attention(q, k, v, table, base, impl="pallas", interpret=True, **args)
-    return np.asarray(ref), np.asarray(out)
+    return tuple(_run(impl, q, k, v, table, base, (ks, vs)) for impl in ("xla", "pallas"))
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
@@ -155,6 +160,108 @@ def test_spliced_shared_block_at_nonzero_offset():
     ref2, out2 = _both(q, k2, v2, table2, base, ks2, vs2)
     np.testing.assert_array_equal(out2, out)
     np.testing.assert_array_equal(ref2, ref)
+
+
+# the real block geometry (16-token blocks of 64-wide heads), so that a grid
+# step of the kernel takes 8 table entries as it does on the chip
+RBS, RHD = 16, 64
+POOLS = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+
+
+def _ragged_case(heads, width, S, pool, seed=0):
+    """One ragged batch: bases 0, 1, ``bs - 1``, ``bs``, ``bs + 1``, mid-table,
+    the full table, the engine's sentinel for a retired row, and an empty live
+    range (no query sees a key). A row owns the blocks of its live columns; the
+    columns past them all name the pool's last block, as the engine's name its
+    scratch block."""
+    rng = np.random.default_rng(seed)
+    capacity = width * RBS
+    bases = np.asarray(
+        [0, 1, RBS - 1, RBS, RBS + 1, capacity // 2 + 3, capacity - S, (width - 1) * RBS, -S]
+    )
+    rows = len(bases)
+    live = np.clip((bases + S - 1) // RBS + 1, 0, width)  # live columns a row
+    blocks = int(live.sum()) + 1
+    owned = iter(rng.permutation(blocks - 1))
+    table = np.full((rows, width), blocks - 1, np.int32)
+    for r in range(rows):
+        table[r, : live[r]] = [next(owned) for _ in range(live[r])]
+    shape = (blocks, heads, RBS, RHD)
+    compute = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    if pool == "int8":
+        k, v = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8) for _ in range(2))
+        scales = [
+            jnp.asarray(rng.uniform(0.005, 0.02, (blocks, heads, 1, 1)), jnp.float32)
+            for _ in range(2)
+        ]
+    else:
+        k, v = (jnp.asarray(rng.normal(size=shape), POOLS[pool]) for _ in range(2))
+        scales = [None, None]
+    q = jnp.asarray(rng.normal(size=(rows, heads, S, RHD)), compute)
+    return q, k, v, jnp.asarray(table), jnp.asarray(bases, jnp.int32), scales, compute
+
+
+@pytest.mark.parametrize("heads", [12, 16])
+@pytest.mark.parametrize("pool", sorted(POOLS))
+@pytest.mark.parametrize("S", [1, 5, 24], ids=["decode", "verify", "chunk"])
+@pytest.mark.parametrize("width", [65, 16], ids=["w65", "w16"])
+def test_bounded_walk_matches_xla_over_ragged_rows(width, S, pool, heads):
+    """The walk that ends at each row's live length against the full gather:
+    every boundary of the block and of the 8-entry tile in one batch, a table
+    whose width the tile does not divide (65) and one it does (16)."""
+    q, k, v, table, base, scales, compute = _ragged_case(heads, width, S, pool)
+    ref = _run("xla", q, k, v, table, base, scales, compute)
+    out = _run("pallas", q, k, v, table, base, scales, compute)
+    # a query that sees no key: the gather arm softmaxes a row of masks into a
+    # uniform average, the kernel adds nothing and writes 0, as the masked full
+    # walk did
+    sees = (np.asarray(base)[:, None] + np.arange(S)[None, :]) >= 0  # (rows, S)
+    assert np.isfinite(out).all()
+    assert (out[~sees[:, None, :].repeat(heads, 1)] == 0).all()
+    live = sees[:, None, :, None]
+    # bf16: the arms round the weights at different points (after and before
+    # the normalisation), one unit of bf16 in the last place apart
+    tol = 2e-2 if pool == "bf16" else 2e-5
+    np.testing.assert_allclose(
+        np.where(live, out, 0), np.where(live, ref, 0), atol=tol, rtol=tol
+    )
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+@pytest.mark.parametrize("S", [1, 24], ids=["decode", "chunk"])
+def test_nothing_past_the_live_length_is_folded_in(S, pool):
+    """Poison everything past each row's last visible key — NaN in float pools
+    (the rest of the last live block included), codes of +-127 under a scale
+    that dequantizes to infinity in whole dead int8 blocks — and the output
+    does not move: dead columns are neither fetched nor multiplied in."""
+    heads, width = 12, 65
+    q, k, v, table, base, scales, compute = _ragged_case(heads, width, S, pool, seed=1)
+    clean = _run("pallas", q, k, v, table, base, scales, compute)
+
+    last = np.asarray(base) + S - 1  # last key position a row's queries see
+    position = np.arange(width * RBS).reshape(width, RBS)
+    dead_key = position[None] > last[:, None, None]  # (rows, width, bs)
+    blocks = np.asarray(table)
+    dead_blocks = np.unique(blocks[dead_key.all(-1)])  # the shared last block, no other
+    if pool == "int8":
+        k, v = np.array(k), np.array(v)
+        k[dead_blocks], v[dead_blocks] = 127, -127
+        scales = [np.array(s) for s in scales]
+        for s in scales:
+            s[dead_blocks] = 3e38
+        scales = [jnp.asarray(s) for s in scales]
+    else:
+        k, v = np.array(k, np.float32), np.array(v, np.float32)
+        for leaf in (k, v):
+            picked = leaf[blocks]  # (rows, width, heads, bs, hd); rows share no live block
+            picked[np.broadcast_to(dead_key[:, :, None, :, None], picked.shape)] = np.nan
+            leaf[blocks] = picked
+    poisoned = _run(
+        "pallas", q, jnp.asarray(k, POOLS[pool]), jnp.asarray(v, POOLS[pool]),
+        table, base, scales, compute,
+    )
+    assert np.isfinite(poisoned).all()
+    np.testing.assert_array_equal(poisoned, clean)
 
 
 def test_impl_validation():

@@ -180,6 +180,40 @@ def test_direct_engine_drive_and_flush_return_to_the_asking_phase(gpt):
     assert engine.pipeline_stats()["phases"]["fetch_wait"]["entries"] == fetched + 1
 
 
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_live_block_steps_weights_each_active_slot_by_its_table_columns(gpt, paged):
+    """Beside ``active_slot_steps``: every dispatch adds, for each active slot,
+    the table columns its row holds keys in (``lens // block_size + 1``) — the
+    columns the paged kernel's bounded walk visits. A dense engine has no table
+    and adds nothing."""
+    model, variables = gpt
+    block = 4
+    engine = DecodeEngine(model, variables, num_slots=3, max_len=64, prefill_buckets=(4, 8, 16),
+                          prefix_block_size=block, paged=paged)
+    expected, slots = [0], [0]
+    dispatch = engine._dispatch_step
+
+    def counting_dispatch(lookahead):
+        lens = engine._lens_host[engine._active]
+        out = dispatch(lookahead)
+        expected[0] += int((lens // block + 1).sum()) * out[3]
+        slots[0] += len(lens) * out[3]
+        return out
+
+    engine._dispatch_step = counting_dispatch
+    engine.admit_many([([3, 1, 4, 1, 5, 9, 2, 6, 5], 9, {}), ([2, 7], 12, {})])
+    while engine._active.any():
+        engine.step()
+    stats = engine.pipeline_stats()
+    assert stats["active_slot_steps"] == slots[0] > 0
+    if paged:
+        assert stats["live_block_steps"] == expected[0]
+        # rows of 2 to 17 keys in 4-token blocks: one to five columns each
+        assert slots[0] < stats["live_block_steps"] <= 5 * slots[0]
+    else:
+        assert stats["live_block_steps"] == 0
+
+
 # ------------------------------------------------------------------------- fit
 
 

@@ -306,8 +306,14 @@ def test_tuned_block_tables_lower_for_tpu():
 # ------------------------------------------------------------ paged attention
 
 #: (heads, head_dim, block_size, table_width, batch): GPT-2 small as the server
-#: pages it (max_len 1024, 16-token blocks, 8 slots) and the unit tests' pool
-PAGED_SHAPES = {"gpt2_small": (12, 64, 16, 65, 8), "tiny": (2, 16, 4, 4, 3)}
+#: pages it (max_len 1024, 16-token blocks, 8 slots), GPT-2 medium as the
+#: benchmark's serving cell does (48 slots) and the unit tests' pool
+PAGED_SHAPES = {
+    "gpt2_small": (12, 64, 16, 65, 8),
+    "gpt2_medium": (16, 64, 16, 65, 48),
+    "tiny": (2, 16, 4, 4, 3),
+}
+REAL_SHAPES = ["gpt2_small", "gpt2_medium"]
 
 
 def _paged_case(shape, pool, mode):
@@ -315,10 +321,10 @@ def _paged_case(shape, pool, mode):
     decode (S=1) | chunk (batch 1, S=64 or 6) | verify (identity table over
     batch*width local blocks, int8 codes carried as f32)."""
     heads, head_dim, block_size, width, batch = PAGED_SHAPES[shape]
-    compute = jnp.bfloat16 if shape == "gpt2_small" else jnp.float32
+    compute = jnp.bfloat16 if shape in REAL_SHAPES else jnp.float32
     blocks, seq, code_dtype = batch * (width - 1) + 1, 1, jnp.int8
     if mode == "chunk":
-        batch, seq = 1, 64 if shape == "gpt2_small" else 6
+        batch, seq = 1, 64 if shape in REAL_SHAPES else 6
     elif mode == "verify":
         blocks, code_dtype = batch * width, jnp.float32
     quantized = pool == "int8"
@@ -370,13 +376,14 @@ def test_paged_attention_lowers_for_tpu(as_on_tpu, shape, pool, mode):
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
-def test_paged_attention_lowers_under_tensor_mesh(as_on_tpu, pool):
+@pytest.mark.parametrize("shape", REAL_SHAPES)
+def test_paged_attention_lowers_under_tensor_mesh(as_on_tpu, shape, pool):
     """The serving mesh's form of the call: the kernel shard_mapped over
     ``tensor`` (heads local) still lowers to a Mosaic call."""
     from unionml_tpu.parallel import make_mesh
 
     mesh = make_mesh({"data": 1, "tensor": 4}, devices=jax.devices()[:4])
-    compute, args = _paged_case("gpt2_small", pool, "decode")
+    compute, args = _paged_case(shape, pool, "decode")
     _assert_mosaic_lowered(_paged_fn(compute, mesh=mesh), *args)
 
 
@@ -400,19 +407,21 @@ def v5e_host():
 
 @pytest.mark.parametrize("mode", ["decode", "chunk", "verify"])
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
-def test_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, pool, mode):
+@pytest.mark.parametrize("shape", REAL_SHAPES)
+def test_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, shape, pool, mode):
     """Lowering is the first gate; this is the second: Mosaic compiles every
-    GPT-2-small variant to machine code for a v5e."""
+    GPT-2 small and medium variant to machine code for a v5e."""
     from jax.sharding import SingleDeviceSharding
 
-    compute, args = _paged_case("gpt2_small", pool, mode)
+    compute, args = _paged_case(shape, pool, mode)
     on_chip = SingleDeviceSharding(v5e_host[0])
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip) for a in args]
     assert jax.jit(_paged_fn(compute)).lower(*args).compile() is not None
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
-def test_paged_attention_partitions_over_tensor_mesh(as_on_tpu, v5e_host, pool):
+@pytest.mark.parametrize("shape", REAL_SHAPES)
+def test_paged_attention_partitions_over_tensor_mesh(as_on_tpu, v5e_host, shape, pool):
     """Under the serving mesh the kernel sits inside a multi-device jit on a
     head-sharded pool. Bare, the partitioner refuses it ("Mosaic kernels cannot
     be automatically partitioned"); under ``mesh=`` it is shard_mapped with
@@ -422,7 +431,7 @@ def test_paged_attention_partitions_over_tensor_mesh(as_on_tpu, v5e_host, pool):
 
     mesh = Mesh(np.asarray(v5e_host).reshape(1, 4), ("data", "tensor"))
     by_head = NamedSharding(mesh, P(None, "tensor", None, None))
-    compute, args = _paged_case("gpt2_small", pool, "decode")
+    compute, args = _paged_case(shape, pool, "decode")
     args = [
         jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=by_head if len(a.shape) == 4 else NamedSharding(mesh, P())

@@ -43,8 +43,9 @@ def test_distill_promotes_only_timing_valid_and_safe(tmp_path):
 
 def test_distill_paged_verdicts_and_heads(tmp_path):
     """Paged sweep → rank-4 verdicts: ties break toward PALLAS (the byte-model
-    default), the int8 entry wins the shared dispatch key, and the winning
-    heads-per-step tiling rides along."""
+    default) and the int8 entry wins the shared dispatch key. The kernel sizes
+    its own grid, so no tiling rides along — an older artifact's
+    ``heads_per_step`` is read past."""
     from tools.promote_tuning import distill_paged
 
     (tmp_path / "PAGED_KERNEL_BENCH.json").write_text(json.dumps({
@@ -68,14 +69,14 @@ def test_distill_paged_verdicts_and_heads(tmp_path):
         "32,16,12,64": "pallas",
         "8,16,16,128": "xla",
     }
-    assert overlay["paged_tuned_heads"]["16,16,12,64"] == 4
+    assert set(overlay) == {"measured_paged_impl"}
 
     # a CPU correctness artifact contributes nothing
     (tmp_path / "PAGED_KERNEL_BENCH.json").write_text(json.dumps({
         "timing_valid": False,
         "results": {"w16_bs16_h12_d64_int8": {"verdict": "use_pallas"}},
     }))
-    assert distill_paged(tmp_path) == {"measured_paged_impl": {}, "paged_tuned_heads": {}}
+    assert distill_paged(tmp_path) == {"measured_paged_impl": {}}
 
 
 def test_promote_merges_with_existing_overlay(tmp_path):
@@ -114,9 +115,10 @@ def test_overlay_merges_into_tables(tmp_path, monkeypatch):
         "measured_packed_impl": {"128,128,64": "pallas"},
         "measured_impl": {"4096,4096,64": "pallas"},
         "tuned_blocks": {"4096,4096,64": [512, 512]},
-        # rank-4 paged tables, with malformed entries that must be dropped
+        # rank-4 paged verdicts, with malformed entries that must be dropped
         "measured_paged_impl": {"16,16,12,64": "xla", "16,16,12": "pallas",
                                 "32,16,12,64": "cuda"},
+        # a key from before the kernel sized its own grid: read past, no error
         "paged_tuned_heads": {"16,16,12,64": 4, "32,16,12,64": True},
     }
     path = tmp_path / "TUNING_MEASURED.json"
@@ -139,8 +141,10 @@ def test_overlay_merges_into_tables(tmp_path, monkeypatch):
         # paged: the measured demotion lands; malformed keys/values are dropped
         assert tuning.pick_paged_impl(16, 16, 12, 64) == "xla"
         assert tuning.pick_paged_impl(32, 16, 12, 64) == tuning.DEFAULT_PAGED_IMPL
-        assert tuning.pick_paged_heads(16, 16, 12, 64) == 4
-        assert tuning.pick_paged_heads(32, 16, 12, 64) == 1  # bool rejected
+        # the overlay above still carries "paged_tuned_heads": it loaded, and
+        # nothing of the table is left to receive it
+        assert not hasattr(tuning, "PAGED_TUNED_HEADS")
+        assert not hasattr(tuning, "pick_paged_heads")
     finally:
         monkeypatch.undo()
         importlib.reload(tuning)  # restore the real tables for later tests

@@ -51,7 +51,6 @@ _TABLES = (
     "tuned_blocks",
     "packed_tuned_blocks",
     "measured_paged_impl",
-    "paged_tuned_heads",
 )
 
 
@@ -71,14 +70,14 @@ def _paged_shape_key(name: str):
 
 
 def distill_paged(repo: pathlib.Path = REPO) -> dict:
-    """PAGED_KERNEL_BENCH.json → measured_paged_impl / paged_tuned_heads.
+    """PAGED_KERNEL_BENCH.json → measured_paged_impl.
 
     The paged default is PALLAS (the byte model carries the burden of proof the
     other way — see ``tuning.DEFAULT_PAGED_IMPL``), so the tie margin demotes
     toward pallas here: XLA must beat the kernel by >2% to claim the shape.
     Both pool dtypes share one dispatch key; the int8 verdict wins conflicts
     (it is the serving configuration the pool exists for)."""
-    overlay = {"measured_paged_impl": {}, "paged_tuned_heads": {}}
+    overlay = {"measured_paged_impl": {}}
     results = _load(repo / "PAGED_KERNEL_BENCH.json")
     if results is None:
         return overlay
@@ -104,8 +103,6 @@ def distill_paged(repo: pathlib.Path = REPO) -> dict:
         overlay["measured_paged_impl"][key] = (
             "pallas" if verdict == "use_pallas" else "xla"
         )
-        if "heads_per_step" in best:
-            overlay["paged_tuned_heads"][key] = best["heads_per_step"]
     return overlay
 
 

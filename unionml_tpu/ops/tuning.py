@@ -74,14 +74,15 @@ BLOCK_CANDIDATES: Tuple[int, ...] = (512, 256, 128, 64)
 
 #: measured pallas-vs-XLA verdicts for the PAGED decode kernel
 #: (:mod:`unionml_tpu.ops.paged_attention`). Shape class:
-#: ``(table_width, block_size, heads, head_dim)`` — the four axes that fix the
-#: kernel's grid and per-step DMA. An entry is an explicit verdict: "xla" where
+#: ``(table_width, block_size, heads, head_dim)``. The kernel's own tiling (heads
+#: and table entries a grid step) is no table here: ``paged_attention._tiling``
+#: reckons it from the call's shapes. An entry is an explicit verdict: "xla" where
 #: the kernel lost a ``bench_kernels.py --paged`` sweep, or where Mosaic refused
 #: to compile the shape (the compiler's message goes beside the entry). There
 #: is no runtime fallback between the arms — this table is the only way a
 #: shape leaves the kernel. On the v5e, chip_smoke.py compiles and checks the
-#: kernel at GPT-2 small's class (65, 16, 12, 64) over bf16 and int8 pools:
-#: both run it, so the table is empty.
+#: kernel at GPT-2 small's class (65, 16, 12, 64) and GPT-2 medium's
+#: (65, 16, 16, 64) over bf16 and int8 pools: all run it, so the table is empty.
 MEASURED_PAGED_IMPL: Dict[Tuple[int, int, int, int], str] = {}
 
 #: unmeasured paged shapes default to the KERNEL — deliberately the opposite of
@@ -97,20 +98,6 @@ def pick_paged_impl(table_width: int, block_size: int, heads: int, head_dim: int
     return MEASURED_PAGED_IMPL.get(
         (table_width, block_size, heads, head_dim), DEFAULT_PAGED_IMPL
     )
-
-
-#: measured winners for the paged kernel's one tiling knob: heads folded into a
-#: single grid step (amortizes grid/DMA overhead when blocks are small). 1 is
-#: the proven-lowering default (plain 2D MXU dots); sweeps promote larger.
-PAGED_TUNED_HEADS: Dict[Tuple[int, int, int, int], int] = {}
-
-
-def pick_paged_heads(table_width: int, block_size: int, heads: int, head_dim: int) -> int:
-    """Heads per grid step for a paged shape class (measured winner, else 1)."""
-    tuned = PAGED_TUNED_HEADS.get((table_width, block_size, heads, head_dim))
-    if tuned and heads % tuned == 0:
-        return tuned
-    return 1
 
 
 def _largest_dividing(seq: int, cap: int = 128) -> int:
@@ -216,13 +203,13 @@ def _apply_measured_overlay() -> None:
     for shape, blocks in parse(overlay.get("packed_tuned_blocks")).items():
         if valid_blocks(blocks):
             PACKED_TUNED_BLOCKS[shape] = tuple(blocks)
-    # paged-decode kernel tables: 4-axis keys "table_width,block_size,heads,head_dim"
+    # paged-decode kernel verdicts: 4-axis keys "table_width,block_size,heads,head_dim".
+    # (The kernel has no tiling table: it sizes its grid from the shapes it is
+    # called with. An older overlay's "paged_tuned_heads" is ignored, like any
+    # other key this function does not know.)
     for shape, impl in parse(overlay.get("measured_paged_impl"), rank=4).items():
         if valid_impl(impl):
             MEASURED_PAGED_IMPL[shape] = impl
-    for shape, gh in parse(overlay.get("paged_tuned_heads"), rank=4).items():
-        if isinstance(gh, int) and not isinstance(gh, bool) and gh > 0:
-            PAGED_TUNED_HEADS[shape] = gh
 
 
 _apply_measured_overlay()

@@ -464,6 +464,11 @@ class DecodeEngine:
         #: with ``tokens_decoded`` and ``step_dispatches`` it differences into
         #: a window's occupancy
         self.active_slot_steps = 0
+        #: paged engines: the same sum with every active slot weighted by the
+        #: table columns its row holds keys in (``lens // block_size + 1``).
+        #: Over ``active_slot_steps x table width`` it is the share of the block
+        #: table the paged kernel's bounded walk still visits
+        self.live_block_steps = 0
         self._last_fetch_done: Optional[float] = None
         #: what the loop thread that drives this engine is doing (see
         #: :data:`LOOP_PHASES`); the batcher drives the same instance
@@ -2243,7 +2248,7 @@ class DecodeEngine:
     def pipeline_stats(self) -> Dict[str, Any]:
         """Pipeline observability for ``GET /stats``: configured depth, whether a
         step is currently in flight, the dispatch/idle counters, the active-slot
-        integral and the loop thread's phase counters
+        and live-block integrals and the loop thread's phase counters
         (:meth:`~unionml_tpu.profiling.PhaseTimeline.snapshot`). Everything but
         ``inflight`` only grows, so two reads difference into a window."""
         return {
@@ -2252,6 +2257,7 @@ class DecodeEngine:
             "step_dispatches": self.step_dispatches,
             "idle_dispatches": self.idle_dispatches,
             "active_slot_steps": self.active_slot_steps,
+            "live_block_steps": self.live_block_steps,
             "phases": self.timeline.snapshot(),
         }
 
@@ -2573,6 +2579,10 @@ class DecodeEngine:
         # steady-state tick performs ZERO host→device transfers (pinned by the
         # transfer-guard regression test).
         active = int(np.count_nonzero(self._active))
+        live_blocks = (
+            int(np.sum(self._lens_host[self._active] // self._prefix_block_size + 1))
+            if self.paged else 0
+        )
         timeline.enter("dispatch", active=active)
         device_was_idle = self._inflight is None
         try:
@@ -2588,6 +2598,7 @@ class DecodeEngine:
                 self._last_logits = self._last_logits.at[bad_slot].set(jnp.nan)
         self.step_dispatches += 1
         self.active_slot_steps += active * lookahead
+        self.live_block_steps += live_blocks * lookahead
         if device_was_idle and self._last_fetch_done is not None:
             self.idle_dispatches += 1
         previous, prev_skip = self._inflight, self._inflight_skip
