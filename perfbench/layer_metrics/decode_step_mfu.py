@@ -1,9 +1,7 @@
 """The decode step's share of the chip's bf16 peak: the FLOPs the rows that
-decoded in the traced interval needed (``costs.decode_flops`` over their live
-lengths, from the client's stamps) over the decode-step programs' device time
+decoded in the traced interval needed (the family's ``decode_flops`` over their
+live lengths, from the client's stamps) over the decode-step programs' device time
 there times the peak."""
-
-from perfbench import costs
 
 
 def read(ctx):
@@ -13,5 +11,5 @@ def read(ctx):
     seconds = sum(e - s for s, e in runs)
     if seconds <= 0 or not rows:
         return None
-    flops = costs.decode_flops(ctx["config"], rows)
+    flops = ctx["family"].decode_flops(ctx["config"], rows)
     return 100.0 * flops / (seconds * ctx["peaks"]["bf16_flops_per_s"])

@@ -3,15 +3,30 @@
 A cell is a ``workloads`` entry: a configuration (``configs[].file``), a traffic
 mix (``<path>/traffic/<traffic>.json``), the cell's limits for ``correct``
 (``<path>/limits/<cell>.json``) and the per-layer metrics that list it, each
-read by ``<path>/layer_metrics/<metric>.py``. ``<path>`` is any directory in
-``paths``, so a later PR brings a cell in a directory of its own.
+read by ``<path>/layer_metrics/<metric>.py``. The configuration names its
+architecture's family (``perfbench.family``), whose adapter is
+``<path>/families/<family>.py``, and its plain reference
+(``perfbench.reference``, a file under a directory of ``paths``). ``<path>`` is
+any directory in ``paths``, so a later PR brings a cell, and a model the
+harness has never seen, in a directory of its own.
+
+The adapter is the one place that knows an architecture; all of what the
+drivers and the readers ask it: ``model(config)``, ``make_params(config, seed,
+dtype)``, ``reference_kwargs(config)``, ``architecture_leaves(tree)`` and the
+counts ``decode_flops(config, live_lengths)``, ``decode_attention_bytes(config,
+live_lengths, kv_bytes, act_bytes)``, ``train_flops_per_token(config,
+mean_keys)``. The reference brings ``logits_at`` for serving, ``loss_and_grads``
+and ``adamw_step`` for training. A family brings what its cells' kind asks for.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
+import re
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -32,18 +47,22 @@ class Cell:
         if not entry:
             raise KeyError(f"workload {name!r} names no known config")
         self.config_entry = entry[0]
-        config_file = root / self.config_entry["file"]
-        self.config = _load_json(config_file if config_file.is_file() else ROOT / self.config_entry["file"])
+        self.config = _load_json(self._file(self.config_entry["file"]) or root / self.config_entry["file"])
         self.mix_path = self.find(f"traffic/{self.workload['traffic']}.json")
         self.mix = _load_json(self.mix_path)
         self.limits = _load_json(self.find(f"limits/{name}.json"))
 
+    def _file(self, relative: str) -> Optional[Path]:
+        for root in (self.root, ROOT):  # a manifest kept elsewhere still finds the harness
+            if (root / relative).is_file():
+                return root / relative
+        return None
+
     def find(self, relative: str) -> Path:
         for base in self.manifest["paths"]:
-            for root in (self.root, ROOT):  # a manifest kept elsewhere still finds the harness
-                candidate = root / base / relative
-                if candidate.is_file():
-                    return candidate
+            found = self._file(f"{base}/{relative}")
+            if found is not None:
+                return found
         raise FileNotFoundError(f"{relative} is under none of {self.manifest['paths']}")
 
     def _listed(self, metric: Dict[str, Any], moved_ok: bool) -> bool:
@@ -59,13 +78,37 @@ class Cell:
         return [m for m in self.manifest["per_layer"] if self._listed(m, m["moves"] in mine)]
 
     def reader(self, metric: str) -> Callable[[Dict[str, Any]], Optional[float]]:
-        path = self.find(f"layer_metrics/{metric}.py")
-        spec = importlib.util.spec_from_file_location(
-            "perfbench_reader_" + metric.replace(".", "_").replace("-", "_"), path
-        )
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return module.read
+        return load_module(self.find(f"layer_metrics/{metric}.py")).read
+
+    def family(self) -> ModuleType:
+        """The adapter of the configuration's architecture."""
+        return load_module(self.find(f"families/{self.config['perfbench']['family']}.py"))
+
+    def reference(self) -> ModuleType:
+        """The configuration's plain reference, at the path its file gives."""
+        relative = self.config["perfbench"]["reference"]
+        if not any(relative.startswith(base + "/") for base in self.manifest["paths"]):
+            raise FileNotFoundError(f"{relative} is under none of {self.manifest['paths']}")
+        found = self._file(relative)
+        if found is None:
+            raise FileNotFoundError(f"{relative}, the configuration's reference, is no file")
+        return load_module(found)
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: Path) -> ModuleType:
+    """The Python file at ``path`` as a module, by its location and once a
+    process (so that what it jits stays compiled). A function that is asked of
+    it and missing is an error that names the file and the function."""
+    spec = importlib.util.spec_from_file_location("perfbench_file_" + re.sub(r"\W", "_", str(path)), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+
+    def missing(name: str) -> Any:
+        raise AttributeError(f"{path} brings no {name!r}")
+
+    module.__dict__.setdefault("__getattr__", missing)
+    return module
 
 
 def _load_json(path: Path) -> Dict[str, Any]:

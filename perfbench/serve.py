@@ -5,7 +5,9 @@ What is timed is ``POST /generate`` with ``stream=true`` on
 ``build_aiohttp_app(generator=DecodeEngine(...))`` — the entry a user starts
 with ``unionml-tpu serve``. The driver takes from the program the app, its
 ``/stats`` counters and its request traces; weights, traffic, clocks, the trace
-reduction and the reference are the benchmark's.
+reduction and the reference are the benchmark's. What depends on the model's
+architecture is asked of the configuration's family (``cell.family()``) and of
+its reference (``cell.reference()``).
 """
 
 from __future__ import annotations
@@ -27,10 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench import common, traffic, weights
+from perfbench import common, traffic
 from perfbench.loadgen import GRACE_S
-from perfbench.program import program_config
-from perfbench.reference import gpt2 as reference
 
 HERE = Path(__file__).resolve().parent
 
@@ -88,14 +88,13 @@ class Server:
             raise RuntimeError("server thread did not stop within 120 s")
 
 
-def build_app(config: Dict[str, Any], params: Any):
-    from unionml_tpu.models.gpt import GPTLMHeadModel
+def build_app(config: Dict[str, Any], params: Any, family: Any):
     from unionml_tpu.serving import build_aiohttp_app
     from unionml_tpu.serving.continuous import DecodeEngine
     from unionml_tpu.serving.telemetry import Telemetry
 
     deployment = config["perfbench"]
-    model = GPTLMHeadModel(program_config(config))
+    model = family.model(config)
     variables = {"params": params}
 
     def engine():
@@ -313,7 +312,8 @@ def check(result: Dict[str, Any], cell, params: Any, seed: int, control: bool) -
     sample = sample_finished(result, seed, int(limits["sample_requests"]))
     pad_to = int(limits["reference_pad_to"])
     max_rows = int(cell.mix["output_tokens"].get("max", cell.mix["output_tokens"].get("value", 0)))
-    kw = dict(num_heads=config["n_head"], eps=config["layer_norm_epsilon"])
+    reference = cell.reference()
+    kw = cell.family().reference_kwargs(config)
     worst = 0.0
     controls = tuple(limits["controls"]) if control else ()
     worst_control = {mode: 0.0 for mode in controls}
@@ -366,10 +366,11 @@ def run(cell, args, t0: float) -> Dict[str, Any]:
     phases.mark("imported")
     config = cell.config
     deployment = config["perfbench"]
-    params = weights.make_params(config, args.seed, deployment["weights_dtype"])
+    family = cell.family()
+    params = family.make_params(config, args.seed, deployment["weights_dtype"])
     jax.block_until_ready(params)
     phases.mark("weights")
-    app = build_app(config, params)
+    app = build_app(config, params, family)
     server = Server(app)
     phases.mark("server_ready")
     tracer = common.Tracer() if args.trace else None
@@ -392,7 +393,7 @@ def run(cell, args, t0: float) -> Dict[str, Any]:
     checked = check(result, cell, params, args.seed, control=bool(args.control))
     phases.mark("checked")
     context = {
-        "cell": cell, "config": config, "mix": cell.mix, "load": result, "e2e": e2e,
+        "cell": cell, "config": config, "family": family, "mix": cell.mix, "load": result, "e2e": e2e,
         "trace": tracer.summary if tracer is not None else None,
         "trace_interval": tracer.interval if tracer is not None else None,
         "compiles_in_window": result["compiles_in_window"], "warm_up": warm,

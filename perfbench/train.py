@@ -6,11 +6,14 @@ first steps with the window's own call and feed (which compiles the step and
 gives the check its readings), calibrates how many steps make a call of 2-3 s,
 and packs the window's documents. The window then calls ``fit`` until
 ``--seconds`` have passed; the last call ends with ``block_until_ready``, and
-the rate is taken over all tokens and all the time up to there.
+the rate is taken over all tokens and all the time up to there. What depends on
+the model's architecture is asked of the configuration's family
+(``cell.family()``) and of its reference (``cell.reference()``).
 """
 
 from __future__ import annotations
 
+import functools
 import gc
 import statistics
 import time
@@ -21,35 +24,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from perfbench import common, costs, traffic, weights
-from perfbench.program import program_config
-from perfbench.reference import gpt2 as reference
 
 FOLLOWED_STEPS = 3  # the reference follows the program through this many steps
 
 
-def architecture_leaves(tree: Any) -> Any:
-    """The tree with each fused ``qkv`` leaf as its query, key and value thirds:
-    the architecture's leaves. The key's bias, whose gradient is nought under
-    softmax, must be a leaf of its own for the rule that leaves it out."""
-    def split(path, leaf):
-        if any(getattr(key, "key", None) == "qkv" for key in path):
-            q, k, v = jnp.split(leaf, 3, axis=-1)
-            return {"q": q, "k": k, "v": v}
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(split, tree)
+@functools.partial(jax.jit, static_argnames=("leaves",))
+def leaf_norms(tree: Any, leaves: Callable[[Any], Any]) -> Any:
+    """The norm of each of the architecture's leaves: ``leaves`` is the family's
+    ``architecture_leaves``, which splits what the program keeps fused."""
+    return jax.tree.map(lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), leaves(tree))
 
 
-@jax.jit
-def leaf_norms(tree: Any) -> Any:
-    return jax.tree.map(
-        lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), architecture_leaves(tree)
+@functools.partial(jax.jit, static_argnames=("leaves",))
+def delta_norms(after: Any, before: Any, leaves: Callable[[Any], Any]) -> Any:
+    return leaf_norms(
+        jax.tree.map(lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), after, before), leaves
     )
-
-
-@jax.jit
-def delta_norms(after: Any, before: Any) -> Any:
-    return leaf_norms(jax.tree.map(lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), after, before))
 
 
 def adam_state(opt_state: Any) -> Any:
@@ -75,9 +65,10 @@ class StepRecorder:
     """
 
     def __init__(self, step: Callable, initial_params: Callable[[], Any],
-                 fault: Optional[str] = None) -> None:
+                 leaves: Callable[[Any], Any], fault: Optional[str] = None) -> None:
         self._step = step
         self._initial_params = initial_params
+        self._leaves = leaves
         self.fault = fault
         self.calls = 0
         self.fed: List[Dict[str, Any]] = []  # first steps: the whole batch
@@ -106,9 +97,9 @@ class StepRecorder:
         if self.calls <= FOLLOWED_STEPS:
             self.losses.append(metrics["loss"])
         if self.calls == 1:
-            self.first_moment_norms = leaf_norms(adam_state(state.opt_state).mu)
+            self.first_moment_norms = leaf_norms(adam_state(state.opt_state).mu, self._leaves)
         if self.calls == FOLLOWED_STEPS:
-            self.delta_norms = delta_norms(state.params, self._initial_params())
+            self.delta_norms = delta_norms(state.params, self._initial_params(), self._leaves)
         self.last_metrics = metrics
         return state, metrics
 
@@ -162,11 +153,14 @@ def _flat(tree: Any) -> Dict[str, float]:
     return {jax.tree_util.keystr(path): float(value) for path, value in leaves}
 
 
-def follow(config: Dict[str, Any], params: Any, batches: List[Dict[str, Any]],
-           trainer: Dict[str, Any], lowp: Optional[str] = None, half_batch: bool = False) -> Dict[str, Any]:
-    """The reference through the first steps: losses, the clipped first
+def follow(cell, params: Any, batches: List[Dict[str, Any]],
+           lowp: Optional[str] = None, half_batch: bool = False) -> Dict[str, Any]:
+    """The cell's reference through the first steps: losses, the clipped first
     gradient's norms by leaf and the norms of the parameters' change."""
-    kw = dict(num_heads=config["n_head"], eps=config["layer_norm_epsilon"])
+    reference, family = cell.reference(), cell.family()
+    trainer = cell.config["perfbench"]["trainer"]
+    kw = family.reference_kwargs(cell.config)
+    leaves = family.architecture_leaves
     mu = jax.tree.map(jnp.zeros_like, params)
     nu = jax.tree.map(jnp.zeros_like, params)
     start = params
@@ -184,9 +178,9 @@ def follow(config: Dict[str, Any], params: Any, batches: List[Dict[str, Any]],
         )
         losses.append(float(loss))
         if k == 1:
-            first_grad = _flat(leaf_norms(clipped))
+            first_grad = _flat(leaf_norms(clipped, leaves))
     return {"losses": losses, "grad_norms": first_grad,
-            "delta_norms": _flat(delta_norms(params, start))}
+            "delta_norms": _flat(delta_norms(params, start, leaves))}
 
 
 def compare(program: Dict[str, Any], ref: Dict[str, Any]) -> Dict[str, float]:
@@ -204,25 +198,24 @@ def compare(program: Dict[str, Any], ref: Dict[str, Any]) -> Dict[str, float]:
 
 
 def check(recorder: StepRecorder, cell, seed: int, control: bool) -> Dict[str, Any]:
-    config, limits = cell.config, cell.limits
-    trainer = config["perfbench"]["trainer"]
+    limits = cell.limits
     b1 = 0.9
     program = {
         "losses": [float(x) for x in recorder.losses],
         "grad_norms": {k: v / (1 - b1) for k, v in _flat(recorder.first_moment_norms).items()},
         "delta_norms": _flat(recorder.delta_norms),
     }
-    params = weights.make_params(config, seed, "float32")
+    params = cell.family().make_params(cell.config, seed, "float32")
     batches = [{k: np.asarray(v) for k, v in b.items()} for b in recorder.fed]
-    ref = follow(config, params, batches, trainer)
+    ref = follow(cell, params, batches)
     numbers = {k: [v, limits.get(k)] for k, v in compare(program, ref).items()}
     correct = all(limit is None or value <= limit for value, limit in numbers.values())
     correct = correct and all(np.isfinite(value) for value, _ in numbers.values())
     if control:
         for mode in limits["controls"]:
-            low = compare(follow(config, params, batches, trainer, lowp=mode), ref)
+            low = compare(follow(cell, params, batches, lowp=mode), ref)
             numbers.update({f"control_{mode}_{k}": [v, None] for k, v in low.items()})
-        half = compare(follow(config, params, batches, trainer, half_batch=True), ref)
+        half = compare(follow(cell, params, batches, half_batch=True), ref)
         numbers.update({f"halfbatch_{k}": [v, None] for k, v in half.items()})
     return {"correct": bool(correct), "numbers": numbers}
 
@@ -235,23 +228,22 @@ def first_steps(cell, seed: int, step: Optional[Callable] = None, fault: Optiona
     compiled step behind its recorder, and the call into ``fit``; driven from the
     seed through the first steps, the ones the reference follows."""
     from unionml_tpu.models import create_train_state
-    from unionml_tpu.models.gpt import GPTLMHeadModel
     from unionml_tpu.models.training import fit, make_lm_train_step
 
-    config, mix = cell.config, cell.mix
+    config, mix, family = cell.config, cell.mix, cell.family()
     trainer = config["perfbench"]["trainer"]
     rows, seq_len, vocab = int(trainer["rows_per_step"]), int(trainer["seq_len"]), config["vocab_size"]
 
     def initial_params():
-        return weights.make_params(config, seed, "float32")
+        return family.make_params(config, seed, "float32")
 
-    model = GPTLMHeadModel(program_config(config))
     state = create_train_state(
-        model, {"params": initial_params()}, learning_rate=trainer["learning_rate"],
+        family.model(config), {"params": initial_params()}, learning_rate=trainer["learning_rate"],
         weight_decay=trainer["weight_decay"], warmup_steps=0,
         max_grad_norm=trainer["max_grad_norm"], rng=weights.seed_key(seed, 1),
     )
-    recorder = StepRecorder(step or make_lm_train_step(packed=True), initial_params, fault=fault)
+    recorder = StepRecorder(step or make_lm_train_step(packed=True), initial_params,
+                            family.architecture_leaves, fault=fault)
 
     def call_fit(state, data):
         return fit(state, data, batch_size=rows, num_epochs=1, prefetch=True,
@@ -330,7 +322,7 @@ def run(cell, args, t0: float) -> Dict[str, Any]:
     checked = check(recorder, cell, args.seed, control=bool(args.control))
     phases.mark("checked")
     context = {
-        "cell": cell, "config": config, "mix": mix, "fed": fed, "e2e": e2e,
+        "cell": cell, "config": config, "family": cell.family(), "mix": mix, "fed": fed, "e2e": e2e,
         "trace": tracer.summary if tracer is not None else None,
         "trace_interval": tracer.interval if tracer is not None else None,
         "compiles_in_window": compiles, "phases": phases.marks,

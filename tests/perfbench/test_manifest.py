@@ -1,6 +1,8 @@
 """``BENCHMARK.json`` keeps to the contract, and every cell's files are found
-by the names in it; a new cell arrives as files plus one entry."""
+by the names in it, its architecture's adapter and reference among them; a new
+cell arrives as files plus one entry."""
 
+import ast
 import json
 import re
 from pathlib import Path
@@ -14,6 +16,15 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 WIDTHS = re.compile(r"(hidden|intermediate|latent|state|proj|_dim$|_rank$|head_size|n_embd|n_inner|expan)")
+
+
+#: what a cell of each kind asks of its family's adapter, and of its reference
+ASKED = {
+    "serve": (("model", "make_params", "reference_kwargs", "decode_flops", "decode_attention_bytes"),
+              ("logits_at",)),
+    "train": (("model", "make_params", "reference_kwargs", "architecture_leaves", "train_flops_per_token"),
+              ("loss_and_grads", "adamw_step")),
+}
 
 
 @pytest.fixture(scope="module")
@@ -113,3 +124,57 @@ def test_a_cell_is_added_by_files_and_one_entry(tmp_path):
     assert cell.reader("answer")({}) == 42.0
     with pytest.raises(KeyError):
         tiny.cell(root, "no.such.cell")
+
+
+@pytest.mark.parametrize("toy", [False, True], ids=["the benchmark", "the tests' toy root"])
+def test_every_configuration_names_its_family_and_its_reference(toy, tmp_path):
+    root = tiny.make_root(tmp_path) if toy else manifest.ROOT
+    bench = manifest.load(root)
+    for entry in bench["workloads"]:
+        cell = manifest.Cell(bench, entry["name"], root=root)
+        settings = cell.config["perfbench"]
+        assert isinstance(cell.config["vocab_size"], int), "the load generator is handed it"
+        family = cell.family()
+        assert Path(family.__file__).name == settings["family"] + ".py"
+        of_family, of_reference = ASKED[settings["kind"]]
+        for name in of_family:
+            assert callable(getattr(family, name)), (settings["family"], name)
+        # the reference: a file under paths that brings what the check calls and
+        # imports nothing of the program or of a family (read, not run)
+        assert any(settings["reference"].startswith(base + "/") for base in bench["paths"])
+        tree = ast.parse((manifest.ROOT / settings["reference"]).read_text())
+        imported, brought = [], set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                imported += [alias.name for alias in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                imported += [f"{node.module}.{alias.name}" for alias in node.names]
+        for node in tree.body:
+            if isinstance(node, ast.FunctionDef):
+                brought.add(node.name)
+            elif isinstance(node, ast.Assign):
+                brought |= {target.id for target in node.targets if isinstance(target, ast.Name)}
+        assert set(of_reference) <= brought, (settings["reference"], of_reference)
+        for name in imported:
+            assert name.split(".")[0] != "unionml_tpu" and not name.startswith("perfbench.families"), name
+
+
+def test_what_is_named_and_not_there_is_an_error_that_says_where(tmp_path):
+    root = tiny.make_root(tmp_path)
+    cell = tiny.cell(root, "toy.train")
+    cell.config["perfbench"]["family"] = "nobody"
+    with pytest.raises(FileNotFoundError, match=r"families/nobody\.py is under none of"):
+        cell.family()
+    cell.config["perfbench"]["reference"] = "unionml_tpu/models/gpt.py"  # a file, but of the program
+    with pytest.raises(FileNotFoundError, match=r"unionml_tpu/models/gpt\.py is under none of"):
+        cell.reference()
+    cell.config["perfbench"]["reference"] = tiny.TOYBENCH + "/reference/nothing.py"
+    with pytest.raises(FileNotFoundError, match=r"reference/nothing\.py"):
+        cell.reference()
+    # a serving family need not bring what training asks: asking is the error
+    bare = tmp_path / "bare.py"
+    bare.write_text("def logits_at():\n    return 1\n")
+    module = manifest.load_module(bare)
+    assert module.logits_at() == 1 and not hasattr(module, "loss_and_grads")
+    with pytest.raises(AttributeError, match=r"bare\.py brings no 'loss_and_grads'"):
+        module.loss_and_grads

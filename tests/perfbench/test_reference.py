@@ -1,17 +1,20 @@
-"""The plain float32 reference against the program's ``GPTLMHeadModel`` at a
-toy size on the CPU, and the controls against the reference."""
+"""Each family's plain float32 reference against the model its adapter builds,
+at a toy size on the CPU, and the controls against the reference."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench import weights
-from perfbench.program import program_config
 from perfbench.reference import gpt2 as reference
 from tests.perfbench import tiny
 
 KW = dict(num_heads=tiny.TINY_SIZES["n_head"], eps=1e-5)
+gpt2 = tiny.gpt2_family()
+
+
+def token_ids(vocab):
+    return jnp.asarray(np.random.default_rng(0).integers(0, vocab, (2, 48)).astype(np.int32))
 
 
 @pytest.fixture(scope="module")
@@ -19,21 +22,23 @@ def toy():
     config = {**tiny.TINY_SIZES, "layer_norm_epsilon": 1e-5, "resid_pdrop": 0.0,
               "perfbench": {"compute_dtype": "float32", "init": {"kernel_std": None,
                             "residual_std": None, "qk_gain": 2.0}}}
-    params = weights.make_params(config, 7, "float32")
-    ids = np.random.default_rng(0).integers(0, config["vocab_size"], (2, 48)).astype(np.int32)
-    return config, params, jnp.asarray(ids)
+    assert gpt2.reference_kwargs(config) == KW
+    return config, gpt2.make_params(config, 7, "float32"), token_ids(config["vocab_size"])
 
 
-def test_forward_agrees_with_the_programs_model(toy):
-    from unionml_tpu.models.gpt import GPTLMHeadModel
-
-    config, params, ids = toy
-    model = GPTLMHeadModel(program_config(config))
+@pytest.mark.parametrize("name", ["tiny.closed", "toy.closed"])
+def test_forward_agrees_with_the_programs_model(name, tmp_path):
+    """What the cell's reference computes from its family's weights and
+    arguments is what the model its family builds computes."""
+    cell = tiny.cell(tiny.make_root(tmp_path), name)
+    family, config = cell.family(), cell.config
+    params, ids = family.make_params(config, 7, "float32"), token_ids(config["vocab_size"])
     with jax.default_matmul_precision("highest"):
-        program = model.apply({"params": params}, ids)
+        program = family.model(config).apply({"params": params}, ids)
     program = program[0] if isinstance(program, tuple) else program
     for row in range(ids.shape[0]):
-        ours = reference.logits_at(params, ids[row : row + 1], jnp.arange(ids.shape[1]), **KW)
+        ours = cell.reference().logits_at(params, ids[row : row + 1], jnp.arange(ids.shape[1]),
+                                          **family.reference_kwargs(config))
         np.testing.assert_allclose(np.asarray(program[row]), np.asarray(ours), atol=2e-4)
 
 

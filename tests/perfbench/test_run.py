@@ -11,11 +11,10 @@ import json
 import shutil
 import subprocess
 import sys
-import time
 
 import pytest
 
-from perfbench import manifest, run
+from perfbench import manifest
 from tests.perfbench import tiny
 
 REQUIRED = ["correct", "attempted", "failed", "metrics", "device"]
@@ -26,11 +25,7 @@ def root(tmp_path_factory):
     return tiny.make_root(tmp_path_factory.mktemp("bench"))
 
 
-def execute(root, name, trace=0, control=0, fault=None, seconds=1.0, seed=2**31 + 5):
-    args = run.parse(["--workload", name, "--seed", str(seed), "--seconds", str(seconds),
-                      "--trace", str(trace), "--control", str(control)])
-    args.fault = fault
-    return run.execute(tiny.cell(root, name), args, time.perf_counter(), peaks_for="TPU v5 lite")
+execute = tiny.execute
 
 
 def numbers(result):

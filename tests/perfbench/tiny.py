@@ -1,20 +1,28 @@
 """A benchmark root of toy cells for the CPU tests: the real ``BENCHMARK.json``
-with three more cells, each brought by files of its own in a directory of its
-own — which is how a later PR adds a cell."""
+with five more cells, each brought by files of its own in a directory of its
+own — which is how a later PR adds a cell. Three are toy sizes of the real
+configurations (``tinybench/``, written here). Two are of another family, the
+``toy`` family, whose files lie in ``toybench/`` beside this file as a later PR
+would commit them: configurations in another model's key names, the family's
+adapter, its reference, traffic and limits; here they get their entries."""
 
 from __future__ import annotations
 
 import copy
 import json
+import time
 from pathlib import Path
 from typing import Any, Dict
 
-from perfbench import manifest
+from perfbench import manifest, run
 
 TINY_SIZES = dict(n_embd=64, n_head=4, n_layer=4, n_positions=128, n_ctx=128, vocab_size=2048)
 #: hotter than the real cells' recipe: four toy layers have to amplify rounding as 24 real ones do
 TINY_SERVE_INIT = {"kernel_std": None, "residual_std": None, "gain": 1.5, "qk_gain": 3.0}
 
+
+#: the other family's directory, as ``paths`` names it
+TOYBENCH = "tests/perfbench/toybench"
 
 OPEN_LOOP_READERS = (("gen_late_p95_ms", "host_clock", "load generator"),
                      ("queue_wait_p95_ms", "program_span", "batcher and scheduler"),
@@ -29,7 +37,7 @@ def _write(path: Path, obj: Dict[str, Any]) -> None:
 def make_root(tmp: Path) -> Path:
     real = manifest.load()
     bench = copy.deepcopy(real)
-    bench["paths"] = real["paths"] + ["tinybench"]
+    bench["paths"] = real["paths"] + ["tinybench", TOYBENCH]
     base = manifest.ROOT / "perfbench"
 
     serve = json.loads((base / "configs" / "gpt2-medium-serve.json").read_text())
@@ -71,12 +79,17 @@ def make_root(tmp: Path) -> Path:
         "grad_norm_gap": 5e-4, "delta_norm_gap": 5e-4, "controls": ["bf16"],
     })
 
-    for name, why in (("tiny-serve", "toy serving"), ("tiny-train", "toy training")):
-        bench["configs"].append({"name": name, "source": "https://example.org/tiny",
-                                 "file": f"tinybench/configs/{name}.json", "reduced": [], "why": why})
+    for name, base, why in (("tiny-serve", "tinybench", "toy serving"), ("tiny-train", "tinybench", "toy training"),
+                            ("toy-serve", TOYBENCH, "another family, served"),
+                            ("toy-train", TOYBENCH, "another family, trained")):
+        source = "https://example.org/" + name.split("-")[0]
+        bench["configs"].append({"name": name, "source": source, "file": f"{base}/configs/{name}.json",
+                                 "reduced": [], "why": why})
     cells = {"tiny.closed": ("tiny-serve", "tiny-closed", ["serve_tokens_per_s"]),
              "tiny.open": ("tiny-serve", "tiny-open", ["itl_tail_mean_ms", "ttft_p90_ms"]),
-             "tiny.train": ("tiny-train", "tiny-docs", ["train_tokens_per_s"])}
+             "tiny.train": ("tiny-train", "tiny-docs", ["train_tokens_per_s"]),
+             "toy.closed": ("toy-serve", "toy-closed", ["serve_tokens_per_s"]),
+             "toy.train": ("toy-train", "toy-docs", ["train_tokens_per_s"])}
     known = {m["name"]: m for m in bench["end_to_end"]}
     for cell, (config, mix, reports) in cells.items():
         bench["workloads"].append({"name": cell, "config": config, "traffic": mix, "chips": 1,
@@ -102,3 +115,15 @@ def make_root(tmp: Path) -> Path:
 
 def cell(root: Path, name: str) -> manifest.Cell:
     return manifest.Cell(manifest.load(root), name, root=root)
+
+
+def gpt2_family():
+    return manifest.load_module(manifest.ROOT / "perfbench" / "families" / "gpt2.py")
+
+
+def execute(root: Path, name: str, trace=0, control=0, fault=None, seconds=1.0, seed=2**31 + 5) -> Dict[str, Any]:
+    """Everything of a run but the look for a chip, on a toy cell."""
+    args = run.parse(["--workload", name, "--seed", str(seed), "--seconds", str(seconds),
+                      "--trace", str(trace), "--control", str(control)])
+    args.fault = fault
+    return run.execute(cell(root, name), args, time.perf_counter(), peaks_for="TPU v5 lite")
