@@ -218,6 +218,29 @@ def phase_kernels() -> None:
             check_kernel(f"paged verify ({model}, {pool})", kernel, reference,
                          (queries(slots, 1), k, v, identity, base, *scales), BF16_TOL)
 
+    # the latent shape of the benchmark's sparse-decoder cell: 32 query heads
+    # over ONE key head whose rows (640 wide: 512 + 64 + padding) are the values
+    # too, 32 slots of 8192 positions in 16-token blocks, a 1024-token chunk
+    heads, row, slots, width = 32, 640, 32, 8192 // block_size + 1
+    blocks = slots * (width - 1) + 1
+    pool = jnp.asarray(rng.normal(size=(blocks, 1, block_size, row)), jnp.bfloat16)
+    table = rng.permutation(blocks - 1).reshape(slots, width - 1).astype(np.int32)
+    table = jnp.asarray(np.concatenate([table, np.full((slots, 1), blocks - 1, np.int32)], 1))
+    base = rng.integers(256, 7000, slots).astype(np.int32)
+    base[0] = (width - 1) * block_size
+    base = jnp.asarray(base)
+
+    def latent(impl):
+        def run(q, pool, table, base):
+            return paged_attention(q, pool, None, table, base, impl=impl, sm_scale=0.1)
+
+        return run
+
+    for name, rows, seq in (("decode S=1", slots, 1), ("chunk S=1024", 1, 1024)):
+        q = jnp.asarray(rng.normal(size=(rows, heads, seq, row)), jnp.bfloat16)
+        check_kernel(f"paged {name} (latent: 32 heads on 1 key row of 640, keys as values)",
+                     latent("pallas"), latent("xla"), (q, pool, table[:rows], base[-rows:]), BF16_TOL)
+
 
 class Server:
     """The aiohttp app on a real socket, served from a background thread."""

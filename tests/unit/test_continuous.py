@@ -529,3 +529,41 @@ def test_generate_route_sampling_params(gpt):
             await client.close()
 
     asyncio.new_event_loop().run_until_complete(main())
+
+
+def test_last_chunk_of_a_chunked_prefill_takes_the_smallest_bucket_that_holds_it(gpt_tiny_session):
+    """An 11-token prompt in chunks of 8: its last 3 tokens run through the
+    4-token program, not through 8 of which 5 are padding; the tokens are those
+    of the unchunked engine, paged and dense."""
+    _, model, variables = gpt_tiny_session
+    prompt = [(7 * i + 3) % 256 for i in range(11)]
+    want = DecodeEngine(model, variables, num_slots=1, max_len=64, prefill_buckets=(16,)).generate(prompt, 6)
+    for paged in (True, False):
+        engine = DecodeEngine(model, variables, num_slots=1, max_len=64, prefill_buckets=(4, 8, 16),
+                              prefill_chunk=8, paged=paged)
+        assert engine.generate(prompt, 6) == want
+        chunk_fn = engine._paged_chunk_fn if paged else engine._chunk_fn
+        assert chunk_fn._cache_size() == 2  # the (1, 8) and the (1, 4) program
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_a_prompt_that_chunks_serve_needs_no_bucket_that_holds_it_whole(gpt_tiny_session, paged):
+    """``prefill_chunk`` 8 with buckets of 4 and 8: a 19-token prompt runs as
+    chunks of 8, 8 and 4 and asks for no 32-token bucket (none is configured, so
+    no program of that width exists); its tokens are the unchunked engine's.
+    What no path takes is still refused: the same prompt without
+    ``prefill_chunk``, and one whose chunks overrun the slot."""
+    _, model, variables = gpt_tiny_session
+    prompt = [(5 * i + 1) % 256 for i in range(19)]
+    want = DecodeEngine(model, variables, num_slots=1, max_len=64, prefill_buckets=(32,)).generate(prompt, 5)
+    engine = DecodeEngine(model, variables, num_slots=1, max_len=64, prefill_buckets=(4, 8),
+                          prefill_chunk=8, paged=paged)
+    engine.check_prefillable(19)
+    assert engine.generate(prompt, 5) == want
+    with pytest.raises(ValueError, match="largest prefill bucket"):
+        DecodeEngine(model, variables, num_slots=1, max_len=64, prefill_buckets=(4, 8)).check_prefillable(19)
+    engine.check_prefillable(60)  # eight chunks of 8 end at max_len
+    tight = DecodeEngine(model, variables, num_slots=1, max_len=60, prefill_buckets=(4, 8), prefill_chunk=8,
+                         paged=paged)
+    with pytest.raises(ValueError, match="largest prefill bucket"):
+        tight.check_prefillable(57)  # its eighth chunk would end at 64, past the slot's 60 rows

@@ -811,7 +811,7 @@ def test_mutated_engine_rebind_is_caught():
         / "unionml_tpu" / "serving" / "continuous.py"
     ).read_text()
     mutated = src.replace(
-        'logits, state["cache"] = self._chunk_fn(', 'logits, _ignored = self._chunk_fn(', 1
+        'last, state["cache"] = self._chunk_fn(', 'last, _ignored = self._chunk_fn(', 1
     )
     assert mutated != src, "the chunked-prefill rebind moved; update this mutation"
     with tempfile.TemporaryDirectory() as d:

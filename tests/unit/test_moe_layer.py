@@ -156,8 +156,9 @@ def test_gpt_moe_a2a_trains_end_to_end():
 
 def test_dropless_mode_never_drops_under_imbalance():
     """Review regression: with a fully-collapsed router, capacity mode drops tokens
-    but dropless mode matches the dense per-token computation exactly."""
-    from unionml_tpu.parallel.ep import moe_apply_topk
+    but the dropless grouped path matches the dense per-token computation exactly
+    (every row of expert 0's group and of expert 1's; experts 2 and 3 get none)."""
+    from unionml_tpu.parallel.ep import moe_apply_grouped, moe_apply_topk
 
     rng = np.random.default_rng(6)
     E, D, T = 4, 8, 32
@@ -172,8 +173,12 @@ def test_dropless_mode_never_drops_under_imbalance():
     g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)
     ref = g[:, :1] * (tokens @ eW[0]) + g[:, 1:2] * (tokens @ eW[1])
 
-    dropless = moe_apply_topk(lambda W, t: t @ W, eW, tokens, gates, k=2, capacity_factor=None)
+    _, top_index = jax.lax.top_k(gates, 2)
+    dropless, sizes = moe_apply_grouped(
+        lambda W, rows, sizes: jax.lax.ragged_dot(rows, W, sizes), eW, tokens, top_index, g
+    )
     np.testing.assert_allclose(np.asarray(dropless), np.asarray(ref), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(sizes), [T, T, 0, 0])
 
     capped = moe_apply_topk(lambda W, t: t @ W, eW, tokens, gates, k=2, capacity_factor=1.0)
     assert np.abs(np.asarray(capped) - np.asarray(ref)).max() > 1e-3  # drops happened
@@ -205,3 +210,27 @@ def test_router_noise_respects_deterministic_flag():
     out_a = layer.apply(params, x, deterministic=True, rngs={"dropout": jax.random.PRNGKey(1)})
     out_b = layer.apply(params, x, deterministic=True, rngs={"dropout": jax.random.PRNGKey(2)})
     np.testing.assert_array_equal(np.asarray(out_a), np.asarray(out_b))
+
+
+def test_dropless_on_an_expert_mesh_is_the_unsharded_layer_and_gathers_no_weights():
+    """``dropless=True`` (inference) with the stacked expert weights sharded over
+    the mesh's ``expert`` axis: the grouped path takes no mesh argument, so XLA's
+    partitioner decides. It must compute the unsharded layer (1e-6: float32, the
+    same sums in another order) and leave the weights where they lie: the
+    collectives it may add are on group sizes (int32) and on the outputs, never
+    an all-gather of a float array."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    mesh = make_mesh({"data": 2, "expert": 4})
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(4, 8, 16)), dtype=jnp.float32)
+    plain = MoEMlp(num_experts=8, hidden_size=16, k=2)
+    sharded = MoEMlp(num_experts=8, hidden_size=16, k=2, mesh=mesh)
+    params = plain.init(jax.random.PRNGKey(0), x)
+    want = plain.apply(params, x, dropless=True)
+    placed = jax.device_put(params, jax.tree.map(
+        lambda p: NamedSharding(mesh, PartitionSpec("expert") if p.ndim == 3 else PartitionSpec()), params
+    ))
+    fn = jax.jit(lambda p, x: sharded.apply(p, x, dropless=True))
+    np.testing.assert_allclose(fn(placed, x), want, atol=1e-6)
+    gathers = [line for line in fn.lower(placed, x).compile().as_text().splitlines() if " all-gather(" in line]
+    assert not [line for line in gathers if "= f32[" in line or "= bf16[" in line], gathers

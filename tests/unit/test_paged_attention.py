@@ -363,3 +363,108 @@ def test_kernel_steady_state_transfer_guard_clean_with_telemetry(gpt_tiny_sessio
             eng.step()
     rendered = tel.metrics.render()
     assert 'unionml_paged_attn_impl{impl="pallas"} 1' in rendered
+
+
+# ------------------------------------- fewer key heads, keys that are the values
+
+
+def _shared_key_case(S, heads=4, key_heads=1, width=9, dim=48, bases=(0, 5, 37, 143 - 24, 8 * 16), seed=0):
+    """A latent-style pool: ``key_heads`` key heads for ``heads`` query heads,
+    no value leaf; ragged rows, the engine's sentinel among them."""
+    rng = np.random.default_rng(seed)
+    bases = np.asarray(bases)
+    rows = len(bases)
+    live = np.clip((bases + S - 1) // RBS + 1, 0, width)
+    blocks = int(live.sum()) + 1
+    owned = iter(rng.permutation(blocks - 1))
+    table = np.full((rows, width), blocks - 1, np.int32)
+    for r in range(rows):
+        table[r, : live[r]] = [next(owned) for _ in range(live[r])]
+    k = jnp.asarray(rng.normal(size=(blocks, key_heads, RBS, dim)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(rows, heads, S, dim)), jnp.float32)
+    return q, k, jnp.asarray(table), jnp.asarray(bases, jnp.int32)
+
+
+def _dense_oracle(q, k, v, table, base, sm_scale):
+    """The semantics spelt out row by row in numpy: query head ``h`` reads key
+    head ``h // group``; ``v=None`` weighs the keys themselves."""
+    q, k, table, base = (np.asarray(x) for x in (q, k, table, base))
+    v = k if v is None else np.asarray(v)
+    rows, heads, S, _ = q.shape
+    group = heads // k.shape[1]
+    out = np.zeros((rows, heads, S, v.shape[-1]), np.float64)
+    for b in range(rows):
+        keys = np.concatenate([k[i] for i in table[b]], axis=1)  # (key_heads, capacity, dim)
+        values = np.concatenate([v[i] for i in table[b]], axis=1)
+        for h in range(heads):
+            for s in range(S):
+                n = min(base[b] + s + 1, keys.shape[1])
+                scores = keys[h // group, :n] @ q[b, h, s] * sm_scale
+                weights = np.exp(scores - scores.max())
+                out[b, h, s] = weights / weights.sum() @ values[h // group, :n]
+    return out
+
+
+@pytest.mark.parametrize("S", [1, 24], ids=["decode", "chunk"])
+@pytest.mark.parametrize("key_heads", [1, 2])
+def test_shared_key_heads_and_keys_as_values_both_arms(key_heads, S):
+    """One key head for four query heads (and two for four), the keys the
+    values too, a scale of the caller's: the kernel and the gather arm against
+    the definition. float32 throughout, so 2e-5 is summation order alone."""
+    q, k, table, base = _shared_key_case(S, key_heads=key_heads)
+    want = _dense_oracle(q, k, None, table, base, 0.3)
+    for impl in ("xla", "pallas"):
+        extra = {"interpret": True} if impl == "pallas" else {}
+        out = paged_attention(q, k, None, table, base, impl=impl, sm_scale=0.3, **extra)
+        assert out.shape == q.shape  # the caller slices its values off the key row
+        np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=2e-5)
+    # a value leaf of its own, narrower than the keys, under shared key heads
+    v = k[..., :16] * 2.0
+    want = _dense_oracle(q, k, v, table, base, 48 ** -0.5)
+    for impl in ("xla", "pallas"):
+        extra = {"interpret": True} if impl == "pallas" else {}
+        out = paged_attention(q, k, v, table, base, impl=impl, **extra)
+        np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("limit,S,want_rows", [(1400 * 1024, 16, 32), (900 * 1024, 16, 8)],
+                         ids=["whole_spans", "part_of_a_span"])
+def test_query_rows_split_into_blocks_when_one_heads_rows_outgrow_vmem(monkeypatch, limit, S, want_rows):
+    """32 query heads of a long chunk over one key head are too many rows for
+    one grid step: the rows split into blocks of whole spans, or of a divisor
+    of one, each with its own last visible key. Forced at a small size by a
+    small VMEM budget; the result is the definition's either way."""
+    from unionml_tpu.ops import paged_attention as module
+
+    monkeypatch.setattr(module, "_VMEM_LIMIT_BYTES", limit)
+    q, k, table, base = _shared_key_case(S, bases=(0, 7, 60, 128))
+    heads, rows, _ = module._tiling(1, 4 * S, S, RBS, 48, 9, 4, False)
+    assert (heads, rows) == (1, want_rows)
+    out = paged_attention(q, k, None, table, base, impl="pallas", interpret=True)
+    want = _dense_oracle(q, k, None, table, base, 48 ** -0.5)
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=2e-5)
+
+
+def test_per_head_shapes_take_the_path_they_took():
+    """GPT-2's calls are what they were: the tiling of its shapes (all heads, the
+    query's own rows, 8 table entries a step; 4 heads of a 512-token chunk),
+    and the gather arm bit for bit the historical formula (gather, flatten,
+    ``xla_attention`` under the positional mask)."""
+    from unionml_tpu.ops import paged_attention as module
+    from unionml_tpu.ops.attention import xla_attention
+
+    assert module._tiling(16, 1, 1, 16, 64, 65, 2, False) == (16, 1, 8)
+    assert module._tiling(12, 1, 1, 16, 64, 65, 1, True) == (12, 1, 8)
+    assert module._tiling(16, 64, 64, 16, 64, 65, 2, False) == (16, 64, 8)
+    assert module._tiling(16, 512, 512, 16, 64, 65, 2, False) == (4, 512, 8)
+    q, k, v, table, base, _, _ = _ragged_case(12, 16, 5, "bf16")
+    rows, heads, S, dim = q.shape
+    capacity = table.shape[1] * RBS
+    flat = lambda leaf: jnp.moveaxis(leaf[table], 2, 1).reshape(rows, heads, capacity, dim)
+    q_pos = base[:, None] + jnp.arange(S)[None, :]
+    mask = (jnp.arange(capacity)[None, None, :] <= q_pos[:, :, None])[:, None]
+    historical = xla_attention(q, flat(k), flat(v), mask=mask)
+    np.testing.assert_array_equal(
+        np.asarray(xla_paged_attention(q, k, v, table, base), np.float32),
+        np.asarray(historical, np.float32),
+    )

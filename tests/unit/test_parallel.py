@@ -357,9 +357,16 @@ def test_moe_capacity_drops_overflow_tokens():
 
 
 def test_moe_a2a_matches_dense_oracle_when_nothing_drops():
-    """Explicit all-to-all dispatch == dropless dense oracle (fwd + grads) when
-    capacity is ample — the exactness contract for the pod-scale path."""
-    from unionml_tpu.parallel.ep import moe_apply_a2a, moe_apply_topk
+    """Explicit all-to-all dispatch == the dropless grouped path (fwd + grads)
+    when capacity is ample — the exactness contract for the pod-scale path."""
+    from unionml_tpu.parallel.ep import moe_apply_a2a, moe_apply_grouped
+
+    def dropless(w, tokens, gates):
+        top_gates, top_index = jax.lax.top_k(gates, 2)
+        top_gates = top_gates / jnp.sum(top_gates, axis=-1, keepdims=True)
+        return moe_apply_grouped(
+            lambda W, rows, sizes: jax.lax.ragged_dot(rows, W, sizes), w, tokens, top_index, top_gates
+        )[0]
 
     rng = np.random.default_rng(5)
     mesh = make_mesh({"data": 2, "expert": 4})
@@ -372,14 +379,14 @@ def test_moe_a2a_matches_dense_oracle_when_nothing_drops():
     out = jax.jit(
         lambda w, t, g: moe_apply_a2a(fn, w, t, g, mesh, k=2, capacity_factor=16.0)
     )(eW, tokens, gates)
-    ref = moe_apply_topk(fn, eW, tokens, gates, None, k=2, capacity_factor=None)
+    ref = dropless(eW, tokens, gates)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
     g_a2a = jax.grad(
         lambda w: jnp.sum(moe_apply_a2a(fn, w, tokens, gates, mesh, k=2, capacity_factor=16.0) ** 2)
     )(eW)
     g_ref = jax.grad(
-        lambda w: jnp.sum(moe_apply_topk(fn, w, tokens, gates, None, k=2, capacity_factor=None) ** 2)
+        lambda w: jnp.sum(dropless(w, tokens, gates) ** 2)
     )(eW)
     np.testing.assert_allclose(np.asarray(g_a2a), np.asarray(g_ref), atol=1e-4)
 

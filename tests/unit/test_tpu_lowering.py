@@ -419,6 +419,34 @@ def test_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, shape, pool,
     assert jax.jit(_paged_fn(compute)).lower(*args).compile() is not None
 
 
+@pytest.mark.parametrize("mode,batch,seq", [("decode", 32, 1), ("chunk", 1, 1024)])
+def test_latent_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, mode, batch, seq):
+    """The sparse-decoder cell's call: 32 query heads over one key head whose
+    640-wide rows (512 latent + 64 rotary + 64 of padding) are the values too,
+    16-token blocks, 32 slots of 8192 positions. Decode takes the 32 heads'
+    rows in one grid step; a 1024-token chunk splits its 32 x 1024 rows."""
+    from jax.sharding import SingleDeviceSharding
+
+    from unionml_tpu.ops.paged_attention import _tiling, paged_attention
+
+    heads, row, block_size, width = 32, 640, 16, 8192 // 16 + 1
+    on_chip = SingleDeviceSharding(v5e_host[0])
+    args = [
+        jax.ShapeDtypeStruct((batch, heads, seq, row), jnp.bfloat16, sharding=on_chip),
+        jax.ShapeDtypeStruct((32 * (width - 1) + 1, 1, block_size, row), jnp.bfloat16, sharding=on_chip),
+        jax.ShapeDtypeStruct((batch, width), jnp.int32, sharding=on_chip),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=on_chip),
+    ]
+
+    def fn(q, pool, table, base):
+        return paged_attention(q, pool, None, table, base, impl="pallas", sm_scale=0.1447)
+
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    key_heads, rows, entries = _tiling(1, heads * seq, seq, block_size, row, width, 2, False)
+    assert (key_heads, entries) == (1, 8) and rows == (32 if mode == "decode" else 512)
+
+
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
 @pytest.mark.parametrize("shape", REAL_SHAPES)
 def test_paged_attention_partitions_over_tensor_mesh(as_on_tpu, v5e_host, shape, pool):

@@ -53,6 +53,12 @@ MODULES = [
 ]
 
 
+#: registries whose content at render time depends on what the process imported
+#: before (the lint rules register themselves when a lint first runs): a page
+#: that printed them differed from one pytest worker to the next
+RUNTIME_REGISTRIES = {("unionml_tpu.analysis", "RULES")}
+
+
 def _public_names(mod) -> list:
     if hasattr(mod, "__all__"):
         return list(mod.__all__)
@@ -109,6 +115,11 @@ def render_module(module_path: str, title: str) -> str:
             _class_entry(name, obj, out)
         elif callable(obj):
             out.write(f"### `{name}{_signature(obj)}`\n\n{_doc(obj)}\n\n")
+        elif (module_path, name) in RUNTIME_REGISTRIES:
+            out.write(
+                f"### `{name}`\n\nA module-level `{type(obj).__name__}` that fills as its modules "
+                "load (whatever has registered itself); its content is not rendered.\n\n"
+            )
         else:
             out.write(f"### `{name}`\n\n`{name} = {obj!r}`\n\n")
     return out.getvalue()

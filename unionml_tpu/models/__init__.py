@@ -22,6 +22,8 @@ from unionml_tpu.models.gpt import init_cache as init_gpt_cache
 from unionml_tpu.models.gpt import import_hf_weights as import_hf_gpt_weights
 from unionml_tpu.models.gpt import init_params as init_gpt_params
 from unionml_tpu.models.gpt import lm_loss as gpt_lm_loss
+from unionml_tpu.models.gpt import KVCacheLayout
+from unionml_tpu.models.latent_moe import LatentCacheLayout, LatentMoEConfig, LatentMoELMHeadModel
 from unionml_tpu.models.mlp import CNNClassifier, MLPClassifier
 from unionml_tpu.models.moe import (
     MoEMlp,
@@ -55,6 +57,10 @@ __all__ = [
     "router_z_loss",
     "GPTConfig",
     "GPTLMHeadModel",
+    "KVCacheLayout",
+    "LatentCacheLayout",
+    "LatentMoEConfig",
+    "LatentMoELMHeadModel",
     "MLPClassifier",
     "fit_lm",
     "gpt_generate",

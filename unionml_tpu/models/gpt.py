@@ -551,6 +551,10 @@ class GPTLMHeadModel(nn.Module):
 
     config: GPTConfig
 
+    def cache_layout(self) -> "KVCacheLayout":
+        """What a serving engine asks about this model's cache."""
+        return KVCacheLayout(self.config)
+
     @nn.compact
     def __call__(
         self,
@@ -560,6 +564,7 @@ class GPTLMHeadModel(nn.Module):
         deterministic: bool = True,
         pad_offsets: Optional[jax.Array] = None,
         segment_ids: Optional[jax.Array] = None,
+        logit_rows: Optional[jax.Array] = None,
     ):
         """``pad_offsets`` (batch,) enables ragged-prompt batching: rows are LEFT-
         padded, each row's position embeddings start at its first real token, and
@@ -578,6 +583,10 @@ class GPTLMHeadModel(nn.Module):
         ``cache["table"]`` is the int32 (batch, width) block table every layer
         reads/writes through (one table, all layers — the pool is per-layer, the
         logical layout is not). The table rides through ``new_cache`` unchanged.
+
+        ``logit_rows`` (batch,) names the one position a row whose logits the
+        caller reads (a prefill reads its prompt's last token): the head then
+        runs over those alone and the logits are (batch, 1, vocab).
         """
         cfg = self.config
         if pad_offsets is not None and cfg.moe_every > 0 and not deterministic:
@@ -635,6 +644,8 @@ class GPTLMHeadModel(nn.Module):
         if block_table is not None:
             new_cache["table"] = block_table
 
+        if logit_rows is not None:
+            hidden = jnp.take_along_axis(hidden, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
         hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name="final_norm")(hidden)
         # tied head with genuinely-f32 logits: Embed.attend would promote back to the
         # compute dtype (bf16), costing mantissa over a large vocab
@@ -763,6 +774,50 @@ def kv_pool_bytes(pool: Dict[str, Any], dense_dtype: Any) -> Tuple[int, int]:
             if not name.endswith("_scale"):
                 full += leaf.size * jnp.dtype(dense_dtype).itemsize
     return stored, full
+
+
+class KVCacheLayout:
+    """A model's cache as a serving engine sees it: per-head keys and values,
+    the functions above behind the names every layout answers to (the latent
+    layout of :mod:`unionml_tpu.models.latent_moe` is the other one). The
+    engine takes its dense cache, its block pool, their sharding, their bytes
+    and the paged kernel's shape key from ``model.cache_layout()``; tables,
+    scatters and gathers are the engine's own and work on any layout whose
+    leaves are ``(rows | blocks, heads, tokens, dim)``."""
+
+    def __init__(self, config: GPTConfig) -> None:
+        self.config = config
+        #: heads of a cache leaf: what a ``tensor`` mesh axis has to divide
+        self.kv_heads = config.num_heads
+        #: ``(heads, last dimension)`` of the paged kernel's call
+        self.kernel_key = (config.num_heads, config.head_dim)
+
+    def init_cache(self, batch: int, max_len: int) -> Dict[str, Any]:
+        return init_cache(self.config, batch, max_len)
+
+    def init_block_pool(
+        self, num_blocks: int, block_size: int, kv_quantize: Optional[str] = None,
+        kv_quantize_skip_layers: Tuple[int, ...] = (),
+    ) -> Dict[str, Any]:
+        return init_block_pool(
+            self.config, num_blocks, block_size, kv_quantize=kv_quantize,
+            kv_quantize_skip_layers=kv_quantize_skip_layers,
+        )
+
+    def cache_spec(self, mesh_axis_names: Tuple[str, ...]) -> Any:
+        return kv_cache_spec(self.config, mesh_axis_names)
+
+    def block_bytes(
+        self, block_size: int, kv_quantize: Optional[str] = None,
+        kv_quantize_skip_layers: Tuple[int, ...] = (),
+    ) -> int:
+        return kv_block_bytes(
+            self.config, block_size, kv_quantize=kv_quantize,
+            kv_quantize_skip_layers=kv_quantize_skip_layers,
+        )
+
+    def pool_bytes(self, pool: Dict[str, Any]) -> Tuple[int, int]:
+        return kv_pool_bytes(pool, self.config.dtype)
 
 
 def init_slot_state(num_slots: int) -> Tuple[jax.Array, jax.Array]:

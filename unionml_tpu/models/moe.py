@@ -13,7 +13,9 @@ Components:
 - :class:`MoEMlp` — a drop-in replacement for a transformer MLP block: dense router,
   softmax gates, top-k capacity dispatch through
   :func:`unionml_tpu.parallel.ep.moe_apply_topk` (expert-sharded when a mesh with an
-  ``"expert"`` axis is supplied, plain single-device dispatch otherwise). Aux losses
+  ``"expert"`` axis is supplied, plain single-device dispatch otherwise); inference
+  (``dropless=True``) computes every routed choice by group through
+  :func:`unionml_tpu.parallel.ep.moe_apply_grouped`. Aux losses
   are sown under ``intermediates/router_z_loss`` and
   ``intermediates/load_balancing_loss`` — collect with
   ``model.apply(..., mutable=["intermediates"])`` and add them to the training loss.
@@ -25,7 +27,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from unionml_tpu.parallel.ep import moe_apply_a2a, moe_apply_topk
+from unionml_tpu.parallel.ep import moe_apply_a2a, moe_apply_grouped, moe_apply_topk
 
 
 def router_z_loss(router_logits: jax.Array) -> jax.Array:
@@ -73,7 +75,10 @@ class MoEMlp(nn.Module):
     #: collectives from sharding constraints); "a2a" shards the tokens and moves
     #: only routed tokens with explicit lax.all_to_all over the expert axis —
     #: O(local_tokens x k x capacity_factor) per device, the pod-scale layout.
-    #: "a2a" requires ``mesh``; the dropless (inference) path is dense either way.
+    #: "a2a" requires ``mesh``; the dropless (inference) path is grouped either way
+    #: (:func:`unionml_tpu.parallel.ep.moe_apply_grouped`) and takes no mesh: with
+    #: the stacked weights sharded over ``expert`` XLA's partitioner keeps them
+    #: where they lie (it gathers the group sizes and reduces the outputs).
     dispatch: str = "gshard"
     #: token-sharding axis alongside "expert" for the a2a path (ignored when the
     #: mesh doesn't carry it)
@@ -129,7 +134,22 @@ class MoEMlp(nn.Module):
         # value) — and with it the router's only gradient path through the task
         # loss. Same contract as ep.moe_apply_capacity, the top-1 wrapper.
         normalize = self.k > 1
-        if self.dispatch == "a2a" and not dropless:
+        if dropless:
+            # inference: every routed choice is computed, by group (no capacity)
+            top_gates, top_index = jax.lax.top_k(gates, self.k)
+            if normalize:
+                top_gates = top_gates / jnp.maximum(jnp.sum(top_gates, axis=-1, keepdims=True), 1e-9)
+
+            def grouped_fn(params, rows, group_sizes):
+                w1, w2 = params
+                hidden = jax.nn.gelu(jax.lax.ragged_dot(rows, w1, group_sizes))
+                return jax.lax.ragged_dot(hidden, w2, group_sizes)
+
+            out, _ = moe_apply_grouped(
+                grouped_fn, (w_in, w_out), tokens.astype(self.dtype), top_index,
+                top_gates.astype(self.dtype),
+            )
+        elif self.dispatch == "a2a":
             if self.mesh is None or "expert" not in self.mesh.shape:
                 raise ValueError("dispatch='a2a' requires a mesh with an 'expert' axis")
             out = moe_apply_a2a(
@@ -151,7 +171,7 @@ class MoEMlp(nn.Module):
                 gates.astype(self.dtype),
                 self.mesh,
                 k=self.k,
-                capacity_factor=None if dropless else self.capacity_factor,
+                capacity_factor=self.capacity_factor,
                 normalize_gates=normalize,
             )
         return out.reshape(x.shape).astype(x.dtype)

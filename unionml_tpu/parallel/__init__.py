@@ -11,6 +11,7 @@ from unionml_tpu.parallel.ep import (
     moe_apply,
     moe_apply_a2a,
     moe_apply_capacity,
+    moe_apply_grouped,
     moe_apply_topk,
 )
 from unionml_tpu.parallel.pp import (
@@ -52,6 +53,7 @@ __all__ = [
     "moe_apply",
     "moe_apply_a2a",
     "moe_apply_capacity",
+    "moe_apply_grouped",
     "moe_apply_topk",
     "circular_superstage",
     "pipeline_apply",

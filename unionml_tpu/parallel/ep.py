@@ -1,7 +1,7 @@
 """Expert parallelism: a mixture-of-experts layer sharded over an ``"expert"`` mesh axis.
 
 Each device owns ``experts_per_device`` expert MLPs (parameters sharded on their
-leading expert axis). Three dispatch formulations, in increasing scalability:
+leading expert axis). Three training dispatch formulations, in increasing scalability, and one for inference:
 
 - :func:`moe_apply` — dense-masked top-1: every device computes its local experts
   over the FULL token set, masks by assignment, ``psum`` combines. O(experts_per_device
@@ -10,6 +10,9 @@ leading expert axis). Three dispatch formulations, in increasing scalability:
 - :func:`moe_apply_topk` / :func:`moe_apply_capacity` — GShard capacity dispatch via
   one-hot einsums with ``expert``-axis sharding constraints; XLA infers the
   collectives. The (tokens, experts, capacity) dispatch tensors are still global.
+- :func:`moe_apply_grouped` — the DROPLESS inference dispatch: (token, expert)
+  pairs sorted by expert and one ``jax.lax.ragged_dot`` a projection over the
+  group sizes, ``tokens x k`` rows of work whatever the routing.
 - :func:`moe_apply_a2a` — explicit ``shard_map`` + ``lax.all_to_all`` token dispatch:
   tokens are sharded, each device routes only its local tokens into per-expert
   capacity buffers, and two all-to-alls (dispatch + return) ride the ICI. Per-device
@@ -131,6 +134,42 @@ def moe_apply_capacity(
     )
 
 
+def moe_apply_grouped(
+    grouped_fn: Callable,
+    stacked_params: Any,
+    tokens: jax.Array,
+    top_index: jax.Array,
+    top_weights: jax.Array,
+):
+    """DROPLESS top-k dispatch by group: the inference path.
+
+    The ``tokens x k`` (token, expert) pairs are sorted by expert, so that each
+    expert's rows are contiguous; ``grouped_fn(stacked_params, rows,
+    group_sizes)`` runs every expert over its own rows only (one
+    ``jax.lax.ragged_dot`` a projection: ``rows`` is ``(tokens * k, d)``,
+    ``group_sizes`` the ``(num_experts,)`` int32 row counts, in expert order);
+    the outputs are unsorted, weighted and summed over each token's k choices.
+    No capacity, so no token is dropped whatever the router's imbalance, and
+    the work is ``tokens * k`` rows — not ``tokens * num_experts`` as the
+    dense-masked formulation this replaces (every expert over every token).
+
+    :param top_index: ``(tokens, k)`` int expert choices (the router's top-k).
+    :param top_weights: ``(tokens, k)`` combine weights of those choices.
+    :returns: ``(out, group_sizes)``: the ``(tokens, d_out)`` combined outputs
+        and the rows each expert received (what load counters read).
+    """
+    num_tokens, k = top_index.shape
+    num_experts = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
+    flat = top_index.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(flat, stable=True)  # pair j of the sorted rows is pair order[j]
+    group_sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+    rows = jnp.take(tokens, order // k, axis=0)
+    outputs = grouped_fn(stacked_params, rows, group_sizes)  # (tokens * k, d_out)
+    unsorted = jnp.take(outputs, jnp.argsort(order), axis=0).reshape(num_tokens, k, -1)
+    out = jnp.einsum("tkd,tk->td", unsorted, top_weights.astype(unsorted.dtype))
+    return out, group_sizes
+
+
 def moe_apply_topk(
     expert_fn: Callable,
     stacked_params: Any,
@@ -139,17 +178,14 @@ def moe_apply_topk(
     mesh: Optional[Mesh] = None,
     *,
     k: int = 2,
-    capacity_factor: Optional[float] = 1.25,
+    capacity_factor: float = 1.25,
     normalize_gates: bool = True,
     axis: str = EXPERT_AXIS,
 ) -> jax.Array:
     """GShard top-k (default top-2) capacity-based MoE dispatch.
 
-    ``capacity_factor=None`` is DROPLESS: the dispatch switches to the dense-masked
-    formulation (every expert computes every token, top-k gates select) so no token
-    ever loses a routed choice regardless of router imbalance — the inference-parity
-    mode. Costs E x redundant expert compute; use the factor-bounded mode for
-    training efficiency.
+    The capacity drop is a TRAINING trade-off; inference routes droplessly
+    through :func:`moe_apply_grouped` (no token ever loses a routed choice).
 
     Generalizes :func:`moe_apply_capacity` to k routed experts per token: each token
     claims up to ``k`` expert-buffer slots, choice-major — every token's FIRST choice
@@ -180,21 +216,6 @@ def moe_apply_topk(
     top_gates, top_index = jax.lax.top_k(gates, k)  # (t, k)
     if normalize_gates:
         top_gates = top_gates / jnp.maximum(jnp.sum(top_gates, axis=-1, keepdims=True), 1e-9)
-
-    if capacity_factor is None:
-        # dropless via the dense-masked formulation (same shape as _moe_local):
-        # every expert computes every token — E x redundant compute, O(E * T * d)
-        # memory — and the top-k gates select/weight per token. Exact for any
-        # router state; far cheaper than capacity=num_tokens buffers (O(E * T^2)).
-        all_out = jax.vmap(expert_fn, in_axes=(0, None))(stacked_params, tokens)  # (e, t, d_out)
-        if mesh is not None:
-            all_out = jax.lax.with_sharding_constraint(
-                all_out, NamedSharding(mesh, P(axis, None, None))
-            )
-        one_hot_k = jax.nn.one_hot(top_index, num_experts, dtype=tokens.dtype)  # (t, k, e)
-        weights = jnp.einsum("tke,tk->te", one_hot_k, top_gates.astype(tokens.dtype))
-        out = jnp.einsum("te,etd->td", weights, all_out.astype(tokens.dtype))
-        return out.astype(tokens.dtype)
 
     capacity = max(int(np.ceil(num_tokens * k / num_experts * capacity_factor)), 1)
 

@@ -553,7 +553,7 @@ def build_aiohttp_app(
                     raise ValueError("empty prompt")
                 if seq.size >= gen.engine.max_len:
                     raise ValueError(f"prompt length {seq.size} >= max_len ({gen.engine.max_len})")
-                gen.engine.bucket_for(seq.size)
+                gen.engine.check_prefillable(int(seq.size))
         except (TypeError, ValueError) as exc:
             return _bad_request(f"invalid prompt payload: {exc}", request_id=request_id)
 

@@ -188,9 +188,14 @@ class SalvagedSlot:
 class DecodeEngine:
     """Slot-based continuous-batching decode engine over a GPT-style model.
 
-    :param model: a :class:`~unionml_tpu.models.gpt.GPTLMHeadModel` (anything with
-        ``.config`` and ``.apply(variables, ids, cache=, position=)`` matching its
-        incremental contract).
+    :param model: a :class:`~unionml_tpu.models.gpt.GPTLMHeadModel` or a
+        :class:`~unionml_tpu.models.latent_moe.LatentMoELMHeadModel` (anything
+        with ``.config``, ``.apply(variables, ids, cache=, position=,
+        logit_rows=)`` matching their incremental and paged contract, and
+        ``.cache_layout()``: the
+        dense cache, the block pool, their sharding and bytes and the paged
+        kernel's shape key come from it, so a latent cache of one row a token
+        is served by the same tables, allocator and programs as per-head K/V).
     :param variables: trained model variables (``{"params": ...}``).
     :param num_slots: concurrent sequences held on device (the decode batch).
     :param max_len: per-slot cache capacity (prompt + generated tokens). A slot
@@ -216,7 +221,9 @@ class DecodeEngine:
         for N prompts (one compile per (rows, bucket) shape).
     :param prefill_chunk: when set, prompts longer than this prefill in chunks of
         this many tokens, ONE chunk per engine tick between decode steps, so a
-        long prompt cannot stall in-flight decodes for its whole prefill.
+        long prompt cannot stall in-flight decodes for its whole prefill. A
+        prompt's last chunk is padded to the smallest of ``prefill_buckets``
+        that holds it (one compile per such width), not to a whole chunk.
     :param prefix_cache_blocks: when > 0, enable PREFIX CACHING with a device
         KV block pool of this many blocks (see :meth:`enable_prefix_cache`):
         completed prompts index their KV block-by-block into a host radix tree
@@ -304,10 +311,12 @@ class DecodeEngine:
         faults: Optional[FaultPlan] = None,
         telemetry: Optional[Any] = None,
     ) -> None:
-        from unionml_tpu.models.gpt import init_cache
-
         model = bind_serving_mesh(model, mesh)
         config = model.config
+        #: the model's cache layout: dense cache, block pool, their sharding
+        #: and bytes, the paged kernel's shape key (per-head K/V for the GPT
+        #: family, one latent row a token for ``latent_moe``)
+        layout = self._layout = model.cache_layout()
         max_len = max_len or config.max_position_embeddings
         if max_len > config.max_position_embeddings:
             raise ValueError(
@@ -339,14 +348,14 @@ class DecodeEngine:
             from jax.sharding import NamedSharding, PartitionSpec
 
             from unionml_tpu.models._sharding import place_by_specs
-            from unionml_tpu.models.gpt import kv_cache_spec, param_shardings
+            from unionml_tpu.models.gpt import param_shardings
             from unionml_tpu.parallel.mesh import TENSOR_AXIS
 
             spec_tree = param_shardings(variables, tuple(mesh.axis_names))
             variables = place_by_specs(variables, mesh, spec_tree)
-            cache_spec = kv_cache_spec(config, tuple(mesh.axis_names))
+            cache_spec = layout.cache_spec(tuple(mesh.axis_names))
             tensor_size = int(mesh.shape[TENSOR_AXIS]) if TENSOR_AXIS in mesh.axis_names else 1
-            if config.num_heads % max(tensor_size, 1) != 0:
+            if layout.kv_heads % max(tensor_size, 1) != 0:
                 cache_spec = PartitionSpec()  # heads don't divide: replicate the cache
             self._cache_sharding = NamedSharding(mesh, cache_spec)
             self._replicated = NamedSharding(mesh, PartitionSpec())
@@ -434,9 +443,10 @@ class DecodeEngine:
 
         #: depth-1 pipelining: dispatch step N+1 before fetching step N's tokens
         self.pipeline = bool(pipeline)
-        #: the dispatched-but-unfetched step: ``(tokens, masks, bads, n_steps)``
-        #: device arrays (leading axis = steps in the burst), or None when drained
-        self._inflight: Optional[Tuple[Any, Any, Any, int]] = None
+        #: the dispatched-but-unfetched step: ``(tokens, masks, bads, n_steps,
+        #: counts)``, device arrays (leading axis = steps in the burst) around the
+        #: burst's length, or None when drained
+        self._inflight: Optional[Tuple[Any, Any, Any, int, Dict[str, Any]]] = None
         #: slots QUARANTINED while ``_inflight`` was already dispatched: that
         #: burst still carries their (garbage) tokens under an active mask, so
         #: its replay must skip them — the slot may hold a NEW occupant by
@@ -469,6 +479,11 @@ class DecodeEngine:
         #: Over ``active_slot_steps x table width`` it is the share of the block
         #: table the paged kernel's bounded walk still visits
         self.live_block_steps = 0
+        #: the model's own step counters (what it sows into its ``"stats"``
+        #: collection in a decode step, e.g. a sparse model's ``expert_rows``),
+        #: summed over decode steps: fetched with the step's tokens, added in
+        #: ``loop.apply``
+        self.model_counters: Dict[str, int] = {}
         self._last_fetch_done: Optional[float] = None
         #: what the loop thread that drives this engine is doing (see
         #: :data:`LOOP_PHASES`); the batcher drives the same instance
@@ -535,8 +550,7 @@ class DecodeEngine:
                 getattr(config, "paged_attn_impl", "auto"),
                 self._table_width,
                 bs,
-                config.num_heads,
-                config.head_dim,
+                *layout.kernel_key,
             )
             if self._telemetry is not None:
                 self._telemetry.paged_attn_impl.set(1.0, self.paged_attn_impl)
@@ -583,13 +597,18 @@ class DecodeEngine:
                 tokens = sample_logits(last_logits, subkey, temp, top_k, top_p)
             else:
                 tokens = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            logits, cache = model.apply(variables, tokens[:, None], cache=cache, position=lens)
+            # the model's step counters: int32 scalars it sows into "stats"
+            # (a model that sows none leaves the collection empty)
+            (logits, cache), sown = model.apply(
+                variables, tokens[:, None], cache=cache, position=lens, mutable=["stats"]
+            )
+            counts = dict(sown.get("stats", {}))
             cache = _constrain_cache(cache)
             # inactive rows freeze: length and logits unchanged, their (ignored)
             # cache write lands on a column their own future prefill/decode rewrites
             new_lens = jnp.where(active, jnp.minimum(lens + 1, max_len - 1), lens)
             new_logits = jnp.where(active[:, None], logits[:, -1, :], last_logits)
-            return cache, new_logits, new_lens, tokens, new_key, bad
+            return cache, new_logits, new_lens, tokens, new_key, bad, counts
 
         def _make_step(n_steps: int, sampling: bool):
             """K decode steps fused into one device program (``lax.scan``;
@@ -608,7 +627,7 @@ class DecodeEngine:
             def _multi(variables, cache, last_logits, lens, active, remaining, key, temp, top_k, top_p):
                 def body(carry, _):
                     cache, last_logits, lens, active, remaining, key = carry
-                    cache, new_logits, new_lens, tokens, key, bad = _decode_body(
+                    cache, new_logits, new_lens, tokens, key, bad, counts = _decode_body(
                         variables, cache, last_logits, lens, active, key, temp, top_k, top_p,
                         sampling=sampling,
                     )
@@ -616,13 +635,13 @@ class DecodeEngine:
                         active, remaining, new_lens, tokens, max_len, eos_token_id
                     )
                     carry = (cache, new_logits, new_lens, new_active, new_remaining, key)
-                    return carry, (tokens, active, bad)
+                    return carry, (tokens, active, bad, counts)
 
                 carry = (cache, last_logits, lens, active, remaining, key)
-                (cache, last_logits, lens, active, remaining, key), (toks, masks, bads) = jax.lax.scan(
-                    body, carry, None, length=n_steps
+                (cache, last_logits, lens, active, remaining, key), (toks, masks, bads, counts) = (
+                    jax.lax.scan(body, carry, None, length=n_steps)
                 )
-                return cache, last_logits, lens, active, remaining, key, toks, masks, bads
+                return cache, last_logits, lens, active, remaining, key, toks, masks, bads, counts
 
             return jax.jit(_multi, donate_argnums=(1, 2))
 
@@ -653,34 +672,30 @@ class DecodeEngine:
             """
             variables = maybe_dequant(variables)
             rows, bucket = prompt_ids.shape
-            local_cache = init_cache(config, rows, bucket)
-            logits, local_cache = model.apply(variables, prompt_ids, cache=local_cache, position=0)
+            local_cache = layout.init_cache(rows, bucket)
             idx = jnp.clip(lengths.astype(jnp.int32) - 1, 0, bucket - 1)
-            last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0, :]
-            return _constrain_cache(local_cache), last
+            # each row's last real token is the only position whose logits are read
+            logits, local_cache = model.apply(
+                variables, prompt_ids, cache=local_cache, position=0, logit_rows=idx
+            )
+            return _constrain_cache(local_cache), logits[:, 0, :]
 
         self._prefill_fn = jax.jit(_prefill)  # re-traces per (rows, bucket) shape (bounded)
 
-        def _chunk_apply(variables, chunk_ids, local_cache, position):
+        def _chunk_apply(variables, chunk_ids, local_cache, position, pick):
             """One chunk of a long prefill: attends over the cache prefix written
             by earlier chunks (``position`` is traced — one compile per
-            (chunk, cache_len) shape, not per offset)."""
+            (chunk, cache_len) shape, not per offset). Returns the logits of the
+            chunk's token ``pick`` alone, (1, vocab): the one row a final chunk
+            seeds decoding from."""
             variables = maybe_dequant(variables)
             logits, local_cache = model.apply(
-                variables, chunk_ids, cache=local_cache, position=position
+                variables, chunk_ids, cache=local_cache, position=position,
+                logit_rows=jnp.reshape(pick, (1,)),
             )
-            return logits, _constrain_cache(local_cache)
+            return logits[:, 0, :], _constrain_cache(local_cache)
 
         self._chunk_fn = jax.jit(_chunk_apply, donate_argnums=(2,))
-
-        def _pick_last(logits, idx):
-            """Row ``idx`` of a batch-1 chunk's logits, selected IN-PROGRAM: an
-            eager ``logits[:, idx, :]`` lowers to dynamic_slice whose start
-            indices ride the host→device lane implicitly — which the
-            transfer-guard admission regression disallows."""
-            return jax.lax.dynamic_index_in_dim(logits[0], idx, axis=0, keepdims=False)[None, :]
-
-        self._pick_last_fn = jax.jit(_pick_last)
 
         def _insert(cache, lens, last_logits, local_cache, local_logits, slots, lengths):
             def put(full, local):
@@ -757,12 +772,15 @@ class DecodeEngine:
                 sentinel = (self._table_width - 1) * self._prefix_block_size
                 pos = jnp.where(active, lens, sentinel)
                 cache = {"table": tables, **pool}
-                logits, new_cache = model.apply(variables, tokens[:, None], cache=cache, position=pos)
+                (logits, new_cache), sown = model.apply(
+                    variables, tokens[:, None], cache=cache, position=pos, mutable=["stats"]
+                )
+                counts = dict(sown.get("stats", {}))
                 pool = {name: leaf for name, leaf in new_cache.items() if name != "table"}
                 pool = _constrain_cache(pool)
                 new_lens = jnp.where(active, jnp.minimum(lens + 1, max_len - 1), lens)
                 new_logits = jnp.where(active[:, None], logits[:, -1, :], last_logits)
-                return pool, new_logits, new_lens, tokens, new_key, bad
+                return pool, new_logits, new_lens, tokens, new_key, bad, counts
 
             def _make_step_paged(n_steps: int, sampling: bool):
                 """Paged ``_make_step``: identical scan/lifecycle contract; the
@@ -775,7 +793,7 @@ class DecodeEngine:
                 ):
                     def body(carry, _):
                         pool, last_logits, lens, active, remaining, key = carry
-                        pool, new_logits, new_lens, tokens, key, bad = _decode_body_paged(
+                        pool, new_logits, new_lens, tokens, key, bad, counts = _decode_body_paged(
                             variables, pool, tables, last_logits, lens,
                             active, key, temp, top_k, top_p, sampling=sampling,
                         )
@@ -783,13 +801,13 @@ class DecodeEngine:
                             active, remaining, new_lens, tokens, max_len, eos_token_id
                         )
                         carry = (pool, new_logits, new_lens, new_active, new_remaining, key)
-                        return carry, (tokens, active, bad)
+                        return carry, (tokens, active, bad, counts)
 
                     carry = (pool, last_logits, lens, active, remaining, key)
-                    (pool, last_logits, lens, active, remaining, key), (toks, masks, bads) = (
+                    (pool, last_logits, lens, active, remaining, key), (toks, masks, bads, counts) = (
                         jax.lax.scan(body, carry, None, length=n_steps)
                     )
-                    return pool, last_logits, lens, active, remaining, key, toks, masks, bads
+                    return pool, last_logits, lens, active, remaining, key, toks, masks, bads, counts
 
                 return jax.jit(_multi, donate_argnums=(1, 3))
 
@@ -846,7 +864,7 @@ class DecodeEngine:
                             )
                         new_pool[name] = out
                     else:
-                        new_pool[name] = {key: put_full(layer[key], local[key]) for key in ("k", "v")}
+                        new_pool[name] = {key: put_full(leaf, local[key]) for key, leaf in layer.items()}
                 pool = _constrain_cache(new_pool)
                 return (
                     pool,
@@ -856,18 +874,24 @@ class DecodeEngine:
 
             self._paged_insert_fn = jax.jit(_paged_insert, donate_argnums=(0, 2, 3))
 
-            def _paged_chunk(variables, chunk_ids, pool, tables, slot, position):
+            def _paged_chunk(variables, chunk_ids, pool, tables, slot, position, pick):
                 """One batch-1 prefill chunk written STRAIGHT into the slot's
                 pool blocks through its table row (no local workspace): this is
                 both the chunked-prefill tick and the prefix-hit suffix — the
                 matched prefix is already pool-resident behind the same table,
-                so attending over the gathered row IS the copy-free restore."""
+                so attending over the gathered row IS the copy-free restore.
+                Returns the logits of the chunk's token ``pick`` alone, (1,
+                vocab): the one row a final chunk seeds decoding from (every
+                queued chunk would otherwise hold a chunk x vocab array)."""
                 variables = maybe_dequant(variables)
                 row = jax.lax.dynamic_slice_in_dim(tables, slot, 1, axis=0)  # (1, width)
                 cache = {"table": row, **pool}
-                logits, new_cache = model.apply(variables, chunk_ids, cache=cache, position=position)
+                logits, new_cache = model.apply(
+                    variables, chunk_ids, cache=cache, position=position,
+                    logit_rows=jnp.reshape(pick, (1,)),
+                )
                 pool = {name: leaf for name, leaf in new_cache.items() if name != "table"}
-                return logits, _constrain_cache(pool)
+                return logits[:, 0, :], _constrain_cache(pool)
 
             self._paged_chunk_fn = jax.jit(_paged_chunk, donate_argnums=(2,))
 
@@ -903,14 +927,11 @@ class DecodeEngine:
         Paged mode allocates the block pool + per-slot block tables instead of
         the dense per-slot cache — the pool is the ONLY KV storage, so this is
         also where a rebuild discards a poisoned pool (the step donates it)."""
-        from unionml_tpu.models.gpt import (
-            init_block_pool, init_block_tables, init_cache, init_slot_state,
-        )
+        from unionml_tpu.models.gpt import init_block_tables, init_slot_state
 
         if self.paged:
             self._cache = None
-            pool = init_block_pool(
-                self._config,
+            pool = self._layout.init_block_pool(
                 self.pool_blocks,
                 self._prefix_block_size,
                 kv_quantize=self.kv_quantize,
@@ -920,7 +941,7 @@ class DecodeEngine:
                 self.num_slots, self.max_len, self._prefix_block_size, self._scratch_block
             )
         else:
-            self._cache = init_cache(self._config, self.num_slots, self.max_len)
+            self._cache = self._layout.init_cache(self.num_slots, self.max_len)
         lens = jnp.zeros((self.num_slots,), jnp.int32)
         last_logits = jnp.zeros((self.num_slots, self._config.vocab_size), jnp.float32)
         key = jax.random.PRNGKey(self._seed + self._resets)
@@ -980,7 +1001,6 @@ class DecodeEngine:
         retiring slot's generated tokens for multi-turn reuse. Callable once,
         either via the constructor (``prefix_cache_blocks=``) or after
         construction (serving-app plumbing)."""
-        from unionml_tpu.models.gpt import init_block_pool
         from unionml_tpu.serving.prefix_cache import PrefixCache
 
         if self.prefix_cache is not None:
@@ -1022,8 +1042,7 @@ class DecodeEngine:
                     getattr(self._config, "paged_attn_impl", "auto"),
                     width,
                     block_size,
-                    self._config.num_heads,
-                    self._config.head_dim,
+                    *self._layout.kernel_key,
                 )
                 if self._telemetry is not None:
                     self._telemetry.paged_attn_impl.set(1.0, self.paged_attn_impl)
@@ -1035,7 +1054,7 @@ class DecodeEngine:
         self.prefix_cache = PrefixCache(int(num_blocks), block_size, telemetry=self._telemetry)
         self.prefix_cache_generated = bool(cache_generated)
         self._prefix_block_size = block_size
-        self._pool = init_block_pool(self._config, int(num_blocks), block_size)
+        self._pool = self._layout.init_block_pool(int(num_blocks), block_size)
         if self._mesh is not None:
             self._pool = jax.device_put(self._pool, self._cache_sharding)
 
@@ -1063,6 +1082,17 @@ class DecodeEngine:
             f"({self._buckets[-1]}); raise prefill_buckets/max_len or truncate"
         )
 
+    def check_prefillable(self, prompt_len: int) -> None:
+        """Raise ``ValueError`` unless some prefill path takes a prompt of this
+        length: chunks where ``prefill_chunk`` is set, the prompt is longer
+        than one and its chunks fit the slot's rows (:meth:`_start_chunked`
+        then serves it and asks for no bucket that holds it whole), else the
+        bucket ladder."""
+        chunk = self.prefill_chunk
+        if chunk is not None and prompt_len > chunk and -(-prompt_len // chunk) * chunk <= self.max_len:
+            return
+        self.bucket_for(prompt_len)
+
     def validate_request(
         self,
         prompt_ids: Sequence[int],
@@ -1087,7 +1117,7 @@ class DecodeEngine:
         temperature, top_k, top_p = validate_sampling(temperature, top_k, top_p)
         temperature = self.temperature if temperature is None else temperature
         try:
-            self.bucket_for(prompt.size)  # raises for prompts beyond the bucket ladder
+            self.check_prefillable(int(prompt.size))  # raises for prompts no prefill path takes
         except ValueError:
             # a cached prefix can stand in for the missing bucket: only the
             # uncovered suffix runs prefill, so a preempted transcript longer
@@ -1245,9 +1275,7 @@ class DecodeEngine:
         dense engines (their per-slot caches are not pool-accounted)."""
         if not self.paged or self._pool is None:
             return {}
-        from unionml_tpu.models.gpt import kv_pool_bytes
-
-        stored, full = kv_pool_bytes(self._pool, self._config.dtype)
+        stored, full = self._layout.pool_bytes(self._pool)
         return {
             "kv_dtype": self.kv_quantize or str(jnp.dtype(self._config.dtype).name),
             "kv_pool_bytes": stored,
@@ -1593,10 +1621,9 @@ class DecodeEngine:
                 self.prefix_restore_dispatches += 1
                 if self._faults is not None:
                     self._faults.check_prefill()
-                logits = self._run_paged_chunk(ids, slot, matched)
+                last = self._run_paged_chunk(ids, slot, matched, suffix_len - 1)
                 self.prefill_dispatches += 1
                 self.prefill_tokens_computed += suffix_len
-                last = self._pick_last_fn(logits, jax.device_put(np.int32(suffix_len - 1)))
                 self._seal_slot(slot, int(prompt.size), last)
             except Exception:
                 # release the matched-path references AND the private grant
@@ -1620,13 +1647,12 @@ class DecodeEngine:
             try:
                 if self._faults is not None:
                     self._faults.check_prefill()
-                logits, local_cache = self._chunk_fn(
+                last, local_cache = self._chunk_fn(
                     self._variables, jax.device_put(ids), local_cache,
-                    jax.device_put(np.int32(matched)),
+                    *jax.device_put((np.int32(matched), np.int32(suffix_len - 1))),
                 )
                 self.prefill_dispatches += 1
                 self.prefill_tokens_computed += suffix_len
-                last = self._pick_last_fn(logits, jax.device_put(np.int32(suffix_len - 1)))
                 self._insert_into_slots(
                     local_cache, last,
                     jax.device_put(np.asarray([slot], dtype=np.int32)),
@@ -1648,15 +1674,16 @@ class DecodeEngine:
             self._note_span(slot, "prefill", tokens=suffix_len, restored=matched)
         return True
 
-    def _run_paged_chunk(self, ids: np.ndarray, slot: int, position: int) -> Any:
+    def _run_paged_chunk(self, ids: np.ndarray, slot: int, position: int, pick: int = 0) -> Any:
         """Dispatch one batch-1 prefill chunk straight into ``slot``'s pool
-        blocks (``_paged_chunk_fn``). The pool is DONATED: a dispatch failure
-        consumed the only KV storage, so it poisons the device state — unlike
-        the dense chunked path, a paged chunk death always escalates."""
+        blocks (``_paged_chunk_fn``) and return the (1, vocab) logits of its
+        token ``pick``. The pool is DONATED: a dispatch failure consumed the
+        only KV storage, so it poisons the device state — unlike the dense
+        chunked path, a paged chunk death always escalates."""
         try:
             logits, self._pool = self._paged_chunk_fn(
                 self._variables, jax.device_put(ids), self._pool, self._tables,
-                *jax.device_put((np.int32(slot), np.int32(position))),
+                *jax.device_put((np.int32(slot), np.int32(position), np.int32(pick))),
             )
         except Exception:
             self._device_poisoned = True
@@ -1754,12 +1781,10 @@ class DecodeEngine:
         cached prefix. Held node paths — other slots', pinned checkpoints' —
         now reference orphaned nodes; their later release/unpin calls mutate
         those orphans harmlessly, and re-admissions simply re-index."""
-        from unionml_tpu.models.gpt import init_block_pool
-
         self.prefix_cache.clear()
         self._slot_path.clear()
-        self._pool = init_block_pool(
-            self._config, self.prefix_cache.num_blocks, self._prefix_block_size
+        self._pool = self._layout.init_block_pool(
+            self.prefix_cache.num_blocks, self._prefix_block_size
         )
         if self._mesh is not None:
             self._pool = jax.device_put(self._pool, self._cache_sharding)
@@ -1832,9 +1857,7 @@ class DecodeEngine:
             if self._telemetry is not None:
                 self._note_span(slot, "prefix_hit", matched_tokens=matched, blocks=len(path))
         else:
-            from unionml_tpu.models.gpt import init_cache
-
-            local_cache = init_cache(self._config, 1, padded_len)
+            local_cache = self._layout.init_cache(1, padded_len)
             if self._mesh is not None:
                 local_cache = jax.device_put(local_cache, self._cache_sharding)
         self._reserved[slot] = True
@@ -1862,17 +1885,22 @@ class DecodeEngine:
             prompt, consumed = state["prompt"], state["consumed"]
             chunk = self.prefill_chunk
             take = min(chunk, prompt.size - consumed)
-            ids = np.zeros((1, chunk), dtype=np.int32)
+            # a prompt's last chunk is as wide as the smallest prefill bucket
+            # that holds what is left of it, not a whole chunk of padding
+            width = min((b for b in self._buckets if take <= b <= chunk), default=chunk)
+            ids = np.zeros((1, width), dtype=np.int32)
             ids[0, :take] = prompt[consumed : consumed + take]
             try:
                 if self._faults is not None:
                     self._faults.check_prefill()
+                # the logits of the chunk's last real token: where this is the
+                # prompt's final chunk they seed decoding
                 if self.paged:
-                    logits = self._run_paged_chunk(ids, slot, int(consumed))
+                    last = self._run_paged_chunk(ids, slot, int(consumed), take - 1)
                 else:
-                    logits, state["cache"] = self._chunk_fn(
+                    last, state["cache"] = self._chunk_fn(
                         self._variables, jnp.asarray(ids), state["cache"],
-                        jnp.asarray(consumed, dtype=jnp.int32),
+                        jnp.asarray(consumed, dtype=jnp.int32), jnp.asarray(take - 1, dtype=jnp.int32),
                     )
             except Exception as exc:  # this slot's local dispatch: fail it alone
                 if self._device_poisoned:
@@ -1899,10 +1927,6 @@ class DecodeEngine:
                 )
             if state["consumed"] < prompt.size:
                 continue
-            # final chunk: logits at the prompt's last REAL token seed decoding
-            last = self._pick_last_fn(
-                logits, jax.device_put(np.int32(prompt.size - 1 - consumed))
-            )
             if self.paged:
                 # the KV is already pool-resident behind the slot's row: only
                 # the length + sampling logits need the point-update
@@ -2258,6 +2282,7 @@ class DecodeEngine:
             "idle_dispatches": self.idle_dispatches,
             "active_slot_steps": self.active_slot_steps,
             "live_block_steps": self.live_block_steps,
+            **self.model_counters,
             "phases": self.timeline.snapshot(),
         }
 
@@ -2339,7 +2364,7 @@ class DecodeEngine:
         self.timeline.enter(asked_from)
 
     def _replay_burst(
-        self, burst: Tuple[Any, Any, Any, int], skip: frozenset = frozenset()
+        self, burst: Tuple[Any, Any, Any, int, Dict[str, Any]], skip: frozenset = frozenset()
     ) -> List[StepEvent]:
         """Block on one dispatched burst's ``(tokens, masks, bads)`` and apply them.
 
@@ -2348,7 +2373,7 @@ class DecodeEngine:
         so it fails the engine exactly like a dispatch failure. A flagged
         ``(step, slot)`` quarantines THAT slot (its sampled token is garbage
         and never delivered) while every other slot's tokens apply normally."""
-        tokens, masks, bads, _ = burst
+        tokens, masks, bads, _, counts = burst
         t0 = self.timeline.enter("fetch_wait")
         try:
             if self._faults is not None:
@@ -2357,9 +2382,10 @@ class DecodeEngine:
                     time.sleep(stall_ms / 1e3)  # a wedged device queue, to the watchdog's eye
                 self._faults.check_fetch()
             # graftlint: disable=host-sync -- the ONE designed sync per tick: tokens+masks+nan-flags fused into a single device_get (PR-3 pipelined-decode contract)
-            tokens_host, masks_host, bads_host = map(
-                np.asarray, jax.device_get((tokens, masks, bads))
+            tokens_host, masks_host, bads_host, counts_host = jax.device_get(
+                (tokens, masks, bads, counts)
             )
+            tokens_host, masks_host, bads_host = map(np.asarray, (tokens_host, masks_host, bads_host))
         except Exception:
             self._on_failure()
             raise
@@ -2367,6 +2393,8 @@ class DecodeEngine:
         self.last_heartbeat = time.monotonic()
         block_ms = (done - t0) * 1e3
         self._last_fetch_done = done
+        for name, per_step in counts_host.items():
+            self.model_counters[name] = self.model_counters.get(name, 0) + int(np.sum(per_step))
         events: List[StepEvent] = []
         telemetry = self._telemetry
         emitted: Dict[Optional[str], int] = {}
@@ -2438,9 +2466,11 @@ class DecodeEngine:
         )
         return StepEvent(slot=slot, token=-1, emit=False, finished=True, error="nan_logits")
 
-    def _dispatch_step(self, lookahead: int) -> Tuple[Any, Any, Any, int]:
+    def _dispatch_step(self, lookahead: int) -> Tuple[Any, Any, Any, int, Dict[str, Any]]:
         """Dispatch ONE compiled decode burst; return ``(tokens, masks, bads,
-        n_steps)`` of the in-flight result (device arrays, not yet fetched).
+        n_steps, counts)`` of the in-flight result (device arrays, not yet
+        fetched): ``counts`` holds the model's step counters by name, stacked
+        over the burst's steps, and is empty for a model that has none.
 
         The seam :meth:`step` drives and subclasses override: the speculative
         engine swaps in its round program here (returning ``n_steps`` = the
@@ -2478,6 +2508,7 @@ class DecodeEngine:
                 tokens,
                 masks,
                 bads,
+                counts,
             ) = fn(
                 self._variables, self._pool, self._tables, self._last_logits,
                 self._lens, self._active_dev, self._remaining_dev, self._key,
@@ -2494,12 +2525,13 @@ class DecodeEngine:
                 tokens,
                 masks,
                 bads,
+                counts,
             ) = fn(
                 self._variables, self._cache, self._last_logits, self._lens,
                 self._active_dev, self._remaining_dev, self._key,
                 self._temp_dev, self._top_k_dev, self._top_p_dev,
             )
-        return tokens, masks, bads, lookahead
+        return tokens, masks, bads, lookahead, counts
 
     def step(self, lookahead: int = 1) -> List[StepEvent]:  # graftlint: hot-path
         """Decode for every active slot; returns per-slot events.
@@ -2586,7 +2618,7 @@ class DecodeEngine:
         timeline.enter("dispatch", active=active)
         device_was_idle = self._inflight is None
         try:
-            tokens, masks, bads, lookahead = self._dispatch_step(lookahead)
+            tokens, masks, bads, lookahead, counts = self._dispatch_step(lookahead)
         except Exception:
             self._on_failure()
             raise
@@ -2602,7 +2634,7 @@ class DecodeEngine:
         if device_was_idle and self._last_fetch_done is not None:
             self.idle_dispatches += 1
         previous, prev_skip = self._inflight, self._inflight_skip
-        self._inflight, self._inflight_skip = (tokens, masks, bads, lookahead), set()
+        self._inflight, self._inflight_skip = (tokens, masks, bads, lookahead, counts), set()
         if previous is not None:
             # dispatch-ahead: the new step is already queued on the device
             # while the host blocks on (and then applies) the previous one
@@ -3010,7 +3042,7 @@ class ContinuousBatcher:
         # surface bad requests on the caller's side, not the worker's
         if prompt.size == 0:
             raise ValueError("empty prompt")
-        self._engine.bucket_for(prompt.size)
+        self._engine.check_prefillable(int(prompt.size))
         if self.supervisor is not None and self.supervisor.state == "failed":
             # the rebuild budget is exhausted: fail fast with the structured
             # terminal error instead of queueing work that can never run
@@ -3086,7 +3118,7 @@ class ContinuousBatcher:
         batcher is closed, so the caller can try the next survivor.
         """
         prompt = np.asarray(ticket.prompt, dtype=np.int32).reshape(-1)
-        self._engine.bucket_for(prompt.size)  # unroutable here -> caller tries elsewhere
+        self._engine.check_prefillable(int(prompt.size))  # unroutable here -> caller tries elsewhere
         with self._lock:
             if self._closed:
                 raise EngineFailure("batcher is closed", reason="batcher_closed")

@@ -541,7 +541,7 @@ class EngineFleet:
     @property
     def engine(self) -> Any:
         """Replica 0's engine — the HTTP layer's request-validation surface
-        (``max_len``/``bucket_for``); replicas are homogeneous by contract."""
+        (``max_len``/``check_prefillable``); replicas are homogeneous by contract."""
         return self._replicas[0].engine
 
     @property

@@ -118,6 +118,14 @@ class SpeculativeEngine(DecodeEngine):
             raise ValueError(f"ema_beta must be in (0, 1], got {ema_beta}")
         if not 0.0 <= float(ema_lo) < float(ema_hi) <= 1.0:
             raise ValueError(f"need 0 <= ema_lo < ema_hi <= 1, got lo={ema_lo} hi={ema_hi}")
+        from unionml_tpu.models.gpt import KVCacheLayout
+
+        for role, net in (("target", model), ("draft", draft)):
+            if not isinstance(net.cache_layout(), KVCacheLayout):
+                raise ValueError(
+                    f"SpeculativeEngine with a {type(net.cache_layout()).__name__} {role}: the "
+                    "verify and commit programs write per-head K and V blocks and no other layout"
+                )
         if draft.config.vocab_size != model.config.vocab_size:
             raise ValueError(
                 f"draft vocab ({draft.config.vocab_size}) != target vocab "
@@ -538,7 +546,7 @@ class SpeculativeEngine(DecodeEngine):
 
     # ------------------------------------------------------------------ dispatch/replay
 
-    def _dispatch_step(self, lookahead: int) -> Tuple[Any, Any, Any, int]:
+    def _dispatch_step(self, lookahead: int) -> Tuple[Any, Any, Any, int, Dict[str, Any]]:
         """Route to the round program whenever any active slot speculates or
         samples; otherwise the base (all-greedy) burst — whose argmax emissions
         are exactly the round program's greedy selection, so the stream is
@@ -575,7 +583,7 @@ class SpeculativeEngine(DecodeEngine):
         )
         self._round_bursts[id(masks)] = True
         self.spec_round_dispatches += 1
-        return tokens, masks, bads, self._gamma_max + 1
+        return tokens, masks, bads, self._gamma_max + 1, {}  # a round reads no model counters
 
     def _replay_burst(self, burst, skip=frozenset()):
         """Base replay plus, for round bursts, the host-side mirror of the
@@ -738,8 +746,8 @@ class SpeculativeBatcher:
         # client pins an explicit seed
         self._key = jax.random.PRNGKey(0)  # guarded-by: _lock
         # the /stats view; num_slots=1 states the single-stream design honestly.
-        # bucket_for is the route's prefill-validation hook: speculation prefills
-        # at the exact prompt length (no bucket ladder), so identity is correct.
+        # check_prefillable is the route's prefill-validation hook: speculation
+        # prefills at the exact prompt length (no bucket ladder), so nothing to refuse.
         # requests_admitted / tokens_decoded / prefill_tokens_computed mirror the
         # continuous engine's generation counters, so the stats route reports the
         # same shape whichever generator is plugged in
@@ -748,7 +756,7 @@ class SpeculativeBatcher:
             num_slots=1,
             num_active=0,
             max_len=self._max_len,
-            bucket_for=lambda n: n,
+            check_prefillable=lambda n: None,
             requests_admitted=0,
             tokens_decoded=0,
             prefill_tokens_computed=0,
