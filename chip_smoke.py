@@ -169,10 +169,12 @@ def phase_kernels() -> None:
     block_size, width = 16, 65
 
     def pool_leaves(n_blocks, heads, quantized, code_dtype):
-        """((k, v), scales) of a random pool; ``scales`` is empty for bf16."""
+        """((k, v), scales) of a random pool. bf16: the one joined leaf (rows of
+        ``[key | value]``), no ``v`` and no scales."""
         shape = (n_blocks, heads, block_size, head_dim)
         if not quantized:
-            return [jnp.asarray(rng.normal(size=shape), jnp.bfloat16) for _ in range(2)], []
+            joined = rng.normal(size=shape[:3] + (2 * head_dim,))
+            return [jnp.asarray(joined, jnp.bfloat16), None], []
         codes = [jnp.asarray(rng.integers(-127, 128, shape), code_dtype) for _ in range(2)]
         scales = [
             jnp.asarray(rng.uniform(0.005, 0.02, (n_blocks, heads, 1, 1)), jnp.float32)

@@ -405,3 +405,85 @@ def test_gpt_remat_grads_match_no_remat():
 
     out = generate(GPTLMHeadModel(remat_cfg), variables, jnp.ones((1, 4), jnp.int32), 3, max_len=16)
     assert out.shape == (1, 7)
+
+
+def test_paged_decode_through_joined_leaf_is_bitwise_dense(tiny):
+    """The paged pool's one ``"kv"`` leaf a layer (key beside value in a row),
+    written by row scatters through its ``(blocks, heads * block_size, row)``
+    view, decodes bitwise like the dense ``{"k","v"}`` cache on the XLA arm:
+    same logits every decode step and every chunk, and the rows a table names
+    hold exactly the dense cache's columns. Ragged rows, prompts that end
+    inside a block, a block order that is not the pool's."""
+    from unionml_tpu.models.gpt import KVCacheLayout, block_table_width, init_block_pool, init_cache
+
+    cfg, model, variables = tiny
+    bs, steps = 4, 6
+    lengths = np.asarray([5, 9, 12])
+    batch, width = len(lengths), block_table_width(32, bs)
+    rng = np.random.default_rng(3)
+    prompts = rng.integers(1, cfg.vocab_size, size=(batch, int(lengths.max()))).astype(np.int32)
+
+    # dense: each row prefilled alone (a batch-1 cache each), then stacked; as
+    # long as a table row with its scratch column, so both arms sum as many keys
+    max_len = width * bs
+    dense = init_cache(cfg, batch, max_len)
+    firsts = []
+    for r, n in enumerate(lengths):
+        logits, row = model.apply(
+            variables, jnp.asarray(prompts[r : r + 1, :n]), cache=init_cache(cfg, 1, max_len), position=0
+        )
+        dense = jax.tree_util.tree_map(lambda full, one: full.at[r].set(one[0]), dense, row)
+        firsts.append(logits[0, -1])
+
+    # paged: the dense rows written block-wise into a permuted set of pool blocks
+    layout = KVCacheLayout(cfg)
+    blocks = batch * (width - 1) + 1
+    pool = init_block_pool(cfg, blocks, bs)
+    assert set(pool["layer_0"]) == {"kv"}
+    assert pool["layer_0"]["kv"].shape == (blocks, cfg.num_heads, bs, 2 * cfg.head_dim)
+    assert layout.kernel_key == (cfg.num_heads, 2 * cfg.head_dim)
+    assert layout.pool_bytes(pool) == (layout.block_bytes(bs) * blocks,) * 2
+    table = rng.permutation(blocks - 1).reshape(batch, width - 1).astype(np.int32)
+    table = jnp.asarray(np.concatenate([table, np.full((batch, 1), blocks - 1, np.int32)], axis=1))
+
+    def put(pool_leaf, rows):  # (batch, heads, max_len, row) -> its blocks
+        as_blocks = rows.reshape(batch, cfg.num_heads, width, bs, -1).transpose(0, 2, 1, 3, 4)
+        return pool_leaf.at[table[:, : width - 1]].set(as_blocks[:, : width - 1])
+
+    pool = jax.tree_util.tree_map(put, pool, layout.join(dense))
+
+    lens = jnp.asarray(lengths, jnp.int32)
+    tokens = jnp.argmax(jnp.stack(firsts), axis=-1).astype(jnp.int32)
+    for _ in range(steps):
+        d_logits, dense = model.apply(variables, tokens[:, None], cache=dense, position=lens)
+        p_logits, new = model.apply(
+            variables, tokens[:, None], cache={"table": table, **pool}, position=lens
+        )
+        pool = {name: leaf for name, leaf in new.items() if name != "table"}
+        np.testing.assert_array_equal(np.asarray(p_logits), np.asarray(d_logits))
+        tokens = jnp.argmax(d_logits[:, -1], axis=-1).astype(jnp.int32)
+        lens = lens + 1
+
+    # a batch-1 chunk through a table row (the prefix-hit suffix, a chunked
+    # prefill's tick) against the same chunk into the dense row
+    chunk = jnp.asarray(rng.integers(1, cfg.vocab_size, size=(1, 3)).astype(np.int32))
+    for r in range(batch):
+        one = jax.tree_util.tree_map(lambda leaf: leaf[r : r + 1], dense)
+        d_logits, one = model.apply(variables, chunk, cache=one, position=lens[r])
+        dense = jax.tree_util.tree_map(lambda full, row: full.at[r].set(row[0]), dense, one)
+        p_logits, new = model.apply(
+            variables, chunk, cache={"table": table[r : r + 1], **pool}, position=lens[r]
+        )
+        pool = {name: leaf for name, leaf in new.items() if name != "table"}
+        np.testing.assert_array_equal(np.asarray(p_logits), np.asarray(d_logits))
+    lens = lens + chunk.shape[1]
+
+    for name, layer in pool.items():
+        rows = layer["kv"][table]  # (batch, width, heads, bs, 2 * head_dim)
+        rows = jnp.moveaxis(rows, 2, 1).reshape(batch, cfg.num_heads, width * bs, -1)
+        got = layout.split({name: {"kv": rows}})[name]
+        for r, n in enumerate(np.asarray(lens)):
+            for key in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(got[key][r, :, :n]), np.asarray(dense[name][key][r, :, :n])
+                )

@@ -129,6 +129,58 @@ def mixed_schedule(engine, *, sampled=False):
 # ------------------------------------------------------------------ parity gate
 
 
+def test_blockwise_insert_of_a_prompt_ending_inside_a_block(gpt, gpt_tiny_solo):
+    """The paged insert writes whole blocks, so the tail of a row's last block
+    holds the bucket's padded columns (and a neighbour row's garbage never: the
+    blocks are the row's own). The mask hides them, the decode appends
+    overwrite them one by one: two prompts of one wave that end inside a block
+    (6 and 5 tokens, 4-token blocks, bucket 8) decode token for token like the
+    dense engine and alone, and after every step each row's pool rows are
+    bitwise the dense engine's cache columns, up to the row's length."""
+    import jax.numpy as jnp
+
+    requests = [([3, 1, 4, 1, 5, 9], 7), ([2, 7, 1, 8, 2], 7)]
+    kw = dict(prefix_cache_blocks=0, prefill_batch=2, prefill_chunk=16)  # whole prompts: one bucket wave
+    paged, dense = make_engine(gpt, paged=True, **kw), make_engine(gpt, paged=False, **kw)
+    layout = gpt[0].cache_layout()
+    streams = {}
+    for name, engine in (("paged", paged), ("dense", dense)):
+        slots = engine.admit_many([(prompt, budget, {}) for prompt, budget in requests])
+        assert slots == [0, 1]
+        streams[name] = {slot: [] for slot in slots}
+        for ev in engine.take_pending_events():
+            if ev.emit:
+                streams[name][ev.slot].append(ev.token)
+
+    def assert_rows_equal():
+        table = paged._tables
+        width = table.shape[1]
+        for name, layer in paged._pool.items():
+            rows = layer["kv"][table]  # (slots, width, heads, bs, 2 * head_dim)
+            rows = jnp.moveaxis(rows, 2, 1).reshape(rows.shape[0], rows.shape[2], width * BS, -1)
+            got = layout.split({name: {"kv": rows}})[name]
+            for slot in (0, 1):
+                n = int(paged._lens_host[slot])
+                assert n == int(dense._lens_host[slot])
+                for key in ("k", "v"):
+                    np.testing.assert_array_equal(
+                        np.asarray(got[key][slot, :, :n]),
+                        np.asarray(dense._cache[name][key][slot, :, :n]),
+                    )
+
+    assert paged.prefill_dispatches == 1 and paged.num_active == 2
+    assert_rows_equal()
+    while paged.num_active or paged.has_pending_events:
+        for name, engine in (("paged", paged), ("dense", dense)):
+            for ev in engine.step():
+                if ev.emit:
+                    streams[name][ev.slot].append(ev.token)
+        assert_rows_equal()
+    assert streams["paged"] == streams["dense"]
+    assert [streams["paged"][slot] for slot in (0, 1)] == [gpt_tiny_solo(p, n) for p, n in requests]
+    _assert_no_block_leaks(paged)
+
+
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
 def test_paged_vs_dense_mixed_schedule_parity(gpt, gpt_tiny_solo, sampled):
     """Paged == dense across hit/miss/chunked/cancel, greedy and fixed-seed

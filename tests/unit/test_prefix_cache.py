@@ -278,7 +278,7 @@ def test_mesh_pool_is_head_sharded(gpt):
     same layout as the slot cache, so restores/saves are shard-local."""
     mesh = _mesh({"tensor": 4})
     engine = make_engine(gpt, mesh=mesh, num_slots=2, max_len=32)
-    leaf = engine._pool["layer_0"]["k"]  # (blocks, heads=4, block_size, head_dim)
+    leaf = engine._pool["layer_0"]["kv"]  # (blocks, heads=4, block_size, 2 * head_dim)
     assert len(leaf.sharding.device_set) == 4
     assert leaf.addressable_shards[0].data.shape[1] == 1  # 1 of 4 heads per device
 

@@ -317,9 +317,10 @@ REAL_SHAPES = ["gpt2_small", "gpt2_medium"]
 
 
 def _paged_case(shape, pool, mode):
-    """Abstract operands of one paged call: ``pool`` bf16|int8, ``mode``
-    decode (S=1) | chunk (batch 1, S=64 or 6) | verify (identity table over
-    batch*width local blocks, int8 codes carried as f32)."""
+    """Abstract operands of one paged call: ``pool`` bf16 (the one joined leaf,
+    ``[key | value]`` rows, no value operand) | int8 (code leaves and scales),
+    ``mode`` decode (S=1) | chunk (batch 1, S=64 or 6) | verify (identity table
+    over batch*width local blocks, int8 codes carried as f32)."""
     heads, head_dim, block_size, width, batch = PAGED_SHAPES[shape]
     compute = jnp.bfloat16 if shape in REAL_SHAPES else jnp.float32
     blocks, seq, code_dtype = batch * (width - 1) + 1, 1, jnp.int8
@@ -328,11 +329,12 @@ def _paged_case(shape, pool, mode):
     elif mode == "verify":
         blocks, code_dtype = batch * width, jnp.float32
     quantized = pool == "int8"
-    leaf = jax.ShapeDtypeStruct(
-        (blocks, heads, block_size, head_dim), code_dtype if quantized else compute
-    )
+    if quantized:
+        leaf = jax.ShapeDtypeStruct((blocks, heads, block_size, head_dim), code_dtype)
+    else:
+        leaf = jax.ShapeDtypeStruct((blocks, heads, block_size, 2 * head_dim), compute)
     args = [
-        jax.ShapeDtypeStruct((batch, heads, seq, head_dim), compute), leaf, leaf,
+        jax.ShapeDtypeStruct((batch, heads, seq, head_dim), compute), leaf, leaf if quantized else None,
         jax.ShapeDtypeStruct((batch, width), jnp.int32),
         jax.ShapeDtypeStruct((batch,), jnp.int32),
     ]
@@ -415,8 +417,81 @@ def test_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, shape, pool,
 
     compute, args = _paged_case(shape, pool, mode)
     on_chip = SingleDeviceSharding(v5e_host[0])
-    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip) for a in args]
+    args = [a and jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip) for a in args]
     assert jax.jit(_paged_fn(compute)).lower(*args).compile() is not None
+
+
+@pytest.fixture(scope="module")
+def cell_engine():
+    """A one-layer engine with the closed serving cell's cache shapes (48 slots
+    of 1024 positions, 16 heads of 64, 16-token blocks) over a pool of a few
+    blocks: its programs are lowered for the cell's 3073."""
+    from unionml_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+    from unionml_tpu.serving.continuous import DecodeEngine
+
+    cfg = GPTConfig(
+        vocab_size=512, hidden_size=1024, num_layers=1, num_heads=16,
+        max_position_embeddings=1024, dropout=0.0, dtype=jnp.bfloat16, paged_attn_impl="pallas",
+    )
+    model = GPTLMHeadModel(cfg)
+    variables = model.init({"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32))
+    return DecodeEngine(
+        model, variables, num_slots=48, max_len=1024, prefix_block_size=16, pool_blocks=66
+    )
+
+
+@pytest.mark.parametrize("program", ["decode_step", "paged_insert", "paged_chunk"])
+def test_pool_writers_compile_without_pool_copies(as_on_tpu, v5e_host, cell_engine, program):
+    """The engine's three programs that write a full-precision pool, compiled
+    for a v5e at the closed serving cell's shapes (3073 blocks; one layer) with
+    the pool donated: the decode step (48 appends, then the Mosaic call), the
+    paged insert of a prefill wave (4 rows of 128) and a 64-token chunk through
+    a table row. None may hold a ``copy`` the size of the pool. The layout this
+    guards: one leaf a layer whose rows are 128 lanes wide, written by scatters
+    whose indexed axes lead. With two 64-wide leaves and ``.at[dst, :, off,
+    :]`` XLA re-laid the pool out around every scatter and call: 6, 4 and 6
+    such copies a layer in these programs, 86% of the cell's device time."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    e, blocks = cell_engine, 3073
+    small, vocab = e.pool_blocks, e._last_logits.shape[-1]
+    on_chip = SingleDeviceSharding(v5e_host[0])
+
+    def abstract(tree):
+        def leaf(x):
+            shape = (blocks,) + x.shape[1:] if x.ndim == 4 and x.shape[0] == small else x.shape
+            return jax.ShapeDtypeStruct(shape, x.dtype, sharding=on_chip)
+
+        return jax.tree_util.tree_map(leaf, tree)
+
+    def on(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    if program == "decode_step":
+        lowered = e._make_step(1, False).lower(*abstract((
+            e._variables, e._pool, e._tables, e._last_logits, e._lens, e._active_dev,
+            e._remaining_dev, e._key, e._temp_dev, e._top_k_dev, e._top_p_dev,
+        )))
+    elif program == "paged_insert":
+        rows, bucket = 4, 128
+        local = abstract(jax.eval_shape(lambda: e._layout.init_cache(rows, bucket)))
+        lowered = e._paged_insert_fn.lower(
+            *abstract((e._pool, e._tables, e._lens, e._last_logits)), local,
+            on((rows, vocab), jnp.float32), on((rows,)), on((rows,)),
+        )
+    else:
+        lowered = e._paged_chunk_fn.lower(
+            abstract(e._variables), on((1, 64)), *abstract((e._pool, e._tables)), on(()), on(()), on(()),
+        )
+    text = lowered.compile().as_text()
+    assert f"[{blocks}," in text  # the pool is in the program at the cell's size
+    copies = [
+        line.strip()[:120] for line in text.splitlines()
+        if " copy(" in line and re.search(rf"= \S+\[{blocks},", line)
+    ]
+    assert not copies, f"{len(copies)} pool-sized copies in {program}:\n" + "\n".join(copies)
 
 
 @pytest.mark.parametrize("mode,batch,seq", [("decode", 32, 1), ("chunk", 1, 1024)])
@@ -461,7 +536,7 @@ def test_paged_attention_partitions_over_tensor_mesh(as_on_tpu, v5e_host, shape,
     by_head = NamedSharding(mesh, P(None, "tensor", None, None))
     compute, args = _paged_case(shape, pool, "decode")
     args = [
-        jax.ShapeDtypeStruct(
+        a and jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=by_head if len(a.shape) == 4 else NamedSharding(mesh, P())
         )
         for a in args

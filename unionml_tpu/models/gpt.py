@@ -158,6 +158,32 @@ def _paged_chunk_quantized(pool_q, pool_scale, table_row, position, vals):
     return pool_q.at[dst].set(new_q), pool_scale.at[dst].set(new_scale)
 
 
+def _pool_block_size(layer) -> int:
+    """Tokens a block holds, from one layer's pool leaves (its joined ``"kv"``
+    leaf, or an int8 layer's ``"k"`` codes)."""
+    return layer["kv" if "kv" in layer else "k"].shape[2]
+
+
+def _paged_append_rows(pool_leaf, dst, off, rows):
+    """(jit-traceable) Write one token row per head into a full-precision pool
+    leaf, in place.
+
+    ``pool_leaf`` is ``(blocks, heads, block_size, width)``; token ``i`` goes
+    to offset ``off[i]`` of pool block ``dst[i]`` (both ``(n,)``), ``rows`` is
+    ``(n, heads, width)``. The scatter runs through the view ``(blocks, heads *
+    block_size, width)`` so that its two indexed axes are leading: a scatter
+    with a full slice BETWEEN indexed axes (``.at[dst, :, off, :]``) makes XLA
+    re-lay the whole pool out around it, whatever the width (three pool-sized
+    copies a leaf a step on a v5e). Heads stay the major part of the merged
+    axis, so a pool sharded by heads stays sharded through the view.
+    """
+    blocks, heads, block_size, width = pool_leaf.shape
+    view = pool_leaf.reshape(blocks, heads * block_size, width)
+    cols = jnp.arange(heads, dtype=jnp.int32)[None, :] * block_size + off[:, None]
+    view = view.at[dst[:, None], cols].set(rows.astype(pool_leaf.dtype))
+    return view.reshape(pool_leaf.shape)
+
+
 def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="auto", mesh=None):
     """(jit-traceable) Speculative verify: attention context for ``S`` chunk
     tokens per row over the row's paged prefix, WITHOUT writing the pool.
@@ -169,8 +195,8 @@ def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="
     permanently inflate a block's monotone absmax scale. Numerics are
     BIT-IDENTICAL to feeding the chunk one token at a time through the decode
     append: each scan step mirrors the append arithmetic
-    (:func:`_paged_append_quantized` / the fp ``.at[].set``) into a LOCAL
-    gathered copy of the row's blocks — ``(batch, width, heads, bs, hd)``, the
+    (:func:`_paged_append_quantized` / :func:`_paged_append_rows`) into a LOCAL
+    gathered copy of the row's blocks — ``(batch, width, heads, bs, row)``, the
     pool's own block layout — and attends through
     :func:`unionml_tpu.ops.paged_attention.paged_attention` over an identity
     table, so the verify step runs the SAME per-block arithmetic (same
@@ -182,7 +208,7 @@ def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="
     tokens per dispatch.
     """
     batch, heads, S, head_dim = q.shape
-    block_size = cache["k"].shape[2]
+    block_size = _pool_block_size(cache)
     width = block_table.shape[1]
     capacity = width * block_size
     quantized = "k_scale" in cache
@@ -192,7 +218,7 @@ def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="
     local_table = (b_idx[:, None] * width + jnp.arange(width)[None, :]).astype(jnp.int32)
 
     def local(leaf):
-        # (batch, width, heads, bs, hd): the row's blocks, block structure kept
+        # (batch, width, heads, bs, row): the row's blocks, block structure kept
         return leaf[block_table]
 
     def flat(x):
@@ -205,7 +231,7 @@ def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="
             local(cache["v"]).astype(jnp.float32), local(cache["v_scale"]),
         )
     else:
-        state = (local(cache["k"]), local(cache["v"]))
+        state = flat(local(cache["kv"]))
 
     def append_q(codes, scales, blk, off, vals):
         # _paged_append_quantized on the gathered layout, arithmetic bit for bit
@@ -243,13 +269,11 @@ def _paged_verify_chunk(cache, block_table, position, q, k, v, out_dtype, impl="
                 mesh=mesh,
             )
         else:
-            kb, vb = state
-            kb = kb.at[b_idx, blk, :, off].set(kj.astype(kb.dtype))
-            vb = vb.at[b_idx, blk, :, off].set(vj.astype(vb.dtype))
-            state = (kb, vb)
+            state = _paged_append_rows(
+                state, b_idx * width + blk, off, jnp.concatenate([kj, vj], axis=-1)
+            )
             ctx = paged_attention(
-                qj, flat(kb), flat(vb), local_table, pos, out_dtype=out_dtype, impl=impl,
-                mesh=mesh,
+                qj, state, None, local_table, pos, out_dtype=out_dtype, impl=impl, mesh=mesh,
             )
         return state, ctx[:, :, 0, :]
 
@@ -271,7 +295,7 @@ def paged_commit_chunk(layer_cache, block_table, position, counts, ck, cv):
     produced for the accepted prefix.
     """
     quantized = "k_scale" in layer_cache
-    block_size = layer_cache["k"].shape[2]
+    block_size = _pool_block_size(layer_cache)
     width = block_table.shape[1]
     capacity = width * block_size
     sentinel = (width - 1) * block_size
@@ -290,10 +314,7 @@ def paged_commit_chunk(layer_cache, block_table, position, counts, ck, cv):
             kq, ks = _paged_append_quantized(kq, ks, dst, off, kj)
             vq, vs = _paged_append_quantized(vq, vs, dst, off, vj)
             return (kq, ks, vq, vs), None
-        kb, vb = carry
-        kb = kb.at[dst, :, off, :].set(kj.astype(kb.dtype))
-        vb = vb.at[dst, :, off, :].set(vj.astype(vb.dtype))
-        return (kb, vb), None
+        return _paged_append_rows(carry, dst, off, jnp.concatenate([kj, vj], axis=-1)), None
 
     if quantized:
         carry = (
@@ -302,9 +323,8 @@ def paged_commit_chunk(layer_cache, block_table, position, counts, ck, cv):
         )
         (kq, ks, vq, vs), _ = jax.lax.scan(step, carry, jnp.arange(S, dtype=jnp.int32))
         return {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
-    carry = (layer_cache["k"], layer_cache["v"])
-    (kb, vb), _ = jax.lax.scan(step, carry, jnp.arange(S, dtype=jnp.int32))
-    return {"k": kb, "v": vb}
+    leaf, _ = jax.lax.scan(step, layer_cache["kv"], jnp.arange(S, dtype=jnp.int32))
+    return {"kv": leaf}
 
 
 class DecoderBlock(nn.Module):
@@ -334,17 +354,21 @@ class DecoderBlock(nn.Module):
         packed-sequence training (cache=None only): causal attention additionally
         confined to same-segment tokens. Returns (hidden, new_cache).
 
-        Paged contract (``block_table`` given): ``cache`` holds ``{"k","v"}`` pool
-        leaves of shape (num_blocks, heads, block_size, head_dim) shared by every
-        row, and ``block_table`` is an int32 (batch, width) map from a row's
-        logical block index to its pool block. Token position ``p`` lives at
-        block ``table[row, p // block_size]``, offset ``p % block_size``. Writes
-        scatter into the tail block in place; reads gather the row's table —
-        contiguous logical order, so the mask arithmetic is identical to the
-        dense path and outputs match it bitwise (masked columns hit exp(-inf)=0
-        exactly). The engine keeps the last table column pointed at a scratch
-        block and encodes retired rows' positions past ``(width-1)*block_size``,
-        so their unavoidable scatter lands in scratch, never in a reused block.
+        Paged contract (``block_table`` given): ``cache`` holds the layer's pool
+        leaf ``{"kv"}`` of shape (num_blocks, heads, block_size, 2 * head_dim)
+        shared by every row — a token's key and value of one head side by side
+        in one row (an int8 layer holds ``{"k","v"}`` code leaves (..., head_dim)
+        and their scales instead: :func:`init_block_pool`) — and ``block_table``
+        is an int32 (batch, width) map from a row's logical block index to its
+        pool block. Token position ``p`` lives at block ``table[row, p //
+        block_size]``, offset ``p % block_size``. Writes scatter rows into the
+        tail block in place (:func:`_paged_append_rows`); reads gather the row's
+        table — contiguous logical order, so the mask arithmetic is identical to
+        the dense path and outputs match it bitwise (masked columns hit
+        exp(-inf)=0 exactly). The engine keeps the last table column pointed at
+        a scratch block and encodes retired rows' positions past
+        ``(width-1)*block_size``, so their unavoidable scatter lands in scratch,
+        never in a reused block.
         """
         cfg = self.config
         batch, seq, _ = hidden.shape
@@ -404,14 +428,14 @@ class DecoderBlock(nn.Module):
                 )
                 new_cache = {**cache, "ck": k, "cv": v}
             else:
-                block_size = cache["k"].shape[2]
+                # an int8-quantized pool announces itself structurally: scale leaves
+                # ride next to its k/v code leaves (see init_block_pool), so
+                # skip-listed layers fall through to the full-precision leaf
+                # with zero config plumbing
+                quantized = "k_scale" in cache
+                block_size = _pool_block_size(cache)
                 width = block_table.shape[1]
                 capacity = width * block_size
-                # an int8-quantized pool announces itself structurally: scale leaves
-                # ride next to k/v (see init_block_pool), so skip-listed layers fall
-                # through to the full-precision path with zero config plumbing
-                quantized = "k_scale" in cache
-                k_scale = v_scale = None
                 if per_row:
                     # decode: each row appends one token into its own tail block
                     pos = jnp.clip(position.astype(jnp.int32), 0, capacity - 1)
@@ -425,8 +449,8 @@ class DecoderBlock(nn.Module):
                             cache["v"], cache["v_scale"], dst, off, v[:, :, 0, :]
                         )
                     else:
-                        k_cache = cache["k"].at[dst, :, off, :].set(k[:, :, 0, :].astype(cache["k"].dtype))
-                        v_cache = cache["v"].at[dst, :, off, :].set(v[:, :, 0, :].astype(cache["v"].dtype))
+                        rows = jnp.concatenate([k[:, :, 0, :], v[:, :, 0, :]], axis=-1)
+                        kv_cache = _paged_append_rows(cache["kv"], dst, off, rows)
                 else:
                     # chunked prefill through the table (batch=1): scatter the chunk's
                     # K/V at positions [position, position+seq) of row 0's blocks
@@ -443,33 +467,33 @@ class DecoderBlock(nn.Module):
                         pos = jnp.clip((position + jnp.arange(seq)).astype(jnp.int32), 0, capacity - 1)
                         blk, off = pos // block_size, pos % block_size
                         dst = jnp.take(block_table[0], blk)
-                        k_cache = cache["k"].at[dst, :, off, :].set(
-                            jnp.moveaxis(k[0], 1, 0).astype(cache["k"].dtype)
-                        )
-                        v_cache = cache["v"].at[dst, :, off, :].set(
-                            jnp.moveaxis(v[0], 1, 0).astype(cache["v"].dtype)
-                        )
+                        rows = jnp.moveaxis(jnp.concatenate([k[0], v[0]], axis=-1), 1, 0)
+                        kv_cache = _paged_append_rows(cache["kv"], dst, off, rows)
 
                 # attend through the table: impl="xla" is the historical
                 # gather-dequant-attend (bitwise-preserved in
                 # ops.paged_attention.xla_paged_attention); "pallas"/"auto"-on-TPU
-                # runs the fused kernel that reads int8 codes + scales straight
-                # off the pool — no dense dequantized gather copy in HBM. The
-                # positional mask is base-position arithmetic either way:
-                # query token s of row b sits at base[b] + s.
+                # runs the fused kernel that reads the pool where it lies (int8
+                # codes + scales, or the joined rows) — no dense gathered copy
+                # in HBM. The positional mask is base-position arithmetic either
+                # way: query token s of row b sits at base[b] + s.
                 if per_row:
                     base = position.astype(jnp.int32)
                 else:
                     base = jnp.reshape(jnp.asarray(position, jnp.int32), (1,))
-                context = paged_attention(
-                    q, k_cache, v_cache, block_table, base,
-                    k_scale=k_scale, v_scale=v_scale,
-                    out_dtype=cfg.dtype, impl=cfg.paged_attn_impl, mesh=cfg.tp_mesh,
-                )
-                new_cache = {"k": k_cache, "v": v_cache}
                 if quantized:
-                    new_cache["k_scale"] = k_scale
-                    new_cache["v_scale"] = v_scale
+                    context = paged_attention(
+                        q, k_cache, v_cache, block_table, base,
+                        k_scale=k_scale, v_scale=v_scale,
+                        out_dtype=cfg.dtype, impl=cfg.paged_attn_impl, mesh=cfg.tp_mesh,
+                    )
+                    new_cache = {"k": k_cache, "v": v_cache, "k_scale": k_scale, "v_scale": v_scale}
+                else:
+                    context = paged_attention(
+                        q, kv_cache, None, block_table, base,
+                        out_dtype=cfg.dtype, impl=cfg.paged_attn_impl, mesh=cfg.tp_mesh,
+                    )
+                    new_cache = {"kv": kv_cache}
         else:
             per_row = not isinstance(position, int) and jnp.ndim(position) == 1
             if per_row and seq != 1:
@@ -699,42 +723,51 @@ def init_block_pool(
     kv_quantize: Optional[str] = None,
     kv_quantize_skip_layers: Tuple[int, ...] = (),
 ) -> Dict[str, Any]:
-    """Zeroed KV block pool for prefix caching: ``(num_blocks, heads, block_size,
-    head_dim)`` per layer, the serving engine's reuse store for prompt-prefix KV.
+    """Zeroed KV block pool: one leaf a layer, ``"kv"``, ``(num_blocks, heads,
+    block_size, 2 * head_dim)`` — the paged engine's only KV storage and the
+    prefix cache's reuse store.
+
+    A token's key and value of one head lie side by side in one row: key in
+    columns ``[:head_dim]``, value in ``[head_dim:]``. At GPT-2's ``head_dim``
+    of 64 (small to XL) a row is exactly the TPU's 128 lanes, which is what
+    lets XLA append to the leaf in place and hand it to the Mosaic kernel where
+    it lies: a leaf whose rows are not a multiple of 128 lanes is re-laid-out
+    around every scatter and call (a whole-pool copy each), and stays correct.
+    The writers keep their indexed axes leading for the same reason
+    (:func:`_paged_append_rows`; block-wise ``.at[block_ids].set``).
 
     Heads sit on the same axis as :func:`init_cache` leaves, so the pool shards
     with the identical head-sharded spec (:func:`kv_block_spec`) and pool↔slot
     copies stay shard-local on a mesh (gather/scatter over the unsharded block
     axis only).
 
-    ``kv_quantize="int8"`` stores K/V as symmetric int8 with per-block-per-head
-    f32 scales resident alongside (``k_scale``/``v_scale``, shape ``(blocks,
-    heads, 1, 1)`` — rank-4 so the one head-sharded spec covers every leaf and
-    scale gathers stay shard-local). Layers listed in
-    ``kv_quantize_skip_layers`` keep full-precision leaves (no scale entries) —
-    the attention layer detects the mode structurally per layer, so mixed pools
-    need no extra plumbing.
+    ``kv_quantize="int8"`` stores K and V as symmetric int8 code leaves
+    ``"k"``/``"v"`` ``(blocks, heads, block_size, head_dim)`` with
+    per-block-per-head f32 scales resident alongside (``k_scale``/``v_scale``,
+    shape ``(blocks, heads, 1, 1)`` — rank-4 so the one head-sharded spec covers
+    every leaf and scale gathers stay shard-local). Layers listed in
+    ``kv_quantize_skip_layers`` keep the full-precision ``"kv"`` leaf (no scale
+    entries) — the attention layer detects the mode structurally per layer, so
+    mixed pools need no extra plumbing. (The int8 leaves are 64 lanes wide and
+    their block re-quantising append is no row scatter: XLA still copies them.)
     """
     dtype = dtype if dtype is not None else config.dtype
     if kv_quantize not in (None, "int8"):
         raise ValueError(f"kv_quantize must be None or 'int8', got {kv_quantize!r}")
     skip = frozenset(int(i) for i in kv_quantize_skip_layers)
-    shape = (num_blocks, config.num_heads, block_size, config.head_dim)
+    code_shape = (num_blocks, config.num_heads, block_size, config.head_dim)
     scale_shape = (num_blocks, config.num_heads, 1, 1)
     pool: Dict[str, Any] = {}
     for i in range(config.num_layers):
         if kv_quantize == "int8" and i not in skip:
             pool[f"layer_{i}"] = {
-                "k": jnp.zeros(shape, dtype=jnp.int8),
-                "v": jnp.zeros(shape, dtype=jnp.int8),
+                "k": jnp.zeros(code_shape, dtype=jnp.int8),
+                "v": jnp.zeros(code_shape, dtype=jnp.int8),
                 "k_scale": jnp.zeros(scale_shape, dtype=jnp.float32),
                 "v_scale": jnp.zeros(scale_shape, dtype=jnp.float32),
             }
         else:
-            pool[f"layer_{i}"] = {
-                "k": jnp.zeros(shape, dtype=dtype),
-                "v": jnp.zeros(shape, dtype=dtype),
-            }
+            pool[f"layer_{i}"] = {"kv": jnp.zeros(code_shape[:3] + (2 * config.head_dim,), dtype=dtype)}
     return pool
 
 
@@ -758,6 +791,7 @@ def kv_block_bytes(
             # int8 k + v, plus one f32 scale each per head
             total += config.num_heads * (2 * per_head * 1 + 2 * 4)
         else:
+            # the joined leaf: a key and a value a row
             total += config.num_heads * 2 * per_head * full_itemsize
     return total
 
@@ -783,14 +817,36 @@ class KVCacheLayout:
     engine takes its dense cache, its block pool, their sharding, their bytes
     and the paged kernel's shape key from ``model.cache_layout()``; tables,
     scatters and gathers are the engine's own and work on any layout whose
-    leaves are ``(rows | blocks, heads, tokens, dim)``."""
+    leaves are ``(rows | blocks, heads, tokens, dim)``.
+
+    The dense cache (a slot's rows, a prefill's workspace) keeps a ``"k"`` and a
+    ``"v"`` leaf a layer; a full-precision pool holds them joined in one
+    ``"kv"`` leaf (:func:`init_block_pool`). :meth:`join` and :meth:`split`
+    carry a tree from the one naming to the other, whatever its leading axes."""
 
     def __init__(self, config: GPTConfig) -> None:
         self.config = config
         #: heads of a cache leaf: what a ``tensor`` mesh axis has to divide
         self.kv_heads = config.num_heads
-        #: ``(heads, last dimension)`` of the paged kernel's call
-        self.kernel_key = (config.num_heads, config.head_dim)
+        #: ``(heads, last dimension)`` of the paged kernel's call over the
+        #: joined leaf (an int8 layer's call is ``head_dim`` wide)
+        self.kernel_key = (config.num_heads, 2 * config.head_dim)
+
+    def join(self, cache: Dict[str, Any]) -> Dict[str, Any]:
+        """Dense-cache layers ``{"k", "v"}`` (..., head_dim) as the pool's
+        ``{"kv"}`` (..., 2 * head_dim): each key beside its value."""
+        return {
+            name: {"kv": jnp.concatenate([layer["k"], layer["v"]], axis=-1)}
+            for name, layer in cache.items()
+        }
+
+    def split(self, tree: Dict[str, Any]) -> Dict[str, Any]:
+        """The inverse of :meth:`join`: pool-named layers back to ``{"k", "v"}``."""
+        head_dim = self.config.head_dim
+        return {
+            name: {"k": layer["kv"][..., :head_dim], "v": layer["kv"][..., head_dim:]}
+            for name, layer in tree.items()
+        }
 
     def init_cache(self, batch: int, max_len: int) -> Dict[str, Any]:
         return init_cache(self.config, batch, max_len)
@@ -876,7 +932,7 @@ def init_block_tables(
 
 def kv_block_spec(config: GPTConfig, mesh_axis_names: Tuple[str, ...]) -> Any:
     """PartitionSpec for KV block-pool leaves ``(blocks, heads, block_size,
-    head_dim)``: heads on ``tensor``, exactly like :func:`kv_cache_spec`, so
+    row)``: heads on ``tensor``, exactly like :func:`kv_cache_spec`, so
     restoring a pool block into a slot's cache rows never reshards."""
     return kv_cache_spec(config, mesh_axis_names)
 
@@ -884,18 +940,19 @@ def kv_block_spec(config: GPTConfig, mesh_axis_names: Tuple[str, ...]) -> Any:
 def gather_block_prefix(pool: Dict[str, Any], block_ids: jax.Array, pad_len: int) -> Dict[str, Any]:
     """(jit-traceable) Gather pool blocks into a batch-1 cache holding the prefix.
 
-    ``block_ids`` is ``(n,)``; the result is a cache pytree of ``(1, heads,
-    pad_len, head_dim)`` leaves whose first ``n * block_size`` columns are the
+    ``block_ids`` is ``(n,)``; the result is a pytree of ``(1, heads, pad_len,
+    row)`` leaves, named and as wide as the pool's (a layout's ``split`` makes a
+    dense cache of them), whose first ``n * block_size`` columns are the
     gathered blocks in order (the rest zero, to be written by the suffix
     prefill). The gather indexes the unsharded block axis, so under a
     head-sharded mesh layout the copy is shard-local.
     """
 
     def gather(leaf):
-        blocks = leaf[block_ids]  # (n, heads, block_size, head_dim)
-        n, heads, block_size, head_dim = blocks.shape
-        prefix = jnp.moveaxis(blocks, 0, 1).reshape(heads, n * block_size, head_dim)
-        out = jnp.zeros((1, heads, pad_len, head_dim), leaf.dtype)
+        blocks = leaf[block_ids]  # (n, heads, block_size, row)
+        n, heads, block_size, row = blocks.shape
+        prefix = jnp.moveaxis(blocks, 0, 1).reshape(heads, n * block_size, row)
+        out = jnp.zeros((1, heads, pad_len, row), leaf.dtype)
         return out.at[0, :, : n * block_size, :].set(prefix)
 
     return jax.tree_util.tree_map(gather, pool)
@@ -905,7 +962,8 @@ def slice_cache_blocks(
     cache: Dict[str, Any], row: jax.Array, start_block: jax.Array, num_blocks: int, block_size: int
 ) -> Dict[str, Any]:
     """(jit-traceable) Slice blocks ``[start, start + num_blocks)`` of one cache
-    row into pool layout ``(num_blocks, heads, block_size, head_dim)`` per layer.
+    row into block order ``(num_blocks, heads, block_size, head_dim)`` per leaf
+    (a layout's ``join`` makes pool leaves of them).
 
     ``row`` and ``start_block`` may be traced scalars (one compile per
     ``num_blocks`` count, not per slot or offset); the slice covers cache

@@ -244,6 +244,13 @@ class LatentCacheLayout:
             for i in range(self.config.num_layers)
         }
 
+    def join(self, cache: Dict[str, Any]) -> Dict[str, Any]:
+        """A dense cache's layers as the pool names them: the same one leaf."""
+        return cache
+
+    def split(self, tree: Dict[str, Any]) -> Dict[str, Any]:
+        return tree
+
     def cache_spec(self, mesh_axis_names: Tuple[str, ...]) -> Any:
         from jax.sharding import PartitionSpec
 

@@ -1,16 +1,18 @@
 """Paged-attention decode: fused Pallas dequant-attend straight off the block pool.
 
 The paged serving path (PRs 11/14) keeps every slot's KV in a shared block pool
-— int8 codes plus per-(block, head) scales under ``kv_quantize`` — and the XLA
-decode step pays a ``pool[table]`` gather that materializes a dense, dequantized
-KV copy before attending (``models/gpt.py`` ``gather_table``). On real HBM that
-copy is ~4x the bytes the int8 codes occupy, per step, per layer. The kernel
-here deletes it: a grid step DMAs the pool blocks that eight entries of the
-slot's block-table row name (scalar-prefetched, so the index feeds the DMA
-engine), every local head of each block at once, dequantizes in VMEM, and folds
-the 128 keys into an online-softmax accumulation — flash-decoding over the
-table indirection. HBM traffic per step is the int8 codes + scales of the
-blocks that hold a row's keys; the bf16-pool variant simply skips the dequant.
+— one leaf a layer whose rows hold a head's key beside its value, or int8 codes
+plus per-(block, head) scales under ``kv_quantize`` — and the XLA decode step
+pays a ``pool[table]`` gather that materializes a dense, dequantized KV copy
+before attending (``models/gpt.py`` ``gather_table``). On real HBM that copy is
+~4x the bytes the int8 codes occupy, per step, per layer. The kernel here
+deletes it: a grid step DMAs the pool blocks that eight entries of the slot's
+block-table row name (scalar-prefetched, so the index feeds the DMA engine),
+every local head of each block at once, dequantizes in VMEM, and folds the 128
+keys into an online-softmax accumulation — flash-decoding over the table
+indirection. HBM traffic per step is the rows (or the int8 codes + scales) of
+the blocks that hold a row's keys; the full-precision variant simply skips the
+dequant.
 
 Two implementations behind one dispatcher (the ``ops/attention.py`` contract):
 
@@ -38,8 +40,9 @@ block_size`` and in no other. The tiles that start past that column are
 neither fetched nor computed: their index maps repeat the row's last live
 tile, and a block index that repeats skips its DMA; ``pl.when`` skips the
 body. What is left of such a grid step is its fixed cost (index maps and DMA
-bookkeeping of the step's 16 pool operands, about 1.5 us on a v5e), which a
-row pays ``tiles`` times whatever it holds. A retired row carries the engine's
+bookkeeping of the step's pool operands: 8 over the joined leaf, 16 over an
+int8 pool's two code leaves and 16 more for their scales), which a row pays
+``tiles`` times whatever it holds. A retired row carries the engine's
 sentinel base ``(width - 1) * block_size``: its live range is the whole table,
 so it walks all of its tiles, every entry its scratch block, at the cost of a
 full row (about a third more than a short row's).
@@ -47,31 +50,39 @@ full row (about a third more than a short row's).
 Why the pool blocks come through BlockSpecs, one operand per table entry of a
 tile, and not through ``make_async_copy`` from a pool left in ``pl.ANY``:
 Mosaic refuses any slice of an HBM ref whose last dimension is not a multiple
-of 128 lanes (``head_dim`` is 64), the whole-block slice included; a BlockSpec
-whose last two dims equal the array's is the form it takes.
+of 128 lanes (an int8 pool's ``head_dim`` of 64), the whole-block slice
+included; a BlockSpec whose last two dims equal the array's is the form it
+takes. (The joined leaf and the latent leaf are whole lanes wide, so copies by
+hand are open to them: a kernel PR of its own.)
 
 One body serves every cache layout. The pool's key heads may be fewer than the
 query heads (a step's K tile is fetched once for all the query heads that
-share it: they ride as rows of one matrix product), the values may be the keys
-themselves (``v=None``: absorbed latent attention, one key row a token whose
-leading columns are its values, so a tile is one DMA, not two), and the last
-dimension is whatever the call's leaves have (a multiple of 128 keeps XLA from
-re-laying the pool out around the call).
+share it: they ride as rows of one matrix product), one leaf may serve as keys
+and values (``v=None``, a tile is one DMA, not two: absorbed latent attention,
+one key row a token whose leading columns are its values; or per-head rows of
+``[key | value]``, which the zero-padded query scores whole and whose value
+columns the caller of the kernel keeps), and the last dimension is whatever
+the call's leaves have (a multiple of 128 keeps XLA from re-laying the pool out
+around the call).
 
 Under a device mesh the kernel runs inside ``shard_map`` with the pool's heads
 local to each ``tensor`` shard (``mesh=``): a Mosaic custom call is opaque to
 the SPMD partitioner, which refuses it ("Mosaic kernels cannot be
 automatically partitioned") wherever a multi-device ``jit`` meets it bare.
 
-Layout contract (matches ``init_block_pool``): pool leaves are
-``(num_blocks, heads, block_size, head_dim)``; scales ``(num_blocks, heads, 1,
-1)`` f32; ``block_table`` is ``(batch, width)`` int32; a query token at logical
-position ``p`` attends keys at logical positions ``k <= p``, where logical
-column ``c = w * block_size + o`` lives in pool block ``table[row, w]``. Table
-columns past a row's live range point at the engine's scratch block — their
-positions exceed every live query position, so no per-row length plumbing is
-needed beyond the base positions: the bound above is reckoned from them, and
-inside the last live block the positional mask discards the rest.
+Layout contract (matches ``init_block_pool``): a full-precision layer is one
+leaf ``(num_blocks, heads, block_size, 2 * head_dim)``, a token's key of one
+head in a row's leading ``head_dim`` columns and its value in the rest; an int8
+layer is two code leaves ``(num_blocks, heads, block_size, head_dim)`` and
+their scales ``(num_blocks, heads, 1, 1)`` f32; a latent layer is one leaf
+``(num_blocks, 1, block_size, row)``; ``block_table`` is ``(batch, width)``
+int32; a query token at logical position ``p`` attends keys at logical
+positions ``k <= p``, where logical column ``c = w * block_size + o`` lives in
+pool block ``table[row, w]``. Table columns past a row's live range point at
+the engine's scratch block — their positions exceed every live query position,
+so no per-row length plumbing is needed beyond the base positions: the bound
+above is reckoned from them, and inside the last live block the positional mask
+discards the rest.
 """
 
 import functools
@@ -106,8 +117,9 @@ def xla_paged_attention(
     block-structure flatten, then :func:`xla_attention` under the positional
     mask ``k_pos <= base + s``. This is the exactness reference the kernel's
     parity gates pin against, and the off-TPU arm of the dispatcher. Fewer key
-    heads than query heads, and ``v=None`` (the keys are the values), read as
-    in :func:`paged_attention`.
+    heads than query heads, and ``v=None`` (one leaf: its rows are the values
+    too, or, where they are wider than the query, ``[key | value]``, which the
+    gathered rows are split into), read as in :func:`paged_attention`.
     """
     batch, heads, S, head_dim = q.shape
     kv_heads, block_size = k.shape[1], k.shape[2]
@@ -126,7 +138,12 @@ def xla_paged_attention(
     q_pos = base_positions.astype(jnp.int32)[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     mask = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None, :, :]
     keys = gather(k, k_scale)
-    values = keys if v is None else gather(v, v_scale)
+    if v is not None:
+        values = gather(v, v_scale)
+    elif keys.shape[-1] > head_dim:
+        keys, values = keys[..., :head_dim], keys[..., head_dim:]
+    else:
+        values = keys
     if group > 1:
         # a key head's query heads side by side as rows (head-major), one mask each
         q = q.reshape(batch, kv_heads, group * S, head_dim)
@@ -461,9 +478,16 @@ def paged_attention(
         dtype. ``key_heads`` divides ``heads``: consecutive query heads share a
         key head (one DMA a tile for all of them; 1 for a latent cache). The
         key leaf's ``dim`` is ``head_dim`` and the value leaf's may differ.
-        ``v=None``: the keys are the values too (absorbed latent attention,
-        whose values are the leading columns of the key row: the caller slices
-        the ``(batch, heads, S, head_dim)`` output). (The speculative-verify
+        ``v=None``: one leaf. Where its rows are as wide as the query, the keys
+        are the values too (absorbed latent attention, whose values are the
+        leading columns of the key row: the caller slices the ``(batch, heads,
+        S, head_dim)`` output). Where they are wider, a row is ``[key |
+        value]``, the key in its leading ``head_dim`` columns
+        (:func:`unionml_tpu.models.gpt.init_block_pool`'s joined leaf), and the
+        output is ``(batch, heads, S, dim - head_dim)``: the kernel scores the
+        zero-padded query against whole rows (the value columns meet zeros: the
+        same sum), accumulates ``probs @ rows`` and keeps the value columns; a
+        tile is still one DMA. (The speculative-verify
         path passes its gathered local state reshaped to this layout with an
         identity table; codes may then be f32 holding exact integers — the
         dequant arithmetic is dtype-agnostic.)
@@ -498,11 +522,11 @@ def paged_attention(
         raise ValueError("keys that are the values too (v=None) take no int8 scales")
     out_dtype = q.dtype if out_dtype is None else out_dtype
     batch, heads, S, head_dim = q.shape
-    key_heads, block_size = k.shape[1], k.shape[2]
+    key_heads, block_size, row = k.shape[1], k.shape[2], k.shape[3]
     if heads % key_heads:
         raise ValueError(f"{heads} query heads do not divide over {key_heads} key heads")
     width = block_table.shape[1]
-    impl = resolve_paged_impl(impl, width, block_size, heads, head_dim)
+    impl = resolve_paged_impl(impl, width, block_size, heads, row)
     # both arms carry one scope name, so that a trace finds the kernel's
     # operations whichever arm ran
     if impl == "xla":
@@ -518,6 +542,9 @@ def paged_attention(
         )
     scale = 1.0 / np.sqrt(head_dim) if sm_scale is None else sm_scale
     shared_kv = v is None
+    joined = shared_kv and row > head_dim  # rows of [key | value]
+    if joined:
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, row - head_dim),))
 
     def kernel(q, k, *rest):
         # a key head's query heads side by side as rows, head-major: free of
@@ -528,7 +555,7 @@ def paged_attention(
         block_table, base_positions, *scales = rest
         k_scale, v_scale = scales or (None, None)
         out = _paged_forward(
-            q.reshape(batch, local_keys, local_heads // local_keys * S, head_dim),
+            q.reshape(batch, local_keys, local_heads // local_keys * S, q.shape[-1]),
             k, v, block_table, base_positions, k_scale, v_scale, out_dtype, scale, S, interpret,
         )
         return out.reshape(batch, local_heads, S, out.shape[-1])
@@ -553,7 +580,8 @@ def paged_attention(
             kernel, mesh=mesh, in_specs=in_specs, out_specs=by_head, check_vma=False
         )
     with jax.named_scope("paged_attention"):
-        return kernel(*operands)
+        out = kernel(*operands)
+    return out[..., head_dim:] if joined else out
 
 
 def fused_hbm_bytes(

@@ -74,15 +74,16 @@ BLOCK_CANDIDATES: Tuple[int, ...] = (512, 256, 128, 64)
 
 #: measured pallas-vs-XLA verdicts for the PAGED decode kernel
 #: (:mod:`unionml_tpu.ops.paged_attention`). Shape class:
-#: ``(table_width, block_size, heads, head_dim)``. The kernel's own tiling (heads
+#: ``(table_width, block_size, heads, row)``, ``row`` the last dimension of the
+#: call's pool leaf (``2 * head_dim`` over the joined full-precision leaf). The kernel's own tiling (heads
 #: and table entries a grid step) is no table here: ``paged_attention._tiling``
 #: reckons it from the call's shapes. An entry is an explicit verdict: "xla" where
 #: the kernel lost a ``bench_kernels.py --paged`` sweep, or where Mosaic refused
 #: to compile the shape (the compiler's message goes beside the entry). There
 #: is no runtime fallback between the arms — this table is the only way a
 #: shape leaves the kernel. On the v5e, chip_smoke.py compiles and checks the
-#: kernel at GPT-2 small's class (65, 16, 12, 64) and GPT-2 medium's
-#: (65, 16, 16, 64) over bf16 and int8 pools: all run it, so the table is empty.
+#: kernel at GPT-2 small's classes (65, 16, 12, 128 | 64) and GPT-2 medium's
+#: (65, 16, 16, 128 | 64) over bf16 and int8 pools: all run it, so the table is empty.
 MEASURED_PAGED_IMPL: Dict[Tuple[int, int, int, int], str] = {}
 
 #: unmeasured paged shapes default to the KERNEL — deliberately the opposite of

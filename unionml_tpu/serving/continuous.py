@@ -717,7 +717,7 @@ class DecodeEngine:
             The gather indexes the unsharded block axis: shard-local on a mesh."""
             from unionml_tpu.models.gpt import gather_block_prefix
 
-            return _constrain_cache(gather_block_prefix(pool, block_ids, pad_len))
+            return _constrain_cache(layout.split(gather_block_prefix(pool, block_ids, pad_len)))
 
         # one compile per (n_blocks, pad_len) — both from small bounded ladders
         self._restore_fn = jax.jit(_restore, static_argnums=(2,))
@@ -727,7 +727,9 @@ class DecodeEngine:
             ``dst_ids``; row/start are traced (one compile per block count)."""
             from unionml_tpu.models.gpt import slice_cache_blocks
 
-            blocks = slice_cache_blocks(cache, row, start_block, dst_ids.shape[0], block_size)
+            blocks = layout.join(
+                slice_cache_blocks(cache, row, start_block, dst_ids.shape[0], block_size)
+            )
 
             def put(pool_leaf, blk):
                 return pool_leaf.at[dst_ids].set(blk.astype(pool_leaf.dtype))
@@ -814,57 +816,62 @@ class DecodeEngine:
             self._make_step = _make_step_paged
 
             def _paged_insert(pool, tables, lens, last_logits, local_cache, local_logits, slots, lengths):
-                """Scatter a batched bucket prefill's dense workspace into the
-                admitted slots' pool blocks through their table rows. Padded
-                columns past a slot's allocation map to scratch (the rows'
-                unmapped tail), so the full-precision scatter needs no per-row
-                length mask. Quantized layers DO mask: a padded column landing
-                in an owned block must not inflate that block's absmax scale,
-                so positions at/after a row's real length quantize as zeros."""
+                """Write a batched bucket prefill's dense workspace into the
+                admitted slots' pool blocks through their table rows, whole
+                blocks at a time: the indexed axis leads, so XLA writes in
+                place (a column scatter ``.at[dst, :, off, :]`` copied the pool
+                whole, three times a leaf). The tail of a row's last block
+                holds the prefill's padded columns, which the positional mask
+                hides until the decode append overwrites them; blocks past a
+                slot's allocation map to scratch (the rows' unmapped tail), so
+                the full-precision write needs no per-row length mask.
+                Quantized layers DO mask: a padded column landing in an owned
+                block must not inflate that block's absmax scale, so positions
+                at/after a row's real length quantize as zeros."""
                 # graftlint: disable=retrace -- deliberate trace-time read: block_size is an axis of every pool leaf and fixes the table width, so any host mutation (enable_prefix_cache re-layout) changes this program's input shapes and forces the retrace that re-reads it
                 block_size = self._prefix_block_size
-                rows_tables = tables[slots]  # (rows, width)
                 bucket = jax.tree_util.tree_leaves(local_cache)[0].shape[2]
-                cols = jnp.arange(bucket)
-                blk, off = cols // block_size, cols % block_size
-                dst = rows_tables[:, blk]  # (rows, bucket)
                 nb = -(-bucket // block_size)
-                dst_blocks = rows_tables[:, :nb]  # (rows, nb)
-                pad = nb * block_size - bucket
+                dst_blocks = tables[slots][:, :nb]  # (rows, nb)
                 valid = (
                     jnp.arange(nb * block_size).reshape(nb, block_size)[None, :, :]
                     < lengths[:, None, None]
                 )  # (rows, nb, bs)
 
+                def as_blocks(local_leaf):
+                    # (rows, heads, bucket, dim) -> (rows, nb, heads, bs, dim), zeros past the bucket
+                    rows, heads, _, dim = local_leaf.shape
+                    src = jnp.pad(local_leaf, ((0, 0), (0, 0), (0, nb * block_size - bucket), (0, 0)))
+                    return src.reshape(rows, heads, nb, block_size, dim).transpose(0, 2, 1, 3, 4)
+
                 def put_full(pool_leaf, local_leaf):
-                    src = jnp.moveaxis(local_leaf, 2, 1).astype(pool_leaf.dtype)
-                    return pool_leaf.at[dst, :, off[None, :], :].set(src)
+                    return pool_leaf.at[dst_blocks].set(as_blocks(local_leaf).astype(pool_leaf.dtype))
 
                 def put_quantized(pool_q, pool_scale, local_leaf):
                     from unionml_tpu.ops.quant import quantize_blockwise
 
-                    rows, heads, _, head_dim = local_leaf.shape
-                    src = local_leaf.astype(jnp.float32)
-                    if pad:
-                        src = jnp.pad(src, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                    # (rows, nb, heads, bs, hd): block layout, padded tail zeroed
-                    src = src.reshape(rows, heads, nb, block_size, head_dim).transpose(0, 2, 1, 3, 4)
-                    src = jnp.where(valid[:, :, None, :, None], src, 0.0)
+                    # block layout, padded tail zeroed
+                    src = jnp.where(
+                        valid[:, :, None, :, None], as_blocks(local_leaf.astype(jnp.float32)), 0.0
+                    )
                     q, scale = quantize_blockwise(src, reduce_axes=(3, 4))
                     return pool_q.at[dst_blocks].set(q), pool_scale.at[dst_blocks].set(scale)
 
+                # the workspace under the pool's names (k beside v in one leaf)
+                joined = layout.join(local_cache)
                 new_pool = {}
                 for name, layer in pool.items():
-                    local = local_cache[name]
                     if "k_scale" in layer:
                         out = {}
                         for key in ("k", "v"):
                             out[key], out[key + "_scale"] = put_quantized(
-                                layer[key], layer[key + "_scale"], local[key]
+                                layer[key], layer[key + "_scale"], local_cache[name][key]
                             )
                         new_pool[name] = out
                     else:
-                        new_pool[name] = {key: put_full(leaf, local[key]) for key, leaf in layer.items()}
+                        new_pool[name] = {
+                            key: put_full(leaf, joined[name][key]) for key, leaf in layer.items()
+                        }
                 pool = _constrain_cache(new_pool)
                 return (
                     pool,
