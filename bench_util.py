@@ -1,7 +1,7 @@
-"""Side-effect-free helpers shared by the bench scripts.
+"""Side-effect-free helper of ``bench_kernels.py``.
 
 Deliberately free of module-level configuration: ``bench.py`` sets process-wide
-logging levels at import, which the other bench scripts must NOT inherit just
+logging levels at import, which ``bench_kernels.py`` must NOT inherit just
 to reuse a path-policy function.
 """
 
@@ -11,7 +11,7 @@ import os
 def resolve_artifact_path(out_path: str, backend: str) -> str:
     """Where a bench run may write its committed artifact.
 
-    One policy for every bench script: accelerator runs own the canonical
+    One policy: accelerator runs own the canonical
     artifact name; CPU smoke runs divert to a ``_cpu``-suffixed sibling
     (gitignored) so host timings can never overwrite a TPU measurement.
     """

@@ -168,7 +168,7 @@ def test_concurrent_requests_coalesce(served_model):
     # server-side device-latency split (VERDICT r3 #8) rides the same endpoint;
     # this app serves an OPAQUE sklearn model (eager path), so the compiled-path
     # record is honestly empty — jax-model coverage: test_resident.py
-    # ::test_resident_device_stats_record_per_request_latency and bench_serving.py
+    # ::test_resident_device_stats_record_per_request_latency
     assert stats["device_latency"] == {"count": 0}
 
 

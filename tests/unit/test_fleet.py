@@ -15,8 +15,7 @@ contract pinned here:
   and each replica of a 2-replica fleet reproduces a fresh solo engine
   serving that replica's routed sub-stream.
 - **Routing.** Prefix affinity beats the seeded-random baseline on a
-  prefix-heavy mix (router-measured block hit rate — the same measurement
-  ``bench_serving.py --fleet`` gates on hardware); sessions stick, TTL- and
+  prefix-heavy mix (router-measured block hit rate); sessions stick, TTL- and
   capacity-evict, and fall back to the affinity winner when their replica is
   unroutable (re-sticking there).
 - **Failover.** A replica whose rebuild budget exhausts hands every

@@ -6,7 +6,8 @@ path, no retrace churn, sharding specs that name real mesh axes, guarded host
 state written under its lock, donated buffers rebound before reuse, no lock
 cycles, no event-loop stalls, and (v3) no leaked pins/refs/traces/slots/
 tickets/handles on any path — are checked mechanically over the package PLUS
-``bench_*.py`` and ``tools/`` on every run. ``tests/`` rides along behind the
+the root's bench scripts (``bench.py``, ``bench_kernels.py``, ``bench_util.py``)
+and ``tools/`` on every run. ``tests/`` rides along behind the
 recorded baseline (``tools/graftlint_baseline.json``): its pre-existing
 findings are inventoried, only NEW ones fail. Any new finding fails here; a
 deliberate exception needs an inline ``# graftlint: disable=RULE -- reason``
@@ -21,7 +22,7 @@ from unionml_tpu.analysis import load_baseline, run_lint
 REPO_ROOT = Path(__file__).resolve().parent.parent.parent
 
 #: the widened lint scope that must be finding-free (no baseline): the
-#: package, every bench entry point (baseline burned down to zero), and tools
+#: package, the root's bench scripts (baseline burned down to zero), and tools
 STRICT_PATHS = sorted(
     [str(REPO_ROOT / "unionml_tpu"), str(REPO_ROOT / "tools")]
     + [str(p) for p in REPO_ROOT.glob("bench*.py")]
@@ -59,7 +60,7 @@ def test_shipped_tree_is_finding_free_across_widened_scope():
 
 
 def test_bench_scripts_are_finding_free_without_any_baseline():
-    """The bench_*.py baseline is burned down to ZERO: they lint clean
+    """The bench scripts' baseline is burned down to ZERO: they lint clean
     together with the package (cross-module donation factories resolve), with
     no recorded-findings crutch."""
     result = run_lint(STRICT_PATHS)

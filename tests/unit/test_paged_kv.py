@@ -429,7 +429,7 @@ def test_int8_pool_logprob_delta_budget(gpt, devices):
     """The pinned quality gate: on the common (pre-divergence) prefix, the
     int8 pool's logprob of the bf16-greedy token stays within
     KV_INT8_LOGPROB_DELTA_BUDGET, and the divergence rate within
-    KV_INT8_GREEDY_DIVERGENCE_BUDGET — same constants the bench enforces."""
+    KV_INT8_GREEDY_DIVERGENCE_BUDGET."""
     from unionml_tpu.ops.quant import (
         KV_INT8_GREEDY_DIVERGENCE_BUDGET, KV_INT8_LOGPROB_DELTA_BUDGET,
     )

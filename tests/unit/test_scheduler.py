@@ -630,7 +630,8 @@ def test_http_status_codes_and_reasons(gpt):
             filler = asyncio.ensure_future(
                 client.post("/generate", json={"prompt_ids": [2, 7], "max_new_tokens": 4})
             )
-            await asyncio.sleep(0.05)
+            while gen.scheduler.load_signal()["depth"] < 1:  # filler holds the queue
+                await asyncio.sleep(0.01)
             resp = await client.post(
                 "/generate", json={"prompt_ids": [5, 5], "max_new_tokens": 4}
             )
@@ -651,12 +652,16 @@ def test_http_status_codes_and_reasons(gpt):
             _set_wait_ema(gen.scheduler, None)
             # the hog must outlive the queued request's deadline even on a
             # warm engine: 60 decode steps vs a 25ms budget
+            admitted = engine.requests_admitted
             hog2 = asyncio.ensure_future(
                 client.post(
                     "/generate", json={"prompt_ids": [8, 8, 8], "max_new_tokens": 60}
                 )
             )
-            while not engine.num_active:
+            # wait for THIS hog to hold the slot: `num_active` alone can still
+            # be the finished filler's slot, and the deadline request would then
+            # be admitted ahead of the hog and expire while running
+            while engine.requests_admitted == admitted or not engine.num_active:
                 await asyncio.sleep(0.01)
             resp = await client.post(
                 "/generate",

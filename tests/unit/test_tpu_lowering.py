@@ -162,9 +162,9 @@ def test_headline_bert_train_step_lowers_for_tpu(monkeypatch):
 
 
 def test_mfu_ladder_variants_lower_for_tpu(monkeypatch):
-    """Every bench_mfu.py hardware variant (remat, grad accumulation, bf16 adam
-    moments, long-seq) must lower for the TPU platform — each is one battery
-    slot during a rare window, and a lowering failure there would waste it."""
+    """Every trainer variant nothing on the chip runs yet (remat, grad
+    accumulation, bf16 adam moments, long-seq) must lower for the TPU platform,
+    so that the first chip run of one is not spent on a lowering failure."""
     import sys
 
     from unionml_tpu.models import BertConfig
@@ -190,7 +190,7 @@ def test_mfu_ladder_variants_lower_for_tpu(monkeypatch):
 
 
 def test_int8_decode_at_scale_lowers_for_tpu():
-    """bench_int8.py's ~1.3B-param quantized decode programs lower for TPU —
+    """~1.3B-param int8-weight decode programs lower for TPU —
     exported from abstract (eval_shape) params/cache, so no memory is
     materialized. Covers BOTH phases the engine compiles: chunked prefill
     (cache write at position 0) and the cached single-token decode step

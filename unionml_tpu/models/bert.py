@@ -48,8 +48,8 @@ class BertConfig:
     sp_mesh: Any = None
     remat: bool = False
     #: tanh-approximate GELU trades exact erf (VPU-expensive) for the cheaper tanh
-    #: polynomial — numerically within ~1e-3 of exact, a candidate MFU lever whose
-    #: value is measured on hardware by bench_mfu.py before changing any default
+    #: polynomial — numerically within ~1e-3 of exact, a candidate MFU lever that
+    #: has not been measured on the chip, so the default stays exact
     gelu_approximate: bool = False
 
     @classmethod

@@ -779,7 +779,8 @@ def kv_block_bytes(
     kv_quantize_skip_layers: Tuple[int, ...] = (),
 ) -> int:
     """Bytes one pool block costs across ALL layers under the given layout —
-    the unit of the equal-KV-byte A/B (`bench_serving --int8 ab`) and of pool
+    the unit of the equal-KV-byte comparison of an int8 pool with a bf16 one
+    (``test_int8_equal_byte_pool_doubles_capacity_and_reports_it``) and of pool
     sizing: ``pool_bytes = kv_block_bytes(...) * num_blocks``."""
     dtype = dtype if dtype is not None else config.dtype
     full_itemsize = jnp.dtype(dtype).itemsize

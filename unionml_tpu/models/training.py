@@ -51,8 +51,8 @@ def create_train_state(
 
     ``mu_dtype`` (e.g. ``jnp.bfloat16``) stores adam's FIRST moment in reduced
     precision — the standard optimizer-HBM lever (halves mu traffic; the second
-    moment stays f32 for numerical range). Measured by ``bench_mfu.py``'s
-    ``*_bf16mu`` variants before being promoted to any default.
+    moment stays f32 for numerical range). Not measured on the chip, so no
+    default uses it.
     """
     if warmup_steps > 0:
         schedule = optax.warmup_cosine_decay_schedule(
@@ -125,8 +125,8 @@ def make_classifier_train_step(
     rest replicate — see :func:`_wrap_step`); XLA inserts the grad all-reduce
     over ICI.
     ``light_metrics=True`` drops the ``grad_norm`` metric — in principle XLA CSEs it
-    against the identical norm inside ``clip_by_global_norm``, and bench_mfu.py
-    measures whether that holds on real hardware. ``grad_accum=N`` splits each
+    against the identical norm inside ``clip_by_global_norm``; whether that holds
+    on the chip has not been measured. ``grad_accum=N`` splits each
     batch into N sequential microbatches whose gradients average before the one
     optimizer step — same objective, one-Nth the activation memory.
     """

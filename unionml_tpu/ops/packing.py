@@ -51,7 +51,7 @@ def pack_sequences(
         ``NATIVE_PACK_THRESHOLD``+ sequences. Both paths run the SAME first-fit
         algorithm and produce byte-identical outputs (pinned by tests); native
         exists because the Python loop's O(n_seqs x n_rows) interpreter cost
-        dominates job start-up at corpus scale (bench_packing.py measures it).
+        dominates job start-up at corpus scale.
     :returns: dict with ``input_ids`` (rows, seq_len) int32, ``segment_ids``
         (rows, seq_len) int32 (0 = padding), ``positions`` (rows, seq_len) int32
         (restarting per segment), and ``truncated`` (int) — how many input

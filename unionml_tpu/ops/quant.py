@@ -39,9 +39,9 @@ __all__ = [
     "quantized_bytes",
 ]
 
-# Pinned quality budgets for the int8 KV block pool, enforced by both the unit
-# tests (tests/unit/test_paged_kv.py) and the `bench_serving --int8 ab` gate so
-# a regression in either place fails the same numbers. Measured on the tiny CPU
+# Pinned quality budgets for the int8 KV block pool, enforced by the unit tests
+# (tests/unit/test_paged_kv.py: test_int8_pool_logprob_delta_budget,
+# test_int8_pool_divergence_budget_mixed_schedule). Measured on the tiny CPU
 # config with ~3x headroom over observed worst cases; budgets are on the
 # pre-divergence prefix (once greedy streams split, the contexts differ and
 # per-token comparison stops being meaningful).

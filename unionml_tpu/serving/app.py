@@ -744,7 +744,7 @@ def build_aiohttp_app(
             if callable(spec_stats):
                 # speculative decoding observability: acceptance EMA, current
                 # adaptive γ, round/fallback counters, and the accepted-tokens-
-                # per-target-step ratio the bench gates on
+                # per-target-step ratio
                 payload["generation"]["speculation"] = spec_stats()
             pipeline_stats = getattr(gen.engine, "pipeline_stats", None)
             if callable(pipeline_stats):

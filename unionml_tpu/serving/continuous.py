@@ -258,14 +258,16 @@ class DecodeEngine:
         (no save copy). Outputs are token-identical to ``paged=False``: the
         gathered table is a contiguous logical view, masked columns contribute
         exactly zero, and the engine's scheduling is unchanged. ``False``
-        selects the legacy dense per-slot caches (the A/B bench arm).
+        selects the legacy dense per-slot caches (a comparison arm: only
+        tests pass it).
     :param pool_blocks: total pool size in blocks for paged mode (including
         one reserved scratch block that absorbs retired rows' masked writes).
         Default ``None`` sizes the pool so block admission can never fail when
         a slot is free — ``num_slots * ceil(max_len/block_size) +
         prefix_cache_blocks + 1`` — i.e. dense-equivalent capacity semantics;
         pass an explicit smaller value to serve more concurrent short requests
-        than dense could at the same KV byte budget (the paged bench arm).
+        than dense could at the same KV byte budget
+        (``test_small_pool_serves_more_concurrent_requests``).
     :param kv_quantize: ``"int8"`` stores the paged block pool as symmetric
         int8 with per-block-per-head f32 scales resident alongside (see
         :func:`unionml_tpu.models.gpt.init_block_pool`) — int8 is what crosses
@@ -280,8 +282,8 @@ class DecodeEngine:
         precision (outlier-sensitive layers); their leaves simply carry no
         scale arrays, which is how the attention layer detects the mode.
     :param faults: a :class:`~unionml_tpu.serving.faults.FaultPlan` arming
-        deterministic fault injection (chaos tests and ``bench_serving
-        --chaos`` only). ``None`` (production) makes every hook a single host
+        deterministic fault injection (chaos tests only). ``None``
+        (production) makes every hook a single host
         branch — no device work, no host syncs added to the hot path.
     """
 
@@ -435,7 +437,7 @@ class DecodeEngine:
         self.prefill_dispatches = 0
         #: REAL prompt tokens run through prefill compute (padding excluded);
         #: prefix-cache hits shrink this to the uncovered suffix per request —
-        #: the FLOP counter the prefix-heavy bench and its CI test assert on
+        #: the FLOP counter the prefix-cache tests assert on
         self.prefill_tokens_computed = 0
         #: pool→slot prefix restores / slot→pool block saves dispatched
         self.prefix_restore_dispatches = 0

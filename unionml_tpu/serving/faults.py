@@ -26,10 +26,10 @@ supervisor must survive *injectable on a CPU mesh, deterministically*:
 
 Determinism: schedules address global per-site counters (1-based), so the same
 plan against the same request schedule injects at exactly the same operations;
-``seed`` drives the optional Bernoulli storm rates (``step_failure_rate``) used
-by ``bench_serving --chaos``, which are reproducible for a fixed seed + site
-ordering. A plan is owned by ONE engine/facade (the worker thread that drives
-it); counters are not cross-thread-safe by design.
+``seed`` drives the optional Bernoulli storm rates (``step_failure_rate``),
+which are reproducible for a fixed seed + site ordering. A plan is owned by ONE
+engine/facade (the worker thread that drives it); counters are not
+cross-thread-safe by design.
 """
 
 import dataclasses
@@ -102,8 +102,7 @@ class FaultPlan:
     :param speculative_round_failures: speculative-generation round indexes
         that raise (the facade's structured-failure path).
     :param step_failure_rate: seeded Bernoulli dispatch-failure probability —
-        the "chaos storm" mode ``bench_serving --chaos`` uses on top of the
-        scheduled sites.
+        the "chaos storm" mode, on top of the scheduled sites.
     :param seed: seeds the storm-rate RNG (scheduled sites need no RNG).
     """
 

@@ -381,7 +381,7 @@ class PrefixCache:
         self.evicted_blocks += 1
 
     def stats(self) -> Dict[str, int]:
-        """Counters for /stats and the prefix-heavy bench."""
+        """Counters for /stats."""
         return {
             "num_blocks": self.num_blocks,
             "block_size": self.block_size,

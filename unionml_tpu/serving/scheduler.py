@@ -38,8 +38,8 @@ The scheduler is transport- and engine-agnostic pure host code: the
 :class:`~unionml_tpu.serving.speculative.SpeculativeBatcher` both route
 through it, so ``GET /stats`` reports one uniform counter set whichever
 generator backs ``/generate``. ``SchedulerConfig(fifo=True)`` degrades the
-policy to the old arrival-order queue (no priorities, no preemption) — the
-control arm of the ``bench_serving.py --slo-mix`` A/B.
+policy to the old arrival-order queue (no priorities, no preemption) — a
+control arm (``tests/unit/test_scheduler.py``).
 """
 
 import dataclasses
@@ -137,7 +137,7 @@ class SchedulerConfig:
         HTTP layer emits it as ``Retry-After``).
     :param fifo: degrade to pure arrival order — priorities, aging, and
         preemption are ignored (deadlines and the queue bound still apply).
-        The control arm of the scheduler-vs-FIFO bench A/B.
+        A control arm: only tests pass it.
     :param speculative_classes: request classes that decode speculatively when
         the engine supports it (:class:`~unionml_tpu.serving.speculative.
         SpeculativeEngine`). Speculation is an ITL play — it spends draft

@@ -498,7 +498,7 @@ class SpeculativeEngine(DecodeEngine):
         self._slot_ema[slot] = 1.0
         if seed is None:
             # deterministic derived key: identical admission sequences (e.g.
-            # the two arms of an A/B bench) draw identical per-slot keys
+            # the two arms of a comparison) draw identical per-slot keys
             seed = self._seed * 1_000_003 + self._spec_admissions
         self._spec_admissions += 1
         key_row = np.array(

@@ -90,8 +90,7 @@ class EngineSupervisor:
         self.rebuild_attempts = 0  # guarded-by: _lock
         self.recovered_requests = 0  # guarded-by: _lock
         self.failed_requests = 0  # guarded-by: _lock
-        #: wall time of the most recent failure->ok transition (ms); the
-        #: chaos bench's headline number — guarded-by: _lock
+        #: wall time of the most recent failure->ok transition (ms)
         self.last_recovery_ms: Optional[float] = None  # guarded-by: _lock
         self._failure_at: Optional[float] = None  # guarded-by: _lock
         self._engine: Optional[Any] = None

@@ -314,7 +314,7 @@ class Telemetry:
         # live mean γ tell at a glance whether speculation is paying (α high,
         # γ ramped) or has adaptively degraded to vanilla (γ → 0); the raw
         # proposed/accepted counters give the exact accepted-tokens-per-
-        # target-step the bench gates on: (accepted + rounds) / rounds
+        # target-step: (accepted + rounds) / rounds
         self.spec_acceptance = m.gauge(
             "unionml_spec_acceptance",
             "Speculative acceptance EMA (mean over live speculative slots) per class",

@@ -6,7 +6,7 @@ replica's :meth:`~unionml_tpu.serving.scheduler.SLOScheduler.load_signal`
 shed rate — and emits an integer replica delta. It is deliberately pure
 host arithmetic with an injected clock so the SAME object runs inside the
 discrete-event simulator (where it is validated against static
-provisioning, ``bench_sim.py``) and against a live fleet's signals.
+provisioning, ``tests/unit/test_sim.py``) and against a live fleet's signals.
 
 Scale-up triggers on ANY pressure source (queue-wait EMA above target,
 block-pool pressure above threshold, or live shedding): these fail at
