@@ -222,7 +222,9 @@ def phase_kernels() -> None:
 
     # the latent shape of the benchmark's sparse-decoder cell: 32 query heads
     # over ONE key head whose rows (640 wide: 512 + 64 + padding) are the values
-    # too, 32 slots of 8192 positions in 16-token blocks, a 1024-token chunk
+    # too, 32 slots of 8192 positions in the cell's 128-token blocks (three a
+    # step of the copied walk in decode), a 1024-token chunk
+    block_size = 128
     heads, row, slots, width = 32, 640, 32, 8192 // block_size + 1
     blocks = slots * (width - 1) + 1
     pool = jnp.asarray(rng.normal(size=(blocks, 1, block_size, row)), jnp.bfloat16)

@@ -27,7 +27,7 @@ import pytest
 
 from unionml_tpu.models import gpt
 from unionml_tpu.models.gpt import GPTLMHeadModel, _paged_append_quantized
-from unionml_tpu.ops.paged_attention import paged_attention, xla_paged_attention
+from unionml_tpu.ops.paged_attention import paged_attention, walk_steps, xla_paged_attention
 from unionml_tpu.parallel import make_mesh
 from unionml_tpu.serving.continuous import DecodeEngine
 
@@ -162,10 +162,16 @@ def test_spliced_shared_block_at_nonzero_offset():
     np.testing.assert_array_equal(ref2, ref)
 
 
-# the real block geometry (16-token blocks of 64-wide heads), so that a grid
-# step of the kernel takes 8 table entries as it does on the chip
+# the real block geometry (16-token blocks of 64-wide heads), so that a step of
+# the kernel takes 8 table entries as it does on the chip. The joined pools are
+# the engine's full-precision leaf, a head's key beside its value in rows of 128
+# lanes: the leaf the kernel fetches with copies of its own, a step a live
+# tile. The 64-wide leaves (what an int8 pool still is) take the BlockSpec walk.
 RBS, RHD = 16, 64
-POOLS = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+POOLS = {
+    "f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8,
+    "joined": jnp.float32, "joined_bf16": jnp.bfloat16,
+}
 
 
 def _ragged_case(heads, width, S, pool, seed=0):
@@ -187,8 +193,12 @@ def _ragged_case(heads, width, S, pool, seed=0):
     for r in range(rows):
         table[r, : live[r]] = [next(owned) for _ in range(live[r])]
     shape = (blocks, heads, RBS, RHD)
-    compute = jnp.bfloat16 if pool == "bf16" else jnp.float32
-    if pool == "int8":
+    compute = jnp.bfloat16 if pool.endswith("bf16") else jnp.float32
+    q = jnp.asarray(rng.normal(size=(rows, heads, S, RHD)), compute)
+    scales = [None, None]
+    if pool.startswith("joined"):
+        k, v = jnp.asarray(rng.normal(size=shape[:3] + (2 * RHD,)), POOLS[pool]), None
+    elif pool == "int8":
         k, v = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8) for _ in range(2))
         scales = [
             jnp.asarray(rng.uniform(0.005, 0.02, (blocks, heads, 1, 1)), jnp.float32)
@@ -196,8 +206,6 @@ def _ragged_case(heads, width, S, pool, seed=0):
         ]
     else:
         k, v = (jnp.asarray(rng.normal(size=shape), POOLS[pool]) for _ in range(2))
-        scales = [None, None]
-    q = jnp.asarray(rng.normal(size=(rows, heads, S, RHD)), compute)
     return q, k, v, jnp.asarray(table), jnp.asarray(bases, jnp.int32), scales, compute
 
 
@@ -207,11 +215,20 @@ def _ragged_case(heads, width, S, pool, seed=0):
 @pytest.mark.parametrize("width", [65, 16], ids=["w65", "w16"])
 def test_bounded_walk_matches_xla_over_ragged_rows(width, S, pool, heads):
     """The walk that ends at each row's live length against the full gather:
-    every boundary of the block and of the 8-entry tile in one batch, a table
-    whose width the tile does not divide (65) and one it does (16)."""
+    every boundary of the block and of the 8-entry tile in one batch (a row on a
+    block's edge, a retired row on the sentinel, a row with no key), a table
+    whose width the tile does not divide (65: the last tile is short) and one it
+    does (16). Both walks: a step a live tile over the joined leaf, the
+    BlockSpec grid over the narrow ones."""
     q, k, v, table, base, scales, compute = _ragged_case(heads, width, S, pool)
     ref = _run("xla", q, k, v, table, base, scales, compute)
     out = _run("pallas", q, k, v, table, base, scales, compute)
+    # the steps the walk takes: a tile for every 8 live columns of a row where
+    # the kernel copies (one even for the row with no key), the whole table's
+    # 9 (or 2) tiles a row where BlockSpecs fetch
+    live = np.clip(np.asarray(base) + S - 1, 0, width * RBS - 1) // RBS
+    want = (live // 8 + 1).sum() if v is None else len(live) * -(-width // 8)
+    assert walk_steps(q, k, v, table, np.asarray(base), *scales) == want
     # a query that sees no key: the gather arm softmaxes a row of masks into a
     # uniform average, the kernel adds nothing and writes 0, as the masked full
     # walk did
@@ -221,7 +238,7 @@ def test_bounded_walk_matches_xla_over_ragged_rows(width, S, pool, heads):
     live = sees[:, None, :, None]
     # bf16: the arms round the weights at different points (after and before
     # the normalisation), one unit of bf16 in the last place apart
-    tol = 2e-2 if pool == "bf16" else 2e-5
+    tol = 2e-2 if pool.endswith("bf16") else 2e-5
     np.testing.assert_allclose(
         np.where(live, out, 0), np.where(live, ref, 0), atol=tol, rtol=tol
     )
@@ -251,13 +268,13 @@ def test_nothing_past_the_live_length_is_folded_in(S, pool):
             s[dead_blocks] = 3e38
         scales = [jnp.asarray(s) for s in scales]
     else:
-        k, v = np.array(k, np.float32), np.array(v, np.float32)
-        for leaf in (k, v):
+        k, v = (leaf if leaf is None else np.array(leaf, np.float32) for leaf in (k, v))
+        for leaf in (k, v)[: 1 if v is None else 2]:
             picked = leaf[blocks]  # (rows, width, heads, bs, hd); rows share no live block
             picked[np.broadcast_to(dead_key[:, :, None, :, None], picked.shape)] = np.nan
             leaf[blocks] = picked
     poisoned = _run(
-        "pallas", q, jnp.asarray(k, POOLS[pool]), jnp.asarray(v, POOLS[pool]),
+        "pallas", q, jnp.asarray(k, POOLS[pool]), v if v is None else jnp.asarray(v, POOLS[pool]),
         table, base, scales, compute,
     )
     assert np.isfinite(poisoned).all()
@@ -365,6 +382,53 @@ def test_kernel_steady_state_transfer_guard_clean_with_telemetry(gpt_tiny_sessio
     assert 'unionml_paged_attn_impl{impl="pallas"} 1' in rendered
 
 
+@pytest.mark.parametrize("pool", ["joined", "int8", "xla"])
+def test_kernel_grid_steps_counts_the_tiles_each_dispatch_walks(pool):
+    """``/stats`` ``generation.pipeline.kernel_grid_steps``: every dispatch adds
+    the steps one layer's call of the decode kernel takes over the step's rows,
+    x the burst's steps. Over the joined 128-wide leaf (heads of 64) that is a
+    step for every 8 live table columns of a row, a retired row's on the
+    sentinel (the whole table) among them; over an int8 pool's narrow leaves the
+    table's tiles for every row, whatever it holds; the gather arm adds 0."""
+    from unionml_tpu.models.gpt import GPTConfig, init_params
+
+    block, slots, max_len = 16, 3, 1024
+    config = GPTConfig.tiny(
+        hidden_size=128, num_heads=2, num_layers=1, max_position_embeddings=1024, dropout=0.0,
+        dtype=jnp.float32,
+        attention_impl="xla", paged_attn_impl="xla" if pool == "xla" else "pallas",
+    )
+    engine = DecodeEngine(
+        GPTLMHeadModel(config), init_params(config, seq_len=16), num_slots=slots, max_len=max_len,
+        prefill_buckets=(4, 8, 16), prefix_block_size=block, paged=True,
+        kv_quantize="int8" if pool == "int8" else None,
+    )
+    width = engine._table_width
+    # 512 KB of two heads' 8 KB f32 blocks is 32 entries a step of the copied
+    # walk; 8 entries fill the lanes of the BlockSpec walk; the table has 65
+    tile = 32 if pool == "joined" else 8
+    expected, dispatch = [0], engine._dispatch_step
+
+    def counting_dispatch(lookahead):
+        lens = np.where(engine._active, engine._lens_host, (width - 1) * block)
+        out = dispatch(lookahead)
+        live = np.clip(lens, 0, width * block - 1) // block
+        steps = {"joined": (live // tile + 1).sum(), "int8": slots * -(-width // tile), "xla": 0}
+        expected[0] += int(steps[pool]) * out[3]
+        return out
+
+    engine._dispatch_step = counting_dispatch
+    engine.admit_many([([3, 1, 4, 1, 5, 9, 2, 6, 5], 9, {}), ([2, 7], 30, {})])
+    while engine._active.any():
+        engine.step()
+    stats = engine.pipeline_stats()
+    assert stats["kernel_grid_steps"] == expected[0]
+    if pool == "joined":
+        # a decoding row is one tile, a retired one the table's three
+        steps, rows = stats["kernel_grid_steps"], stats["active_slot_steps"]
+        assert 3 * stats["step_dispatches"] < steps == rows + 3 * (3 * stats["step_dispatches"] - rows)
+
+
 # ------------------------------------- fewer key heads, keys that are the values
 
 
@@ -427,36 +491,59 @@ def test_shared_key_heads_and_keys_as_values_both_arms(key_heads, S):
         np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("dim", [48, 128], ids=["blockspec", "copied"])
 @pytest.mark.parametrize("limit,S,want_rows", [(1400 * 1024, 16, 32), (900 * 1024, 16, 8)],
                          ids=["whole_spans", "part_of_a_span"])
-def test_query_rows_split_into_blocks_when_one_heads_rows_outgrow_vmem(monkeypatch, limit, S, want_rows):
+def test_query_rows_split_into_blocks_when_one_heads_rows_outgrow_vmem(monkeypatch, limit, S, want_rows, dim):
     """32 query heads of a long chunk over one key head are too many rows for
-    one grid step: the rows split into blocks of whole spans, or of a divisor
-    of one, each with its own last visible key. Forced at a small size by a
-    small VMEM budget; the result is the definition's either way."""
+    one step: the rows split into blocks of whole spans, or of a divisor of
+    one, each with its own last visible key and so its own walk. Forced at a
+    small size by a small VMEM budget, over a narrow row (BlockSpecs) and one
+    128 lanes wide (copies); the result is the definition's either way."""
     from unionml_tpu.ops import paged_attention as module
 
     monkeypatch.setattr(module, "_VMEM_LIMIT_BYTES", limit)
-    q, k, table, base = _shared_key_case(S, bases=(0, 7, 60, 128))
-    heads, rows, _ = module._tiling(1, 4 * S, S, RBS, 48, 9, 4, False)
-    assert (heads, rows) == (1, want_rows)
-    out = paged_attention(q, k, None, table, base, impl="pallas", interpret=True)
-    want = _dense_oracle(q, k, None, table, base, 48 ** -0.5)
+    q, k, table, base = _shared_key_case(S, bases=(0, 7, 60, 128), dim=dim)
+    copied = dim % 128 == 0
+    heads, rows, tile = module._tiling(1, 4 * S, S, RBS, dim, 9, 4, False, copied)
+    assert (heads, rows, tile) == (1, want_rows, 8)
+    # the jitted forward keeps a trace a shape: none made under another budget
+    # may serve this call, and none made under this one a later test
+    module._paged_forward.clear_cache()
+    try:
+        out = paged_attention(q, k, None, table, base, impl="pallas", interpret=True)
+    finally:
+        module._paged_forward.clear_cache()
+    want = _dense_oracle(q, k, None, table, base, dim ** -0.5)
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=2e-5)
+    # a step for every tile of every row block: a block of whole spans sees the
+    # row's last key, a part of a span the last of its own positions
+    blocks = 4 * S // rows
+    first = (np.arange(blocks) * rows) % S
+    last = np.asarray(base)[:, None] + (first + rows - 1 if rows < S else np.full(blocks, S - 1))
+    steps = (np.clip(last, 0, 9 * RBS - 1) // RBS // 8 + 1).sum() if copied else 4 * blocks * 2
+    assert walk_steps(q, k, None, table, np.asarray(base)) == steps
 
 
 def test_per_head_shapes_take_the_path_they_took():
-    """GPT-2's calls are what they were: the tiling of its shapes (all heads, the
-    query's own rows, 8 table entries a step; 4 heads of a 512-token chunk),
-    and the gather arm bit for bit the historical formula (gather, flatten,
-    ``xla_attention`` under the positional mask)."""
+    """GPT-2's calls are what they were: the tiling of its shapes over the joined
+    128-wide leaf (all heads, the query's own rows, 8 table entries a step: its
+    512 KB; 4 heads of a 512-token chunk) and over an int8 pool's 64-wide codes;
+    the latent leaf's 160 KB blocks go three a step in decode, where the lanes
+    alone gave one, and one a step under a chunk's split rows; four local heads
+    of a ``tensor`` shard take as many more entries as their blocks are smaller. And the gather arm bit
+    for bit the historical formula (gather, flatten, ``xla_attention`` under the
+    positional mask)."""
     from unionml_tpu.ops import paged_attention as module
     from unionml_tpu.ops.attention import xla_attention
 
-    assert module._tiling(16, 1, 1, 16, 64, 65, 2, False) == (16, 1, 8)
+    assert module._tiling(16, 1, 1, 16, 128, 65, 2, False, True) == (16, 1, 8)
     assert module._tiling(12, 1, 1, 16, 64, 65, 1, True) == (12, 1, 8)
-    assert module._tiling(16, 64, 64, 16, 64, 65, 2, False) == (16, 64, 8)
-    assert module._tiling(16, 512, 512, 16, 64, 65, 2, False) == (4, 512, 8)
+    assert module._tiling(16, 64, 64, 16, 128, 65, 2, False, True) == (16, 64, 8)
+    assert module._tiling(16, 512, 512, 16, 128, 65, 2, False, True) == (4, 512, 8)
+    assert module._tiling(1, 32, 1, 128, 640, 65, 2, False, True) == (1, 32, 3)
+    assert module._tiling(1, 32 * 1024, 1024, 128, 640, 65, 2, False, True) == (1, 512, 1)
+    assert module._tiling(4, 1, 1, 16, 128, 65, 2, False, True) == (4, 1, 32)
     q, k, v, table, base, _, _ = _ragged_case(12, 16, 5, "bf16")
     rows, heads, S, dim = q.shape
     capacity = table.shape[1] * RBS

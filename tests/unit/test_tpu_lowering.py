@@ -418,7 +418,11 @@ def test_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, shape, pool,
     compute, args = _paged_case(shape, pool, mode)
     on_chip = SingleDeviceSharding(v5e_host[0])
     args = [a and jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip) for a in args]
-    assert jax.jit(_paged_fn(compute)).lower(*args).compile() is not None
+    text = jax.jit(_paged_fn(compute)).lower(*args).compile().as_text()
+    # whichever walk the pool's leaves take (copies over the joined leaf, the
+    # BlockSpec grid over int8 codes), the call's first operand is the table
+    batch, width = args[3].shape
+    assert f'custom_call_target="tpu_custom_call", operand_layout_constraints={{s32[{batch},{width}]' in text
 
 
 @pytest.fixture(scope="module")
@@ -498,13 +502,18 @@ def test_pool_writers_compile_without_pool_copies(as_on_tpu, v5e_host, cell_engi
 def test_latent_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, mode, batch, seq):
     """The sparse-decoder cell's call: 32 query heads over one key head whose
     640-wide rows (512 latent + 64 rotary + 64 of padding) are the values too,
-    16-token blocks, 32 slots of 8192 positions. Decode takes the 32 heads'
-    rows in one grid step; a 1024-token chunk splits its 32 x 1024 rows."""
+    128-token blocks, 32 slots of 8192 positions. Decode takes the 32 heads'
+    rows in one step and three of the leaf's 160 KB blocks a tile; a 1024-token
+    chunk splits its 32 x 1024 rows and takes a block a tile. The block table
+    is the Mosaic call's first operand and the positions its second, as the
+    benchmark's trace readers find the decode kernel
+    (``perfbench/configs/xing4-29b-a4b-serve.json``: ``custom-call(s32[32,65]``):
+    a dynamic grid extent would stand in front of them."""
     from jax.sharding import SingleDeviceSharding
 
     from unionml_tpu.ops.paged_attention import _tiling, paged_attention
 
-    heads, row, block_size, width = 32, 640, 16, 8192 // 16 + 1
+    heads, row, block_size, width = 32, 640, 128, 8192 // 128 + 1
     on_chip = SingleDeviceSharding(v5e_host[0])
     args = [
         jax.ShapeDtypeStruct((batch, heads, seq, row), jnp.bfloat16, sharding=on_chip),
@@ -516,10 +525,12 @@ def test_latent_paged_attention_compiles_under_mosaic(as_on_tpu, v5e_host, mode,
     def fn(q, pool, table, base):
         return paged_attention(q, pool, None, table, base, impl="pallas", sm_scale=0.1447)
 
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    key_heads, rows, entries = _tiling(1, heads * seq, seq, block_size, row, width, 2, False)
-    assert (key_heads, entries) == (1, 8) and rows == (32 if mode == "decode" else 512)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    operands = f"operand_layout_constraints={{s32[{batch},{width}]{{1,0}}, s32[{batch}]{{0}}, bf16["
+    assert operands in text
+    want = (1, 32, 3) if mode == "decode" else (1, 512, 1)
+    assert _tiling(1, heads * seq, seq, block_size, row, width, 2, False, True) == want
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])

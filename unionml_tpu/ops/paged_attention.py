@@ -6,7 +6,7 @@ plus per-(block, head) scales under ``kv_quantize`` — and the XLA decode step
 pays a ``pool[table]`` gather that materializes a dense, dequantized KV copy
 before attending (``models/gpt.py`` ``gather_table``). On real HBM that copy is
 ~4x the bytes the int8 codes occupy, per step, per layer. The kernel here
-deletes it: a grid step DMAs the pool blocks that eight entries of the slot's
+deletes it: a step DMAs the pool blocks that eight entries of the slot's
 block-table row name (scalar-prefetched, so the index feeds the DMA engine),
 every local head of each block at once, dequantizes in VMEM, and folds the 128
 keys into an online-softmax accumulation — flash-decoding over the table
@@ -16,14 +16,15 @@ dequant.
 
 Two implementations behind one dispatcher (the ``ops/attention.py`` contract):
 
-- ``impl="pallas"``: the fused kernel. Grid ``(batch, head_groups, tiles)``
-  with the table walk innermost, ``tiles = ceil(width / tile)``; VMEM scratch
-  carries the (m, l, acc) softmax state across a row's tiles, initialized at
-  the first and normalized/written at the last. How many heads and table
-  entries a grid step takes follows from the shapes of the call
-  (:func:`_tiling`): all local heads and ``128 // block_size`` entries at every
-  shape the repo runs, so ``head_groups`` is 1 and GPT-2 medium's 65-column
-  table is 9 tiles, the last one short.
+- ``impl="pallas"``: the fused kernel. Its unit of work is a segment (a batch
+  row, a group of key heads, a block of the query rows that meet them) and, of
+  the segment, a tile: consecutive table entries whose blocks are joined into
+  one K and V so that the scores fill the lanes. VMEM scratch carries the
+  (m, l, acc) softmax state across a segment's tiles, initialized before the
+  first and normalized and written after the last. How many heads, rows and
+  table entries a step takes follows from the shapes of the call
+  (:func:`_tiling`): all local heads at every shape the repo runs, 8 of GPT-2
+  medium's 16-token blocks, 3 of the latent leaf's 128-token blocks.
 - ``impl="xla"``: gather-dequant-attend, arithmetic-identical to the historical
   ``gather_table`` + ``xla_attention`` path (the reference the kernel is pinned
   against, and what runs off-TPU).
@@ -34,26 +35,47 @@ Two implementations behind one dispatcher (the ``ops/attention.py`` contract):
   between the arms: ``impl="pallas"`` off a TPU raises unless the caller asked
   for the Pallas interpreter (``interpret=True``, a test argument).
 
-**The walk ends at the row's live length.** A row with base position ``p`` and
-``S`` query tokens can see keys in table columns ``0 .. (p + S - 1) //
-block_size`` and in no other. The tiles that start past that column are
-neither fetched nor computed: their index maps repeat the row's last live
-tile, and a block index that repeats skips its DMA; ``pl.when`` skips the
-body. What is left of such a grid step is its fixed cost (index maps and DMA
-bookkeeping of the step's pool operands: 8 over the joined leaf, 16 over an
-int8 pool's two code leaves and 16 more for their scales), which a row pays
-``tiles`` times whatever it holds. A retired row carries the engine's
-sentinel base ``(width - 1) * block_size``: its live range is the whole table,
-so it walks all of its tiles, every entry its scratch block, at the cost of a
-full row (about a third more than a short row's).
+**The walk is the live tiles and nothing else.** A row with base position ``p``
+and ``S`` query tokens can see keys in table columns ``0 .. (p + S - 1) //
+block_size`` and in no other: the walk of a segment is the tiles that hold
+those columns (:func:`_live_column`), at least one. Over a pool whose leaves
+are whole lanes wide (the joined leaf, the latent leaf) the kernel fetches them
+itself (:func:`_walk_kernel`): the grid is ``(batch, segments)``, the pool
+stays in HBM (``pl.ANY``), and a loop over the segment's live tiles copies
+each tile's blocks into one of two VMEM slots (``make_async_copy``, the block
+index read from the scalar-prefetched table) while the tile before it is
+folded; after a segment's last tile the copy in flight is the first tile of
+the next segment, so that a grid step starts on keys that are already there. Of
+the last tile only the entries up to the last live column are copied. A table
+column past a row's keys therefore costs nothing: no grid step, no index map,
+no descriptor. (Until PR 31 the grid had a step for every tile of the table,
+``batch x ceil(width / tile)``, and six in seven of them were past the rows'
+keys: fetched nothing, computed nothing and still paid a grid step's fixed
+cost, 0.83 us with eight pool operands. ``PERF.md`` section 6, PR 31.)
+:func:`walk_steps` counts the steps a call takes, on the host. A retired row
+carries the engine's sentinel base ``(width - 1) * block_size``: its live
+range is the whole table, so it walks all of its tiles, every entry its
+scratch block: the cost of a full row.
 
-Why the pool blocks come through BlockSpecs, one operand per table entry of a
-tile, and not through ``make_async_copy`` from a pool left in ``pl.ANY``:
-Mosaic refuses any slice of an HBM ref whose last dimension is not a multiple
-of 128 lanes (an int8 pool's ``head_dim`` of 64), the whole-block slice
-included; a BlockSpec whose last two dims equal the array's is the form it
-takes. (The joined leaf and the latent leaf are whole lanes wide, so copies by
-hand are open to them: a kernel PR of its own.)
+**Why not a one-dimensional grid over a list of live tiles** (a dynamic grid
+extent with scalar prefetch, as ``megablox.gmm`` has): jax 0.9.0 hands a
+dynamic extent to the Mosaic call in front of every other operand, and the
+benchmark's trace readers find the decode kernel by its first operand, the
+block table (``custom-call(s32[32,65]``). So the extent of the walk is a loop
+bound inside a grid step, not a grid bound, and the table stays first.
+
+**The leaves Mosaic will not slice keep BlockSpecs.** Mosaic refuses any slice
+of an HBM ref whose last dimension is not a multiple of 128 lanes (an int8
+pool's ``head_dim`` of 64, its ``(blocks, heads, 1, 1)`` scales), the
+whole-block slice included; a BlockSpec whose last two dims equal the array's
+is the form it takes. Those pools (:func:`_copied` says which) run the grid
+``(batch, segments, ceil(width / tile))`` with one operand per table entry of a
+tile (:func:`_paged_kernel`): past the row's last live tile the index maps
+repeat it, a block index that repeats skips its DMA and ``pl.when`` skips the
+body, but the grid step's fixed cost (index maps and DMA bookkeeping of 16 code
+operands and 16 scales) is paid ``tiles`` times a row whatever it holds. That
+walk ends when the int8 pool is joined into 128 lanes (``ROADMAP.md`` S14). The
+fold (:func:`_fold_tile`) is one function for both walks.
 
 One body serves every cache layout. The pool's key heads may be fewer than the
 query heads (a step's K tile is fetched once for all the query heads that
@@ -163,31 +185,58 @@ def _round_up(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
 
 
-def _tiling(heads, rows, q_len, block_size, head_dim, width, pool_itemsize, quantized):
-    """``(key heads, query rows, table entries)`` a grid step takes, for a call's shapes.
+#: bytes of pool a step of the copied walk fetches and folds, all heads of the
+#: step and every pool leaf together: enough that a step's fixed cost (the loop's
+#: scalar work, a descriptor an entry, the fold's matrix set-up) is small beside
+#: its HBM time, and two of them (the walk double-buffers) are a small part of
+#: the VMEM budget. See ``PERF.md`` section 6, PR 31, for the tiles it was set
+#: from.
+_TILE_BYTES = 512 * 1024
+
+
+def _copied(*leaves) -> bool:
+    """Whether the walk may fetch these pool leaves with copies of its own
+    (:func:`_walk_kernel`): every leaf whole lanes wide, which leaves out an
+    int8 pool's scales and its 64-wide codes (Mosaic slices no HBM ref whose
+    last dimension is not a multiple of 128). Those take the BlockSpec walk
+    (:func:`_paged_kernel`)."""
+    return all(leaf.shape[-1] % _LANES == 0 for leaf in leaves if leaf is not None)
+
+
+def _tiling(heads, rows, q_len, block_size, head_dim, width, pool_itemsize, quantized, copied=False):
+    """``(key heads, query rows, table entries)`` a step takes, for a call's shapes.
 
     ``heads`` are the pool's (key) heads and ``rows`` the query rows that meet
     one of them: its query heads side by side, ``q_len`` tokens each (plain
-    multi-head attention: ``rows == q_len``). A grid step takes as many table
-    entries as fill the 128 lanes of the score matrix with keys (8 blocks of
-    16; never more than the table has), and the most heads, a divisor of them,
-    whose footprint stays under half of :data:`_VMEM_LIMIT_BYTES`: every
-    entry's K and V block double-buffered as VMEM pads it, the scales' padded
-    tiles, the tile's dequantized copy, the f32 scores and weights, and the
-    query, output and softmax state over the rows. Decode, verify and the
-    64-token chunks the repo runs take all 16 heads of GPT-2 medium; a chunk of
-    hundreds of tokens splits them (512 tokens: 4). Where one head's rows alone
-    are too many (32 query heads of a 1024-token chunk over one latent key
-    head), the rows split too, into blocks that hold whole query spans or
-    divide one, so that a block's positions are a range.
+    multi-head attention: ``rows == q_len``). A step takes the most heads, a
+    divisor of them, whose footprint stays under half of
+    :data:`_VMEM_LIMIT_BYTES`: every entry's K and V block double-buffered as
+    VMEM pads it, the scales' padded tiles, the tile's dequantized copy, the
+    f32 scores and weights, and the query, output and softmax state over the
+    rows. Decode, verify and the 64-token chunks the repo runs take all 16
+    heads of GPT-2 medium; a chunk of hundreds of tokens splits them (512
+    tokens: 4). Where one head's rows alone are too many (32 query heads of a
+    1024-token chunk over one latent key head), the rows split too, into blocks
+    that hold whole query spans or divide one, so that a block's positions are
+    a range.
+
+    The table entries of a step are as many as fill the 128 lanes of the score
+    matrix with keys (8 blocks of 16; never more than the table has). The
+    BlockSpec walk (``copied`` false) stops there, because it pays for every
+    entry as an operand of every grid step. The copied walk pays for an entry
+    only where it fetches one, so where the whole query fits beside it (decode
+    and verify: a step's time is its fetch and its fixed cost, not its
+    products) it takes :data:`_TILE_BYTES` of pool a step in whole 128-key
+    groups: still 8 of GPT-2 medium's 64 KB blocks, and 3 of the latent leaf's
+    160 KB blocks where the lanes alone gave 1.
     """
-    tile = max(1, min(width, _LANES // block_size))
-    keys = _round_up(tile * block_size, _LANES)
     lanes = _round_up(head_dim, _LANES)
     # a (block_size, head_dim) slab of the pool in VMEM: sublanes pad to 32 bytes' worth
     slab = _round_up(block_size, 32 // pool_itemsize) * lanes * pool_itemsize
+    fill = max(1, _LANES // block_size)
 
-    def per_head(block_rows):
+    def footprint(block_rows, tile):
+        keys = _round_up(tile * block_size, _LANES)
         padded = _round_up(block_rows, 8)
         return (
             2 * 2 * tile * slab  # K and V blocks, double-buffered
@@ -198,17 +247,193 @@ def _tiling(heads, rows, q_len, block_size, head_dim, width, pool_itemsize, quan
         )
 
     budget = _VMEM_LIMIT_BYTES // 2
+    tile = max(1, min(width, fill))
+    if copied:
+        by_bytes = min(width, _TILE_BYTES // (heads * slab) // fill * fill)
+        if by_bytes > tile and heads * footprint(rows, by_bytes) <= budget:
+            return heads, rows, by_bytes
     for gh in range(heads, 0, -1):
-        if heads % gh == 0 and gh * per_head(rows) <= budget:
+        if heads % gh == 0 and gh * footprint(rows, tile) <= budget:
             return gh, rows, tile
     # one head a step, and of its rows a block: whole spans, or a divisor of one
     spans = rows // q_len
     blocks = [q_len * n for n in range(spans, 0, -1) if spans % n == 0]
     blocks += [q_len // n for n in range(2, q_len + 1) if q_len % n == 0 and (q_len // n) % 8 == 0]
     for block_rows in blocks:
-        if per_head(block_rows) <= budget:
+        if footprint(block_rows, tile) <= budget:
             return 1, block_rows, tile
     return 1, blocks[-1], tile
+
+
+def _block_span(h, rows, q_len, row_blocks):
+    """``(first offset, offsets spanned)`` of the query positions, relative to
+    the row's base, in the row block that segment ``h`` of a batch row names: a
+    block of whole query spans starts at 0 and spans ``q_len``; a divisor of a
+    span is the range ``[first, first + rows)``."""
+    if rows >= q_len:
+        return 0, q_len
+    return ((h % row_blocks) * rows) % q_len, rows
+
+
+def _live_column(base, first, span, width, block_size, xp=jnp):
+    """The last table column that holds a key some query of a segment sees:
+    ``base`` is the batch row's position, ``first`` and ``span`` the segment's
+    (:func:`_block_span`). The segment's walk fetches the columns ``0 .. live``
+    and its tiles are ``0 .. live // tile``; one that sees no key (a negative
+    position) still takes column 0, so that every segment has a tile. One
+    function for the kernels' scalars and, with ``xp=numpy``, for the host's
+    count (:func:`walk_steps`)."""
+    return xp.clip(base + first + (span - 1), 0, width * block_size - 1) // block_size
+
+
+def _init_state(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _fold_tile(q, k, v, w, base, first, last, end, acc_ref, m_ref, l_ref, *, q_len, sm_scale):
+    """Fold tile ``w`` of a segment's walk, ``k`` and ``v`` ``(gh, tile_keys,
+    dim)``, into the (acc, m, l) scratch: the flash-attention recurrence of
+    ``attention._flash_kernel``, walked over the table instead of a dense KV.
+
+    The block's ``rows`` query rows are the query heads that share a key head,
+    ``q_len`` tokens each, head-major: row ``r`` of the head's rows sits at
+    position ``base + first + r % q_len``.
+    """
+    rows, tile_keys = q.shape[1], k.shape[1]
+    operand = jnp.promote_types(q.dtype, k.dtype)
+    scores = jax.lax.dot_general(
+        q.astype(operand), k.astype(operand), (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * sm_scale  # (gh, rows, tile_keys)
+    k_pos = w * tile_keys + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
+    offset = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    if rows > q_len:  # several query heads' spans in one block
+        offset = offset % q_len
+    q_pos = base + first + offset
+    valid = k_pos <= jnp.minimum(q_pos, end)
+    scores = jnp.where(valid, scores, _NEG_INF)
+
+    m_prev, l_prev = m_ref[...], l_ref[...]  # (gh, rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+    # a query with no key yet (an empty live range inside a chunk) must add
+    # exactly 0: m_new is still _NEG_INF there and exp(0) would be 1
+    probs = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
+    correction = jnp.exp(m_prev - m_new)
+    # what the tile holds past the block's last key (the rest of its last
+    # block, an entry that was not fetched) has weight 0, and 0 x NaN would
+    # still be NaN
+    seen = w * tile_keys + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    v = jnp.where(seen <= last, v, jnp.zeros_like(v))
+    pv = jax.lax.dot_general(
+        probs.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )  # (gh, rows, hd)
+    acc_ref[...] = acc_ref[...] * correction + pv
+    l_ref[...] = l_prev * correction + jnp.sum(probs, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+
+
+def _walk_kernel(
+    table_ref,  # scalar prefetch: (batch, width) int32
+    base_ref,  # scalar prefetch: (batch,) int32 query base positions
+    q_ref,  # (1, gh, rows, hd)
+    *rest,  # the pool's leaves where they lie (K, [V]), o_ref, scratch
+    tile: int,
+    sm_scale: float,
+    shared_kv: bool,
+    q_len: int,
+    row_blocks: int,
+):
+    """One segment of the copied walk: a batch row's head group and row block,
+    over the tiles that hold keys it can see and over no other.
+
+    The pool's leaves stay in HBM. A tile is ``tile`` consecutive table
+    entries, each one pool block with the step's ``gh`` key heads, copied into
+    one of two VMEM slots; of the segment's last tile only the entries up to
+    its last live column are copied. While a tile is folded
+    (:func:`_fold_tile`) the next one is on its way: the segment's next tile,
+    or after its last the first tile of the next segment, whose grid step finds
+    it waiting. So the grid's steps are the segments, the loop's the live tiles
+    (:func:`walk_steps` counts them), and a table column past a row's keys
+    costs nothing at all.
+    """
+    pools, rest = rest[: 1 if shared_kv else 2], rest[1 if shared_kv else 2:]
+    o_ref, rest = rest[0], rest[1:]
+    buffers, (arrived, slot_ref, acc_ref, m_ref, l_ref) = rest[: len(pools)], rest[len(pools):]
+
+    b, h = pl.program_id(0), pl.program_id(1)
+    segments = pl.num_programs(1)
+    step = b * segments + h
+    heads, block_size = pools[0].shape[1], pools[0].shape[2]
+    gh, rows = q_ref.shape[1], q_ref.shape[2]
+    width = table_ref.shape[1]
+    tile_keys = tile * block_size
+    end = width * block_size - 1  # the table's last key
+
+    def live_column(b, h):
+        first, span = _block_span(h, rows, q_len, row_blocks)
+        return _live_column(base_ref[b], first, span, width, block_size)
+
+    def each_copy(b, h, w, slot, act):
+        """Start, or wait for, the copies of tile ``w`` of segment ``(b, h)``:
+        its entries up to the segment's last live column."""
+        entries = jnp.minimum(live_column(b, h) - w * tile + 1, tile)
+
+        def entry(t, carry):
+            block = table_ref[b, w * tile + t]
+            for pool, buffer in zip(pools, buffers):
+                source = pool.at[block]
+                if gh < heads:
+                    source = source.at[pl.ds(h // row_blocks * gh, gh)]
+                act(pltpu.make_async_copy(source, buffer.at[slot, t], arrived.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, entries, entry, 0)
+
+    start = functools.partial(each_copy, act=lambda copy: copy.start())
+    wait = functools.partial(each_copy, act=lambda copy: copy.wait())
+
+    @pl.when(step == 0)
+    def _first_of_all():
+        slot_ref[0] = 0
+        start(b, h, 0, 0)
+
+    first, span = _block_span(h, rows, q_len, row_blocks)
+    last = jnp.minimum(base_ref[b] + first + (span - 1), end)  # the last key any query of the block sees
+    tiles = live_column(b, h) // tile + 1
+    slot0 = slot_ref[0]
+    _init_state(acc_ref, m_ref, l_ref)
+
+    def walk(w, carry):
+        slot = (slot0 + w) % 2
+        more = w + 1 < tiles
+        # the segment that follows in the grid's order, and its first tile
+        wrap = h + 1 == segments
+        nb, nh = jnp.where(wrap, b + 1, b), jnp.where(wrap, 0, h + 1)
+
+        @pl.when(jnp.logical_or(more, step + 1 < pl.num_programs(0) * segments))
+        def _next_tile():
+            start(jnp.where(more, b, nb), jnp.where(more, h, nh), jnp.where(more, w + 1, 0), 1 - slot)
+
+        wait(b, h, w, slot)
+
+        @pl.when(w * tile_keys <= last)
+        def _fold():
+            joined = [
+                jnp.concatenate([buffer[slot, t] for t in range(tile)], axis=1) for buffer in buffers
+            ]  # (gh, tile_keys, dim) a leaf
+            _fold_tile(
+                q_ref[0], joined[0], joined[-1], w, base_ref[b], first, last, end,
+                acc_ref, m_ref, l_ref, q_len=q_len, sm_scale=sm_scale,
+            )
+
+        return carry
+
+    jax.lax.fori_loop(0, tiles, walk, 0)
+    slot_ref[0] = (slot0 + tiles) % 2
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_kernel(
@@ -226,23 +451,17 @@ def _paged_kernel(
     out_dtype,
 ):
     """One (batch row, head group and row block, table tile) program of the
-    online softmax.
+    BlockSpec walk: the pool leaves Mosaic will not slice (an int8 pool's).
 
     The scalar-prefetched table row already steered this tile's DMAs (see the
     index maps in :func:`_paged_forward`): ``tile`` consecutive table entries,
     each one pool block with all ``gh`` key heads, (1, gh, bs, hd). The body
     joins them into one (gh, tile * bs, hd) K and V, so the scores fill the
-    lanes, and folds them into the (acc, m, l) scratch — the flash-attention
-    recurrence of ``attention._flash_kernel``, walked over the table instead of
-    a dense KV. It runs only for tiles that hold a key some query of the block
-    may see (``tile start <= base + the block's last offset``); for the tiles
-    past that the index maps repeat the last live tile, so nothing is fetched
-    either. With ``shared_kv`` the K tile is the V tile too (one DMA).
-
-    The block's ``rows`` query rows are the query heads that share a key head,
-    ``q_len`` tokens each, head-major: row ``r`` of the head's rows sits at
-    position ``base + r % q_len``. A block holds whole spans of ``q_len``, or a
-    divisor of one (:func:`_tiling`).
+    lanes, and folds them (:func:`_fold_tile`). It runs only for tiles that
+    hold a key some query of the block may see (``tile start <= base + the
+    block's last offset``); for the tiles past that the index maps repeat the
+    last live tile, so nothing is fetched either, and what is left of the grid
+    step is its fixed cost. With ``shared_kv`` the K tile is the V tile too.
 
     Dequant mirrors the XLA gather arm bit for bit on VALUES:
     ``(codes.astype(f32) * scale).astype(out_dtype)`` — the cast to the compute
@@ -260,17 +479,16 @@ def _paged_kernel(
 
     b, w = pl.program_id(0), pl.program_id(2)
     rows = q_ref.shape[2]
+    width = table_ref.shape[1]
     tile_keys = tile * block_size
     # the table's last key: a short last tile repeats its last entry past it
-    end = table_ref.shape[1] * block_size - 1
+    end = width * block_size - 1
     first, span = _block_span(pl.program_id(1), rows, q_len, row_blocks)
     last = jnp.minimum(base_ref[b] + first + (span - 1), end)  # the last key any query of the block sees
 
     @pl.when(w == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
     @pl.when(w * tile_keys <= last)
     def _fold():
@@ -285,132 +503,119 @@ def _paged_kernel(
                 blocks.append(block)
             return jnp.concatenate(blocks, axis=1)
 
-        q = q_ref[0]  # (gh, rows, hd)
         k = joined(k_refs, k_scale_refs)  # (gh, tile_keys, hd)
         v = k if shared_kv else joined(v_refs, v_scale_refs)
-        operand = jnp.promote_types(q.dtype, k.dtype)
-        scores = jax.lax.dot_general(
-            q.astype(operand), k.astype(operand), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # (gh, rows, tile_keys)
-        k_pos = w * tile_keys + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
-        offset = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        if rows > q_len:  # several query heads' spans in one block
-            offset = offset % q_len
-        q_pos = base_ref[b] + first + offset
-        valid = k_pos <= jnp.minimum(q_pos, end)
-        scores = jnp.where(valid, scores, _NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]  # (gh, rows, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        # a query with no key yet (an empty live range inside a chunk) must add
-        # exactly 0: m_new is still _NEG_INF there and exp(0) would be 1
-        probs = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
-        correction = jnp.exp(m_prev - m_new)
-        # what the tile holds past the block's last key (the rest of its last
-        # block, a repeated entry) has weight 0, and 0 x NaN would still be NaN
-        seen = w * tile_keys + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-        v = jnp.where(seen <= last, v, jnp.zeros_like(v))
-        pv = jax.lax.dot_general(
-            probs.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # (gh, rows, hd)
-        acc_ref[...] = acc_ref[...] * correction + pv
-        l_ref[...] = l_prev * correction + jnp.sum(probs, axis=-1, keepdims=True)
-        m_ref[...] = m_new
+        _fold_tile(
+            q_ref[0], k, v, w, base_ref[b], first, last, end,
+            acc_ref, m_ref, l_ref, q_len=q_len, sm_scale=sm_scale,
+        )
 
     @pl.when(w == pl.num_programs(2) - 1)
     def _finalize():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _block_span(h, rows, q_len, row_blocks):
-    """``(first offset, offsets spanned)`` of the query positions, relative to
-    the row's base, in the row block that grid index ``h`` of axis 1 names: a
-    block of whole query spans starts at 0 and spans ``q_len``; a divisor of a
-    span is the range ``[first, first + rows)``."""
-    if rows >= q_len:
-        return 0, q_len
-    return ((h % row_blocks) * rows) % q_len, rows
+def _plan(heads, rows_all, q_len, head_dim, width, k, v, k_scale):
+    """``(copied, key heads, query rows, table entries)`` of a call of
+    :func:`_paged_forward`: which walk its pool leaves take and how
+    :func:`_tiling` cuts its shapes. Shapes and dtypes only."""
+    copied = _copied(k, v, k_scale)
+    tiling = _tiling(
+        heads, rows_all, q_len, k.shape[2], head_dim, width, jnp.dtype(k.dtype).itemsize,
+        k_scale is not None, copied,
+    )
+    return (copied,) + tiling
 
 
+@functools.partial(jax.jit, static_argnames=("out_dtype", "sm_scale", "q_len", "interpret"))
 def _paged_forward(
-    q, k, v, block_table, base_positions, k_scale, v_scale, out_dtype, sm_scale, q_len, interpret,
+    q, k, v, block_table, base_positions, k_scale, v_scale, *, out_dtype, sm_scale, q_len, interpret,
 ):
     """``q`` is ``(batch, key heads, rows, head_dim)``: each key head's query
-    heads side by side, ``q_len`` tokens each (see :func:`paged_attention`)."""
+    heads side by side, ``q_len`` tokens each (see :func:`paged_attention`).
+
+    Jitted, so that a model's layers share one trace of the kernel and a
+    program one lowering of it (a ``func.call`` a layer): the kernel's body was
+    most of what tracing and lowering a decode step cost, 24 times over."""
     batch, heads, rows_all, head_dim = q.shape
     block_size = k.shape[2]
     width = block_table.shape[1]
     quantized = k_scale is not None
     shared_kv = v is None
     out_dim = head_dim if shared_kv else v.shape[-1]
-    gh, rows, tile = _tiling(
-        heads, rows_all, q_len, block_size, head_dim, width, k.dtype.itemsize, quantized
-    )
+    copied, gh, rows, tile = _plan(heads, rows_all, q_len, head_dim, width, k, v, k_scale)
     row_blocks = rows_all // rows
+    segments = heads // gh * row_blocks
 
-    kernel = functools.partial(
-        _paged_kernel,
-        tile=tile,
-        block_size=block_size,
-        sm_scale=sm_scale,
-        quantized=quantized,
-        shared_kv=shared_kv,
-        q_len=q_len,
-        row_blocks=row_blocks,
-        out_dtype=out_dtype,
-    )
+    def by_row(b, h, *_):
+        return b, h // row_blocks, h % row_blocks, 0
 
     def entry(t):
-        """Index map of a tile's ``t``-th table entry: (b, h, w, table, base) to
-        the pool block to DMA — this indirection IS the kernel's reason to exist
-        (no gathered copy). Past the block's last live column the walk stands
-        still: the entry repeats, and a repeated block index skips its DMA."""
+        """Index map of a tile's ``t``-th table entry in the BlockSpec walk:
+        (b, h, w, table, base) to the pool block to DMA. Past the block's last
+        live column the walk stands still: the entry repeats, and a repeated
+        block index skips its DMA."""
 
         def index(b, h, w, table, base):
             first, span = _block_span(h, rows, q_len, row_blocks)
-            live = jnp.clip(base[b] + first + (span - 1), 0, width * block_size - 1) // block_size
+            live = _live_column(base[b], first, span, width, block_size)
             column = jnp.minimum(jnp.minimum(w, live // tile) * tile + t, live)
             return table[b, column], h // row_blocks, 0, 0
 
         return index
 
-    def by_row(b, h, w, table, base):
-        return b, h // row_blocks, h % row_blocks, 0
-
-    pool_specs = [pl.BlockSpec((1, gh, block_size, head_dim), entry(t)) for t in range(tile)]
-    in_specs = [pl.BlockSpec((1, gh, rows, head_dim), by_row)] + pool_specs
-    operands = [q] + [k] * tile
-    if not shared_kv:
-        in_specs += [pl.BlockSpec((1, gh, block_size, out_dim), entry(t)) for t in range(tile)]
-        operands += [v] * tile
-    if quantized:
-        # the scales keep the pool's own rank-4 (blocks, heads, 1, 1) layout: a
-        # (1, gh, 1, 1) block's last two dims equal the array's, which is the
-        # one sub-(8, 128) block shape the Mosaic lowering accepts (a (1, gh)
-        # block of a (blocks, heads) view is refused)
-        in_specs += [pl.BlockSpec((1, gh, 1, 1), entry(t)) for t in range(tile)] * 2
-        operands += [k_scale] * tile + [v_scale] * tile
+    common = dict(tile=tile, sm_scale=sm_scale, shared_kv=shared_kv, q_len=q_len, row_blocks=row_blocks)
+    in_specs = [pl.BlockSpec((1, gh, rows, head_dim), by_row)]
+    scratch = [
+        pltpu.VMEM((gh, rows, out_dim), jnp.float32),
+        pltpu.VMEM((gh, rows, 1), jnp.float32),
+        pltpu.VMEM((gh, rows, 1), jnp.float32),
+    ]
+    if copied:
+        # the leaves where they lie; two slots a leaf, a DMA semaphore a slot,
+        # and the slot the segment's first tile is in, carried from step to step
+        kernel = functools.partial(_walk_kernel, **common)
+        grid = (batch, segments)
+        operands = [q, k] + ([] if shared_kv else [v])
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * (len(operands) - 1)
+        scratch = [
+            pltpu.VMEM((2, tile, gh) + leaf.shape[2:], leaf.dtype) for leaf in operands[1:]
+        ] + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)] + scratch
+    else:
+        kernel = functools.partial(
+            _paged_kernel, block_size=block_size, quantized=quantized, out_dtype=out_dtype, **common
+        )
+        grid = (batch, segments, -(-width // tile))
+        in_specs += [pl.BlockSpec((1, gh, block_size, head_dim), entry(t)) for t in range(tile)]
+        operands = [q] + [k] * tile
+        if not shared_kv:
+            in_specs += [pl.BlockSpec((1, gh, block_size, out_dim), entry(t)) for t in range(tile)]
+            operands += [v] * tile
+        if quantized:
+            # the scales keep the pool's own rank-4 (blocks, heads, 1, 1) layout: a
+            # (1, gh, 1, 1) block's last two dims equal the array's, which is the
+            # one sub-(8, 128) block shape the Mosaic lowering accepts (a (1, gh)
+            # block of a (blocks, heads) view is refused)
+            in_specs += [pl.BlockSpec((1, gh, 1, 1), entry(t)) for t in range(tile)] * 2
+            operands += [k_scale] * tile + [v_scale] * tile
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(batch, heads // gh * row_blocks, -(-width // tile)),
+        grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, gh, rows, out_dim), by_row),
-        scratch_shapes=[
-            pltpu.VMEM((gh, rows, out_dim), jnp.float32),
-            pltpu.VMEM((gh, rows, 1), jnp.float32),
-            pltpu.VMEM((gh, rows, 1), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     codes_bytes = width * heads * block_size * (head_dim + (0 if shared_kv else out_dim)) * k.dtype.itemsize
     scale_bytes = 2 * width * heads * 4 if quantized else 0
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, heads, rows_all, out_dim), out_dtype),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        # sequential: a segment's first tile is fetched by the segment before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid), vmem_limit_bytes=_VMEM_LIMIT_BYTES
+        ),
         # a full table: what rows of the greatest length cost
         cost_estimate=pl.CostEstimate(
             flops=2 * batch * heads * rows_all * width * block_size * (head_dim + out_dim),
@@ -418,11 +623,15 @@ def _paged_forward(
             transcendentals=batch * heads * rows_all * width * block_size,
         ),
         interpret=interpret,
-    )(
-        block_table.astype(jnp.int32),
-        jnp.asarray(base_positions, jnp.int32).reshape(batch),
-        *operands,
     )
+    # the scope again, innermost: XLA names the Mosaic call after it, so a trace
+    # shows the kernel as ``paged_attention`` whatever jit wraps it
+    with jax.named_scope("paged_attention"):
+        return call(
+            block_table.astype(jnp.int32),
+            jnp.asarray(base_positions, jnp.int32).reshape(batch),
+            *operands,
+        )
 
 
 def resolve_paged_impl(
@@ -540,7 +749,9 @@ def paged_attention(
             f"paged_attention(impl='pallas') needs a TPU backend, found "
             f"{jax.default_backend()!r}; use impl='auto'/'xla', or interpret=True in tests"
         )
-    scale = 1.0 / np.sqrt(head_dim) if sm_scale is None else sm_scale
+    # hashable: they are static arguments of the jitted forward
+    scale = float(1.0 / np.sqrt(head_dim) if sm_scale is None else sm_scale)
+    out_dtype = jnp.dtype(out_dtype)
     shared_kv = v is None
     joined = shared_kv and row > head_dim  # rows of [key | value]
     if joined:
@@ -556,7 +767,8 @@ def paged_attention(
         k_scale, v_scale = scales or (None, None)
         out = _paged_forward(
             q.reshape(batch, local_keys, local_heads // local_keys * S, q.shape[-1]),
-            k, v, block_table, base_positions, k_scale, v_scale, out_dtype, scale, S, interpret,
+            k, v, block_table, base_positions, k_scale, v_scale,
+            out_dtype=out_dtype, sm_scale=scale, q_len=S, interpret=interpret,
         )
         return out.reshape(batch, local_heads, S, out.shape[-1])
 
@@ -582,6 +794,45 @@ def paged_attention(
     with jax.named_scope("paged_attention"):
         out = kernel(*operands)
     return out[..., head_dim:] if joined else out
+
+
+def walk_steps(
+    q, k, v, block_table, base_positions, k_scale=None, v_scale=None, shards: int = 1,
+) -> int:
+    """Steps one call of the kernel takes over these operands: the tiles it
+    fetches and folds, of every segment (batch row x head group x row block).
+
+    The operands are :func:`paged_attention`'s, of which only ``base_positions``
+    is read (a host array: this is numpy on the host, the engine's count for
+    ``/stats`` ``kernel_grid_steps``); the others give shapes and dtypes, so
+    ``jax.ShapeDtypeStruct`` will do. ``shards``: the ``tensor`` axis of the
+    mesh the call is shard-mapped over; the count is one shard's. The copied
+    walk takes ``live // tile + 1`` steps a segment, ``live`` the last table
+    column that holds a key the segment sees (:func:`_live_column`): the loop of
+    :func:`_walk_kernel` runs that often, in so many grid steps as there are
+    segments. The BlockSpec walk takes a grid step for every tile of the table,
+    whatever the rows hold.
+    """
+    del v_scale
+    batch, heads, q_len, head_dim = q.shape
+    key_heads, block_size, row = k.shape[1:]
+    width = block_table.shape[1]
+    if shards > 1 and key_heads % shards == 0:
+        heads, key_heads = heads // shards, key_heads // shards
+    elif shards > 1 and key_heads == 1 and heads % shards == 0:
+        heads //= shards
+    if v is None and row > head_dim:
+        head_dim = row  # rows of [key | value]: the query is padded to them
+    rows_all = heads // key_heads * q_len
+    copied, gh, rows, tile = _plan(key_heads, rows_all, q_len, head_dim, width, k, v, k_scale)
+    row_blocks = rows_all // rows
+    if not copied:
+        return batch * (key_heads // gh) * row_blocks * -(-width // tile)
+    first, span = _block_span(np.arange(row_blocks), rows, q_len, row_blocks)
+    base = np.asarray(base_positions, np.int64).reshape(batch, 1)
+    live = _live_column(base, first, span, width, block_size, xp=np)
+    tiles = np.broadcast_to(live // tile + 1, (batch, row_blocks))
+    return int(tiles.sum()) * (key_heads // gh)
 
 
 def fused_hbm_bytes(

@@ -80,6 +80,7 @@ reason, and all of it is deterministically injectable via
 
 import asyncio
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -481,6 +482,13 @@ class DecodeEngine:
         #: Over ``active_slot_steps x table width`` it is the share of the block
         #: table the paged kernel's bounded walk still visits
         self.live_block_steps = 0
+        #: paged engines on the kernel: sum over dispatches of the steps one
+        #: layer's call of the decode kernel takes (the tiles it fetches and
+        #: folds: ``ops.paged_attention.walk_steps`` of every row's position,
+        #: a retired row's sentinel among them), x steps of the burst. Over
+        #: ``active_slot_steps`` it is kernel steps a decoding row
+        self.kernel_grid_steps = 0
+        self._walk: Optional[Tuple[Tuple[int, int], Any]] = None
         #: the model's own step counters (what it sows into its ``"stats"``
         #: collection in a decode step, e.g. a sparse model's ``expert_rows``),
         #: summed over decode steps: fetched with the step's tokens, added in
@@ -2291,6 +2299,7 @@ class DecodeEngine:
             "idle_dispatches": self.idle_dispatches,
             "active_slot_steps": self.active_slot_steps,
             "live_block_steps": self.live_block_steps,
+            "kernel_grid_steps": self.kernel_grid_steps,
             **self.model_counters,
             "phases": self.timeline.snapshot(),
         }
@@ -2624,6 +2633,7 @@ class DecodeEngine:
             int(np.sum(self._lens_host[self._active] // self._prefix_block_size + 1))
             if self.paged else 0
         )
+        kernel_steps = self._kernel_steps() if self.paged_attn_impl == "pallas" else 0
         timeline.enter("dispatch", active=active)
         device_was_idle = self._inflight is None
         try:
@@ -2640,6 +2650,7 @@ class DecodeEngine:
         self.step_dispatches += 1
         self.active_slot_steps += active * lookahead
         self.live_block_steps += live_blocks * lookahead
+        self.kernel_grid_steps += kernel_steps * lookahead
         if device_was_idle and self._last_fetch_done is not None:
             self.idle_dispatches += 1
         previous, prev_skip = self._inflight, self._inflight_skip
@@ -2651,6 +2662,33 @@ class DecodeEngine:
         if not self.pipeline:
             events.extend(self._fetch_inflight())  # blocks until the burst's tokens are on the host
         return events
+
+    def _kernel_steps(self) -> int:
+        """Steps the decode kernel takes in one layer of the step about to be
+        dispatched, from the host's mirrors: every slot's row at its length, a
+        retired one on the sentinel (``_decode_body_paged``). The call's shapes
+        are bound once a table geometry (this runs every dispatch, on the loop
+        thread)."""
+        geometry = (self._table_width, self._prefix_block_size)
+        if self._walk is None or self._walk[0] != geometry:
+            from unionml_tpu.ops.paged_attention import walk_steps
+            from unionml_tpu.parallel.mesh import TENSOR_AXIS
+
+            layer = {
+                name: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+                for name, leaf in next(iter(self._pool.values())).items()
+            }
+            k = layer.get("kv", layer.get("k"))
+            heads = self._layout.kernel_key[0]
+            self._walk = geometry, functools.partial(
+                walk_steps,
+                jax.ShapeDtypeStruct((self.num_slots, heads, 1, k.shape[-1]), k.dtype),
+                k, layer.get("v"), jax.ShapeDtypeStruct((self.num_slots, geometry[0]), jnp.int32),
+                k_scale=layer.get("k_scale"), v_scale=layer.get("v_scale"),
+                shards=int(self._mesh.shape.get(TENSOR_AXIS, 1)) if self._mesh is not None else 1,
+            )
+        sentinel = (geometry[0] - 1) * geometry[1]
+        return self._walk[1](base_positions=np.where(self._active, self._lens_host, sentinel))
 
     def abort_all(self) -> None:
         """Deactivate every slot (in-flight state is abandoned; cache reuse is safe).
