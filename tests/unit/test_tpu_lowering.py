@@ -444,23 +444,57 @@ def cell_engine():
     )
 
 
-@pytest.mark.parametrize("program", ["decode_step", "paged_insert", "paged_chunk"])
-def test_pool_writers_compile_without_pool_copies(as_on_tpu, v5e_host, cell_engine, program):
-    """The engine's three programs that write a full-precision pool, compiled
-    for a v5e at the closed serving cell's shapes (3073 blocks; one layer) with
-    the pool donated: the decode step (48 appends, then the Mosaic call), the
-    paged insert of a prefill wave (4 rows of 128) and a 64-token chunk through
-    a table row. None may hold a ``copy`` the size of the pool. The layout this
-    guards: one leaf a layer whose rows are 128 lanes wide, written by scatters
-    whose indexed axes lead. With two 64-wide leaves and ``.at[dst, :, off,
-    :]`` XLA re-laid the pool out around every scatter and call: 6, 4 and 6
-    such copies a layer in these programs, 86% of the cell's device time."""
+@pytest.fixture(scope="module")
+def latent_engine():
+    """A one-layer engine with the sparse-decoder cell's cache shapes (32 slots
+    of 8192 positions, 128-token blocks, one 640-wide leaf a layer: 512 latent
+    + 64 rotary + 64 of padding) at the published attention widths; the feed-
+    forward and the vocabulary are small, the pool a few blocks: its programs
+    are lowered for the cell's 2049."""
+    from unionml_tpu.models.latent_moe import LatentMoEConfig, LatentMoELMHeadModel, init_params
+    from unionml_tpu.serving.continuous import DecodeEngine
+
+    cfg = LatentMoEConfig(
+        vocab_size=512, num_layers=1, first_k_dense_replace=1, intermediate_size=256,
+        max_position_embeddings=8192, paged_attn_impl="pallas",
+    )
+    return DecodeEngine(
+        LatentMoELMHeadModel(cfg), init_params(cfg), num_slots=32, max_len=8192,
+        prefix_block_size=128, pool_blocks=66, prefill_buckets=(256, 512, 1024),
+        prefill_chunk=1024, prefill_batch=1,
+    )
+
+
+@pytest.mark.parametrize("program,engine,blocks", [
+    ("decode_step", "cell_engine", 3073),
+    ("prefill_wave", "cell_engine", 3073),
+    ("paged_chunk", "cell_engine", 3073),
+    ("prefill_wave", "latent_engine", 32 * 64 + 1),
+])
+def test_pool_writers_compile_without_pool_copies(as_on_tpu, v5e_host, request, program, engine, blocks):
+    """The engine's programs that write a full-precision pool, compiled for a
+    v5e at the serving cells' shapes (one layer) with the pool donated. At the
+    closed cell's 3073 blocks: the decode step (48 appends, then the Mosaic
+    call), the fused admission wave (4 rows of 128: the table rows, the bucket
+    prefill, its workspace scattered whole blocks at a time, the lengths, the
+    logits and the slot mirrors in one program that donates everything but the
+    weights) and a 64-token chunk through a table row. At the latent layout's
+    2049 blocks of 128 tokens and 640-wide rows: the sparse cell's wave, one
+    row of 512. None may hold a ``copy`` the size of the pool. The layout this
+    guards: one leaf a layer whose rows are whole 128-lane tiles, written by
+    scatters whose indexed axes lead. With two 64-wide leaves and ``.at[dst,
+    :, off, :]`` XLA re-laid the pool out around every scatter and call: 6, 4
+    and 6 such copies a layer in these programs, 86% of the closed cell's
+    device time. And a program that holds the donated pool together with new
+    work (here the whole prefill) is where XLA would re-lay it out again."""
     import re
 
     from jax.sharding import SingleDeviceSharding
 
-    e, blocks = cell_engine, 3073
-    small, vocab = e.pool_blocks, e._last_logits.shape[-1]
+    from unionml_tpu.serving.continuous import _WAVE_SCALARS
+
+    e = request.getfixturevalue(engine)
+    small = e.pool_blocks
     on_chip = SingleDeviceSharding(v5e_host[0])
 
     def abstract(tree):
@@ -478,13 +512,12 @@ def test_pool_writers_compile_without_pool_copies(as_on_tpu, v5e_host, cell_engi
             e._variables, e._pool, e._tables, e._last_logits, e._lens, e._active_dev,
             e._remaining_dev, e._key, e._temp_dev, e._top_k_dev, e._top_p_dev,
         )))
-    elif program == "paged_insert":
-        rows, bucket = 4, 128
-        local = abstract(jax.eval_shape(lambda: e._layout.init_cache(rows, bucket)))
-        lowered = e._paged_insert_fn.lower(
-            *abstract((e._pool, e._tables, e._lens, e._last_logits)), local,
-            on((rows, vocab), jnp.float32), on((rows,)), on((rows,)),
-        )
+    elif program == "prefill_wave":
+        rows, bucket = (4, 128) if engine == "cell_engine" else (1, 512)
+        lowered = e._prefill_wave_fn.lower(*abstract((
+            e._variables, e._pool, e._tables, e._lens, e._last_logits, e._active_dev,
+            e._remaining_dev, e._temp_dev, e._top_k_dev, e._top_p_dev,
+        )), on((rows, bucket + _WAVE_SCALARS + e._table_width)))
     else:
         lowered = e._paged_chunk_fn.lower(
             abstract(e._variables), on((1, 64)), *abstract((e._pool, e._tables)), on(()), on(()), on(()),

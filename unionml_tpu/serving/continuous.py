@@ -122,12 +122,18 @@ def bind_serving_mesh(model: Any, mesh: Optional[Any]) -> Any:
 #: the serving loop's phases (spans ``loop.<phase>``, counters in
 #: ``pipeline_stats()["phases"]``): ``idle`` no active slot and nothing queued;
 #: ``admit`` deadlines, preemption, scheduler pop, validation, block-demand
-#: gate, registration; ``prefill`` padded rows, block allocation, the prefill
-#: and insert dispatches, activation; ``plan`` pending events, headroom and
+#: gate, registration; ``prefill`` padded rows, block allocation, the wave's
+#: upload and dispatch, activation; ``plan`` pending events, headroom and
 #: lookahead planning; ``dispatch`` enqueueing the decode program;
 #: ``fetch_wait`` the host blocked in the fused token fetch (slack, not work);
 #: ``apply`` tokens into the host mirrors; ``fan_out`` delivery to the sinks
 LOOP_PHASES = ("idle", "admit", "prefill", "plan", "dispatch", "fetch_wait", "apply", "fan_out")
+
+#: a paged admission wave's one upload is an int32 array of a row a request:
+#: the padded prompt, then these per-row scalars (slot, prompt length, token
+#: budget, ``top_k``, and the bits of the float32 ``temperature`` and
+#: ``top_p``), then the slot's block-table row
+_WAVE_SCALARS = 6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -488,6 +494,11 @@ class DecodeEngine:
         #: a retired row's sentinel among them), x steps of the burst. Over
         #: ``active_slot_steps`` it is kernel steps a decoding row
         self.kernel_grid_steps = 0
+        #: paged engines: host-to-device uploads plus program dispatches
+        #: issued by bucketed admission waves, counted where each is issued.
+        #: Over the ``prefill`` phase's ``entries`` it is calls a wave: 2
+        #: (the dense engine's wave, several programs, is not counted)
+        self.wave_device_calls = 0
         self._walk: Optional[Tuple[Tuple[int, int], Any]] = None
         #: the model's own step counters (what it sows into its ``"stats"``
         #: collection in a decode step, e.g. a sparse model's ``expert_rows``),
@@ -825,7 +836,7 @@ class DecodeEngine:
 
             self._make_step = _make_step_paged
 
-            def _paged_insert(pool, tables, lens, last_logits, local_cache, local_logits, slots, lengths):
+            def _paged_insert(pool, table_rows, local_cache, lengths):
                 """Write a batched bucket prefill's dense workspace into the
                 admitted slots' pool blocks through their table rows, whole
                 blocks at a time: the indexed axis leads, so XLA writes in
@@ -842,7 +853,7 @@ class DecodeEngine:
                 block_size = self._prefix_block_size
                 bucket = jax.tree_util.tree_leaves(local_cache)[0].shape[2]
                 nb = -(-bucket // block_size)
-                dst_blocks = tables[slots][:, :nb]  # (rows, nb)
+                dst_blocks = table_rows[:, :nb]  # (rows, nb)
                 valid = (
                     jnp.arange(nb * block_size).reshape(nb, block_size)[None, :, :]
                     < lengths[:, None, None]
@@ -882,14 +893,40 @@ class DecodeEngine:
                         new_pool[name] = {
                             key: put_full(leaf, joined[name][key]) for key, leaf in layer.items()
                         }
-                pool = _constrain_cache(new_pool)
+                return _constrain_cache(new_pool)
+
+            def _prefill_wave(
+                variables, pool, tables, lens, last_logits, active, remaining, temp, top_k, top_p, wave
+            ):
+                """One bucketed admission wave, whole: the rows' table rows
+                written, the bucket prefill, its workspace scattered through
+                those rows into the pool, and ``lens``, ``last_logits`` and the
+                five slot mirrors set at the rows' slots. ``wave`` is the
+                wave's one upload (:meth:`_dispatch_wave` packs it).
+                Everything but the weights and ``wave`` is DONATED; an
+                in-flight step keeps the old ``tables`` and mirror arrays, as
+                under ``_write_row`` and ``_slot_update``."""
+                # graftlint: disable=retrace -- deliberate trace-time read: the table width is an axis of ``tables``, so a host mutation (enable_prefix_cache re-layout) changes this program's input shapes and forces the retrace that re-reads it
+                bucket = wave.shape[1] - _WAVE_SCALARS - self._table_width
+                prompt_ids = wave[:, :bucket]
+                slots, lengths, budgets, ks, ts, ps = (
+                    wave[:, bucket + i] for i in range(_WAVE_SCALARS)
+                )
+                table_rows = wave[:, bucket + _WAVE_SCALARS:]
+                local_cache, local_logits = _prefill(variables, prompt_ids, lengths)
                 return (
-                    pool,
-                    lens.at[slots].set(lengths.astype(lens.dtype)),
+                    _paged_insert(pool, table_rows, local_cache, lengths),
+                    tables.at[slots].set(table_rows),
+                    lens.at[slots].set(lengths),
                     last_logits.at[slots].set(local_logits.astype(jnp.float32)),
+                    active.at[slots].set(True),
+                    remaining.at[slots].set(budgets),
+                    temp.at[slots].set(jax.lax.bitcast_convert_type(ts, jnp.float32)),
+                    top_k.at[slots].set(ks),
+                    top_p.at[slots].set(jax.lax.bitcast_convert_type(ps, jnp.float32)),
                 )
 
-            self._paged_insert_fn = jax.jit(_paged_insert, donate_argnums=(0, 2, 3))
+            self._prefill_wave_fn = jax.jit(_prefill_wave, donate_argnums=tuple(range(1, 10)))
 
             def _paged_chunk(variables, chunk_ids, pool, tables, slot, position, pick):
                 """One batch-1 prefill chunk written STRAIGHT into the slot's
@@ -1226,14 +1263,16 @@ class DecodeEngine:
         }
 
     # transfers: kv-block
-    def _alloc_slot_blocks(self, slot: int, start: int, need: int) -> List[int]:
+    def _alloc_slot_blocks(self, slot: int, start: int, need: int, gauges: bool = True) -> List[int]:
         """Acquire ``need`` private pool blocks for ``slot``'s table columns
         ``[start, start+need)``, flushing the in-flight burst once on shortfall
         (its unreplayed retirements may be sitting on frees). Still short →
         the structured pool-exhaustion failure: ``retryable``, because blocks
         free as live requests retire. The grant is recorded in
         ``_slot_block_map`` immediately, so every unwind path (cancel, the
-        admission orphan sweep) sees the ownership."""
+        admission orphan sweep) sees the ownership. A caller that allocates
+        for several slots passes ``gauges=False`` and refreshes the pool
+        gauges itself, once, after the last."""
         if need <= 0:
             self._slot_block_map.setdefault(slot, {})
             return []
@@ -1253,7 +1292,8 @@ class DecodeEngine:
         if self._telemetry is not None:
             self._telemetry.blocks_per_request.observe(float(need))
             self._note_span(slot, "block_alloc", blocks=need, shared=start)
-            self._note_pool_gauges()
+            if gauges:
+                self._note_pool_gauges()
         return ids
 
     # owns: kv-block
@@ -1321,6 +1361,13 @@ class DecodeEngine:
             raise
 
     def _activate(self, slot: int, length: int, budget: int, temp: float, top_k: int, top_p: float) -> None:
+        self._mark_active(slot, length, budget, temp, top_k, top_p)
+        self._slot_device_update(slot, True, budget, temp, top_k, top_p)
+
+    def _mark_active(self, slot: int, length: int, budget: int, temp: float, top_k: int, top_p: float) -> None:
+        """The host half of an activation: the numpy mirrors and the
+        admission bookkeeping (a paged wave sets the device mirrors inside its
+        own program)."""
         self._active[slot] = True
         self._reserved[slot] = False
         self._lens_host[slot] = length
@@ -1331,7 +1378,6 @@ class DecodeEngine:
         self.requests_admitted += 1
         if self._admitting is not None:
             self._admitting.append(slot)
-        self._slot_device_update(slot, True, budget, temp, top_k, top_p)
 
     def _slot_device_update(
         self, slot: int, is_active: bool, budget: int, temp: float, top_k: int, top_p: float
@@ -1414,13 +1460,21 @@ class DecodeEngine:
         instead of recomputing it — a cold burst of N same-prefix prompts pays
         ONE full prefill plus N-1 suffixes, not N full prefills.
 
-        Admission is ATOMIC against non-poisoning failures: when a prefill
-        dispatch dies without consuming shared engine state, every slot this
-        call already admitted is cancelled before the exception re-raises, so
-        the caller can attribute the failure per-request by re-admitting one
-        at a time (the batcher does exactly this). A failure that consumed
-        donated engine state escalates to a full engine failure instead —
-        salvage captured, device state rebuilt in place (see :meth:`rebuild`).
+        Admission is ATOMIC against non-poisoning failures: when an admission
+        dies without consuming shared engine state, every slot this call
+        already admitted is cancelled (and every block it was granted freed)
+        before the exception re-raises, so the caller can attribute the
+        failure per-request by re-admitting one at a time (the batcher does
+        exactly this). A failure that consumed donated engine state escalates
+        to a full engine failure instead — salvage captured, device state
+        rebuilt in place (see :meth:`rebuild`). On the paged engine a bucketed
+        wave is ONE program that donates the pool, the tables, the lengths,
+        the logits and the slot mirrors, so what unwinds cleanly there is what
+        fails before that dispatch: validation, slot and block shortage
+        (``pool_exhausted``), an injected prefill fault, the wave's upload.
+        Any exception out of the dispatch itself is a full engine failure.
+        The dense engine's prefill dispatch donates nothing and still unwinds
+        cleanly; its insert and point-updates escalate.
         """
         self._ensure_usable()
         if self._faults is not None:
@@ -1509,44 +1563,41 @@ class DecodeEngine:
     def _flush_groups(
         self, groups: Dict[int, List[int]], normalized: Sequence[Tuple], slots: Sequence[int]
     ) -> None:
-        """Run the batched bucket prefills: per bucket, up to ``prefill_batch``
-        rows per device dispatch, then one scatter into the slot cache rows."""
+        """Run the batched bucket prefills: per bucket, waves of up to
+        ``prefill_batch`` rows. A paged wave is one upload and one program
+        (:meth:`_dispatch_wave`); the dense engine's is a prefill dispatch,
+        one scatter into the slot cache rows and a point-update a row."""
         slot_to_norm = {slot: norm for slot, norm in zip(slots, normalized)}
         for bucket, idxs in groups.items():
             for start in range(0, len(idxs), self.prefill_batch):
                 chunk = idxs[start : start + self.prefill_batch]
                 rows = len(chunk)
                 self.timeline.enter("prefill", rows=rows, bucket=int(bucket))
-                padded = np.zeros((rows, bucket), dtype=np.int32)
-                lengths = np.zeros((rows,), dtype=np.int32)
-                for r, slot in enumerate(chunk):
-                    prompt = slot_to_norm[slot][0]
-                    padded[r, : prompt.size] = prompt
-                    lengths[r] = prompt.size
                 if self.paged:
-                    # block admission: each slot's table row maps exactly its
-                    # lifetime demand; bucket padding past the allocation lands
-                    # on the row's scratch tail inside the paged insert
-                    for slot in chunk:
-                        norm = slot_to_norm[slot]
-                        private = self._alloc_slot_blocks(
-                            slot, 0, self.block_demand(norm[0].size, norm[1])
-                        )
-                        self._write_slot_row(slot, private)
-                if self._faults is not None:
-                    self._faults.check_prefill()
-                local_cache, local_logits = self._prefill_fn(
-                    self._variables, jnp.asarray(padded), jnp.asarray(lengths)
-                )
-                self._insert_into_slots(
-                    local_cache, local_logits,
-                    jnp.asarray(chunk, dtype=jnp.int32),
-                    jnp.asarray(lengths),
-                )
+                    self._dispatch_wave(int(bucket), [(slot, *slot_to_norm[slot]) for slot in chunk])
+                    activate = self._mark_active  # the wave's program set the device mirrors
+                else:
+                    padded = np.zeros((rows, bucket), dtype=np.int32)
+                    lengths = np.zeros((rows,), dtype=np.int32)
+                    for r, slot in enumerate(chunk):
+                        prompt = slot_to_norm[slot][0]
+                        padded[r, : prompt.size] = prompt
+                        lengths[r] = prompt.size
+                    if self._faults is not None:
+                        self._faults.check_prefill()
+                    local_cache, local_logits = self._prefill_fn(
+                        self._variables, jnp.asarray(padded), jnp.asarray(lengths)
+                    )
+                    self._insert_into_slots(
+                        local_cache, local_logits,
+                        jnp.asarray(chunk, dtype=jnp.int32),
+                        jnp.asarray(lengths),
+                    )
+                    activate = self._activate
                 self.prefill_dispatches += 1
-                for r, slot in enumerate(chunk):
+                for slot in chunk:
                     prompt, budget, temp, top_k, top_p = slot_to_norm[slot]
-                    self._activate(slot, int(lengths[r]), budget, temp, top_k, top_p)
+                    activate(slot, int(prompt.size), budget, temp, top_k, top_p)
                     self.prefill_tokens_computed += int(prompt.size)
                     self._index_prompt(slot, prompt)
                     if self._telemetry is not None:
@@ -1555,6 +1606,54 @@ class DecodeEngine:
                             slot, "prefill",
                             tokens=int(prompt.size), bucket=int(bucket), batch_rows=rows,
                         )
+
+    def _dispatch_wave(self, bucket: int, wave_rows: Sequence[Tuple]) -> None:
+        """One bucketed admission wave of a paged engine, on the device: the
+        rows ``(slot, prompt, budget, temperature, top_k, top_p)`` get their
+        pool blocks (each slot's table row maps exactly its lifetime demand;
+        bucket padding past it lands on the row's scratch tail), everything
+        the program reads goes up in ONE explicit ``device_put`` (an int32
+        array, laid out as :data:`_WAVE_SCALARS` says) and ONE donating
+        dispatch does the rest (``_prefill_wave``). The in-flight step keeps
+        the old tables and mirrors, so a running burst is not disturbed. An
+        injected prefill fault fires before the upload, with nothing donated:
+        a clean unwind. A failure of the dispatch itself has CONSUMED the
+        pool, the tables, lengths, logits and slot mirrors, so it marks the
+        device state poisoned and the public entry point escalates."""
+        scalars_at = bucket + _WAVE_SCALARS
+        wave = np.zeros((len(wave_rows), scalars_at + self._table_width), dtype=np.int32)
+        wave[:, scalars_at:] = self._scratch_block
+        floats = wave[:, scalars_at - 2 : scalars_at].view(np.float32)  # the same memory, as floats
+        for r, (slot, prompt, budget, temp, top_k, top_p) in enumerate(wave_rows):
+            private = self._alloc_slot_blocks(
+                slot, 0, self.block_demand(prompt.size, budget), gauges=False
+            )
+            wave[r, : prompt.size] = prompt
+            wave[r, bucket : scalars_at - 2] = (
+                slot, prompt.size, min(int(budget), np.iinfo(np.int32).max), top_k
+            )
+            floats[r] = (temp, top_p)
+            wave[r, scalars_at : scalars_at + len(private)] = private
+        if self._telemetry is not None:
+            self._note_pool_gauges()
+        if self._faults is not None:
+            self._faults.check_prefill()
+        self.wave_device_calls += 1
+        wave = jax.device_put(wave, self._replicated)
+        self.wave_device_calls += 1
+        try:
+            (
+                self._pool, self._tables, self._lens, self._last_logits,
+                self._active_dev, self._remaining_dev,
+                self._temp_dev, self._top_k_dev, self._top_p_dev,
+            ) = self._prefill_wave_fn(
+                self._variables, self._pool, self._tables, self._lens, self._last_logits,
+                self._active_dev, self._remaining_dev,
+                self._temp_dev, self._top_k_dev, self._top_p_dev, wave,
+            )
+        except Exception:
+            self._device_poisoned = True
+            raise
 
     def _defer_for_sibling(self, prompt: np.ndarray, sibling_prefixes: set) -> bool:
         """True when an earlier request in THIS admit_many call is about to
@@ -1977,22 +2076,15 @@ class DecodeEngine:
         )
 
     def _insert_into_slots(self, local_cache: Any, local_logits: Any, slots: Any, lengths: Any) -> None:
-        """Run the donating slot-insert dispatch (paged: scatter the bucket
-        workspace through the admitted rows' block tables into the pool). A
-        failure here has CONSUMED the shared engine KV/lens/logits, so it marks
-        the device state poisoned — the public entry point escalates to a full
+        """Run the dense engine's donating slot-insert dispatch. A failure
+        here has CONSUMED the shared engine KV/lens/logits, so it marks the
+        device state poisoned — the public entry point escalates to a full
         engine failure instead of pretending the batch survived."""
         try:
-            if self.paged:
-                self._pool, self._lens, self._last_logits = self._paged_insert_fn(
-                    self._pool, self._tables, self._lens, self._last_logits,
-                    local_cache, local_logits, slots, lengths,
-                )
-            else:
-                self._cache, self._lens, self._last_logits = self._insert_fn(
-                    self._cache, self._lens, self._last_logits, local_cache, local_logits,
-                    slots, lengths,
-                )
+            self._cache, self._lens, self._last_logits = self._insert_fn(
+                self._cache, self._lens, self._last_logits, local_cache, local_logits,
+                slots, lengths,
+            )
         except Exception:
             self._device_poisoned = True
             raise
@@ -2300,6 +2392,7 @@ class DecodeEngine:
             "active_slot_steps": self.active_slot_steps,
             "live_block_steps": self.live_block_steps,
             "kernel_grid_steps": self.kernel_grid_steps,
+            "wave_device_calls": self.wave_device_calls,
             **self.model_counters,
             "phases": self.timeline.snapshot(),
         }
