@@ -589,3 +589,64 @@ def test_paged_attention_partitions_over_tensor_mesh(as_on_tpu, v5e_host, shape,
         jax.jit(_paged_fn(compute)).lower(*args).compile()
     compiled = jax.jit(_paged_fn(compute, mesh=mesh), out_shardings=by_head).lower(*args).compile()
     assert "all-gather" not in compiled.as_text()
+
+
+# ------------------------------------------- the hybrid decoder's three kernels
+
+
+@pytest.mark.parametrize("call", ["full_cache", "window_ring", "ssm_step", "ssm_scan"])
+def test_hybrid_decoder_kernels_compile_under_mosaic(as_on_tpu, v5e_host, monkeypatch, call):
+    """The hybrid cell's kernels at its sizes (64 slots, blocks of 128
+    tokens, rows of a key group 256 lanes wide): the full layer's and the cross
+    layers' call (40 zero-padded query heads over 10 key groups, a table of 33
+    columns), the window layers' (the rotated ring of 5 blocks, a window of
+    512), the state update (16 x 5120 float32 a slot, in place) and a
+    1,024-token bucket's scan (64 tokens x 512 channels a grid step). The
+    attention calls' first operand is their table, which is how the benchmark's
+    readers tell the two apart (``perfbench/configs/phi4-mini-flash-serve.json``)."""
+    import importlib
+
+    from jax.sharding import SingleDeviceSharding
+
+    from unionml_tpu.ops.paged_attention import paged_attention
+
+    ssm = importlib.import_module("unionml_tpu.ops.ssm")
+    monkeypatch.setattr(ssm, "on_tpu", lambda: True)
+    on_chip = SingleDeviceSharding(v5e_host[0])
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+    slots, groups, block, row, channels, states = 64, 10, 128, 256, 5120, 16
+    if call == "ssm_scan":
+        seq = 1024
+        args = [
+            on((1, seq, channels), jnp.bfloat16), on((1, seq, channels), jnp.float32), on((states, channels), jnp.float32),
+            on((1, seq, states), jnp.bfloat16), on((1, seq, states), jnp.bfloat16), on((channels,), jnp.float32),
+            on((1, states, channels), jnp.float32), on((1,), jnp.int32),
+        ]
+        fn = lambda u, delta, a, b, c, d, state, valid: ssm.selective_scan(u, delta, a, b, c, d, state, valid, impl="pallas")
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%ssm_scan" in text
+        return
+    if call == "ssm_step":
+        args = [
+            on((slots, channels), jnp.bfloat16), on((slots, channels), jnp.float32), on((states, channels), jnp.float32),
+            on((slots, states), jnp.bfloat16), on((slots, states), jnp.bfloat16), on((channels,), jnp.float32),
+            on((slots, states, channels), jnp.float32), on((slots,), jnp.bool_),
+        ]
+        fn = lambda u, delta, a, b, c, d, state, live: ssm.selective_step(
+            u, delta, a, b, c, d, state, live=live, impl="pallas")
+        text = jax.jit(fn, donate_argnums=(6,)).lower(*args).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%ssm_step" in text
+        assert f"f32[{slots},{states},{channels}]" in text
+        # in place: no copy of the state around the call
+        assert not [line for line in text.splitlines() if " copy(" in line and f"f32[{slots},{states},{channels}]" in line]
+        return
+    width, blocks, window = (33, slots * 32 + 1, None) if call == "full_cache" else (5, slots * 5 + 1, 512)
+    args = [
+        on((slots, 40, 1, row // 2), jnp.bfloat16), on((blocks, groups, block, row), jnp.bfloat16),
+        on((slots, width), jnp.int32), on((slots,), jnp.int32),
+    ]
+    fn = lambda q, pool, table, base: paged_attention(
+        q, pool, None, table, base, impl="pallas", sm_scale=0.125, window=window, out_dtype=jnp.float32)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"operand_layout_constraints={{s32[{slots},{width}]{{1,0}}, s32[{slots}]{{0}}, bf16[" in text

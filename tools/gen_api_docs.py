@@ -41,6 +41,7 @@ MODULES = [
     ("unionml_tpu.sim", "Fleet simulator (replay, synthetic traces, autoscaler)"),
     ("unionml_tpu.ops.attention", "Attention ops"),
     ("unionml_tpu.ops.paged_attention", "Paged attention (fused decode kernel)"),
+    ("unionml_tpu.ops.ssm", "State-space ops (selective scan, decode step, causal convolution)"),
     ("unionml_tpu.ops.sampling", "Sampling ops"),
     ("unionml_tpu.ops.quant", "Quantization ops"),
     ("unionml_tpu.stage", "Staged execution"),

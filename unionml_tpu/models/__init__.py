@@ -25,6 +25,7 @@ from unionml_tpu.models.gpt import lm_loss as gpt_lm_loss
 from unionml_tpu.models.gpt import KVCacheLayout
 from unionml_tpu.models.latent_moe import LatentCacheLayout, LatentMoEConfig, LatentMoELMHeadModel
 from unionml_tpu.models.mlp import CNNClassifier, MLPClassifier
+from unionml_tpu.models.phi4flash import HybridCacheLayout, Phi4FlashConfig, Phi4FlashLMHeadModel
 from unionml_tpu.models.moe import (
     MoEMlp,
     collect_aux_losses,
@@ -57,11 +58,14 @@ __all__ = [
     "router_z_loss",
     "GPTConfig",
     "GPTLMHeadModel",
+    "HybridCacheLayout",
     "KVCacheLayout",
     "LatentCacheLayout",
     "LatentMoEConfig",
     "LatentMoELMHeadModel",
     "MLPClassifier",
+    "Phi4FlashConfig",
+    "Phi4FlashLMHeadModel",
     "fit_lm",
     "gpt_generate",
     "gpt_lm_loss",

@@ -825,6 +825,13 @@ class KVCacheLayout:
     ``"kv"`` leaf (:func:`init_block_pool`). :meth:`join` and :meth:`split`
     carry a tree from the one naming to the other, whatever its leading axes."""
 
+    #: what a slot keeps beside its blocks under the table (a layout with
+    #: recurrent state or a window ring names them: the engine then refuses, by
+    #: these names, what moves blocks and nothing else): nothing here
+    slot_state: Tuple[str, ...] = ()
+    #: layers that a prefill runs for the position it reads alone: none
+    tail_layers = 0
+
     def __init__(self, config: GPTConfig) -> None:
         self.config = config
         #: heads of a cache leaf: what a ``tensor`` mesh axis has to divide
@@ -854,12 +861,26 @@ class KVCacheLayout:
 
     def init_block_pool(
         self, num_blocks: int, block_size: int, kv_quantize: Optional[str] = None,
-        kv_quantize_skip_layers: Tuple[int, ...] = (),
+        kv_quantize_skip_layers: Tuple[int, ...] = (), num_slots: Optional[int] = None,
     ) -> Dict[str, Any]:
+        """``num_slots`` sizes per-slot state, of which this layout has none."""
         return init_block_pool(
             self.config, num_blocks, block_size, kv_quantize=kv_quantize,
             kv_quantize_skip_layers=kv_quantize_skip_layers,
         )
+
+    def paged(self, pool: Dict[str, Any]) -> Dict[str, Any]:
+        """Of a pool, the layers whose blocks lie under the slots' table: one
+        group, every layer."""
+        return pool
+
+    def insert_slot_state(self, pool, local_cache, slots, lengths):
+        """(jit-traceable) A prefill's per-slot state into ``slots``: none to write."""
+        return pool
+
+    def slot_bytes(self, block_size: int) -> Dict[str, int]:
+        """Bytes a slot holds beside its blocks under the table, whatever its length."""
+        return {"state": 0, "ring": 0}
 
     def cache_spec(self, mesh_axis_names: Tuple[str, ...]) -> Any:
         return kv_cache_spec(self.config, mesh_axis_names)

@@ -215,6 +215,11 @@ class LatentCacheLayout:
     one leaf, no value leaf), in the shapes the per-head layouts have, so that
     tables, scatters and gathers are the engine's own."""
 
+    #: per-slot state beside the blocks under the table, and layers a prefill
+    #: runs for one position alone (:class:`unionml_tpu.models.gpt.KVCacheLayout`): none
+    slot_state: Tuple[str, ...] = ()
+    tail_layers = 0
+
     def __init__(self, config: LatentMoEConfig) -> None:
         self.config = config
         #: heads of a pool leaf (what a mesh may shard: nothing here)
@@ -231,7 +236,7 @@ class LatentCacheLayout:
 
     def init_block_pool(
         self, num_blocks: int, block_size: int, kv_quantize: Optional[str] = None,
-        kv_quantize_skip_layers: Tuple[int, ...] = (),
+        kv_quantize_skip_layers: Tuple[int, ...] = (), num_slots: Optional[int] = None,
     ) -> Dict[str, Any]:
         if kv_quantize is not None:
             raise ValueError(
@@ -256,7 +261,17 @@ class LatentCacheLayout:
 
         return PartitionSpec()  # one key head: every shard's, whole
 
-    def block_bytes(self, block_size: int) -> int:
+    def paged(self, pool: Dict[str, Any]) -> Dict[str, Any]:
+        return pool
+
+    def insert_slot_state(self, pool, local_cache, slots, lengths):
+        return pool
+
+    def slot_bytes(self, block_size: int) -> Dict[str, int]:
+        return {"state": 0, "ring": 0}
+
+    def block_bytes(self, block_size: int, kv_quantize: Optional[str] = None,
+                    kv_quantize_skip_layers: Tuple[int, ...] = ()) -> int:
         itemsize = jnp.dtype(self.config.dtype).itemsize
         return self.config.num_layers * block_size * self.config.cache_row_dim * itemsize
 

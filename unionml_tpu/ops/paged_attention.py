@@ -131,6 +131,7 @@ def xla_paged_attention(
     v_scale: Optional[jax.Array] = None,
     out_dtype=None,
     sm_scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Reference paged attention: gather the table, dequantize, attend dense.
 
@@ -158,7 +159,10 @@ def xla_paged_attention(
 
     k_pos = jnp.arange(capacity)
     q_pos = base_positions.astype(jnp.int32)[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    mask = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None, :, :]
+    mask = k_pos[None, None, :] <= q_pos[:, :, None]
+    if window is not None:
+        mask = mask & (k_pos[None, None, :] > q_pos[:, :, None] - window)
+    mask = mask[:, None, :, :]
     keys = gather(k, k_scale)
     if v is not None:
         values = gather(v, v_scale)
@@ -292,7 +296,7 @@ def _init_state(acc_ref, m_ref, l_ref):
     l_ref[...] = jnp.zeros_like(l_ref)
 
 
-def _fold_tile(q, k, v, w, base, first, last, end, acc_ref, m_ref, l_ref, *, q_len, sm_scale):
+def _fold_tile(q, k, v, w, base, first, last, end, acc_ref, m_ref, l_ref, *, q_len, sm_scale, window=None):
     """Fold tile ``w`` of a segment's walk, ``k`` and ``v`` ``(gh, tile_keys,
     dim)``, into the (acc, m, l) scratch: the flash-attention recurrence of
     ``attention._flash_kernel``, walked over the table instead of a dense KV.
@@ -313,6 +317,8 @@ def _fold_tile(q, k, v, w, base, first, last, end, acc_ref, m_ref, l_ref, *, q_l
         offset = offset % q_len
     q_pos = base + first + offset
     valid = k_pos <= jnp.minimum(q_pos, end)
+    if window is not None:  # the last ``window`` keys and none before them
+        valid = jnp.logical_and(valid, k_pos > q_pos - window)
     scores = jnp.where(valid, scores, _NEG_INF)
 
     m_prev, l_prev = m_ref[...], l_ref[...]  # (gh, rows, 1)
@@ -345,6 +351,7 @@ def _walk_kernel(
     shared_kv: bool,
     q_len: int,
     row_blocks: int,
+    window: Optional[int] = None,
 ):
     """One segment of the copied walk: a batch row's head group and row block,
     over the tiles that hold keys it can see and over no other.
@@ -426,7 +433,7 @@ def _walk_kernel(
             ]  # (gh, tile_keys, dim) a leaf
             _fold_tile(
                 q_ref[0], joined[0], joined[-1], w, base_ref[b], first, last, end,
-                acc_ref, m_ref, l_ref, q_len=q_len, sm_scale=sm_scale,
+                acc_ref, m_ref, l_ref, q_len=q_len, sm_scale=sm_scale, window=window,
             )
 
         return carry
@@ -449,6 +456,7 @@ def _paged_kernel(
     q_len: int,
     row_blocks: int,
     out_dtype,
+    window: Optional[int] = None,
 ):
     """One (batch row, head group and row block, table tile) program of the
     BlockSpec walk: the pool leaves Mosaic will not slice (an int8 pool's).
@@ -507,7 +515,7 @@ def _paged_kernel(
         v = k if shared_kv else joined(v_refs, v_scale_refs)
         _fold_tile(
             q_ref[0], k, v, w, base_ref[b], first, last, end,
-            acc_ref, m_ref, l_ref, q_len=q_len, sm_scale=sm_scale,
+            acc_ref, m_ref, l_ref, q_len=q_len, sm_scale=sm_scale, window=window,
         )
 
     @pl.when(w == pl.num_programs(2) - 1)
@@ -527,9 +535,10 @@ def _plan(heads, rows_all, q_len, head_dim, width, k, v, k_scale):
     return (copied,) + tiling
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "sm_scale", "q_len", "interpret"))
+@functools.partial(jax.jit, static_argnames=("out_dtype", "sm_scale", "q_len", "interpret", "window"))
 def _paged_forward(
     q, k, v, block_table, base_positions, k_scale, v_scale, *, out_dtype, sm_scale, q_len, interpret,
+    window=None,
 ):
     """``q`` is ``(batch, key heads, rows, head_dim)``: each key head's query
     heads side by side, ``q_len`` tokens each (see :func:`paged_attention`).
@@ -564,7 +573,9 @@ def _paged_forward(
 
         return index
 
-    common = dict(tile=tile, sm_scale=sm_scale, shared_kv=shared_kv, q_len=q_len, row_blocks=row_blocks)
+    common = dict(
+        tile=tile, sm_scale=sm_scale, shared_kv=shared_kv, q_len=q_len, row_blocks=row_blocks, window=window
+    )
     in_specs = [pl.BlockSpec((1, gh, rows, head_dim), by_row)]
     scratch = [
         pltpu.VMEM((gh, rows, out_dim), jnp.float32),
@@ -677,6 +688,7 @@ def paged_attention(
     interpret: bool = False,
     mesh=None,
     sm_scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Attend ``q`` over a row's paged KV through its block-table row.
 
@@ -724,6 +736,14 @@ def paged_attention(
         ``tensor`` shard (replicated when the axis does not divide them).
     :param sm_scale: what the scores are multiplied by; ``head_dim ** -0.5``
         when not given.
+    :param window: a query at logical position ``p`` sees the keys at ``p -
+        window < k <= p`` and none before them. The walk still begins at the
+        table's first column: a caller whose rows keep a ring of blocks hands
+        over the row's table ROTATED, its first column the block that holds
+        the window's first key, and base positions counted from that block's
+        first key (``models/phi4flash.py::ring_view``): the walk then starts at
+        the window's first live tile and covers ``window / block_size + 1``
+        columns whatever the row's length.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
@@ -743,6 +763,7 @@ def paged_attention(
             return xla_paged_attention(
                 q, k, v, block_table, base_positions,
                 k_scale=k_scale, v_scale=v_scale, out_dtype=out_dtype, sm_scale=sm_scale,
+                window=window,
             )
     if not interpret and not on_tpu():
         raise RuntimeError(
@@ -769,6 +790,7 @@ def paged_attention(
             q.reshape(batch, local_keys, local_heads // local_keys * S, q.shape[-1]),
             k, v, block_table, base_positions, k_scale, v_scale,
             out_dtype=out_dtype, sm_scale=scale, q_len=S, interpret=interpret,
+            window=window,
         )
         return out.reshape(batch, local_heads, S, out.shape[-1])
 

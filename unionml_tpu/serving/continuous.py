@@ -202,7 +202,15 @@ class DecodeEngine:
         ``.cache_layout()``: the
         dense cache, the block pool, their sharding and bytes and the paged
         kernel's shape key come from it, so a latent cache of one row a token
-        is served by the same tables, allocator and programs as per-head K/V).
+        is served by the same tables, allocator and programs as per-head K/V.
+        A layout may also keep per-slot state that is not paged
+        (``slot_state``: :class:`~unionml_tpu.models.phi4flash.HybridCacheLayout`'s
+        recurrent state and window ring): the pool then holds those leaves
+        beside the blocks, the admission wave writes them
+        (``insert_slot_state``), a prefill chunk is told its slot
+        (``cache["slots"]``) and the decode step updates them in place; the
+        prefix cache, preemption, speculative decoding, an int8 pool and a
+        mesh are refused by name for such a layout).
     :param variables: trained model variables (``{"params": ...}``).
     :param num_slots: concurrent sequences held on device (the decode batch).
     :param max_len: per-slot cache capacity (prompt + generated tokens). A slot
@@ -338,6 +346,23 @@ class DecodeEngine:
             raise ValueError(f"Unknown kv_quantize mode {kv_quantize!r}; expected None or 'int8'")
         if kv_quantize is not None and not paged:
             raise ValueError("kv_quantize requires paged=True (the block pool is what quantizes)")
+        if layout.slot_state:
+            # what a slot keeps beside its blocks under the table is in no block:
+            # every mechanism that moves, shares or re-scales blocks and nothing
+            # else is refused by name, not left to be silently wrong
+            held = " and ".join(layout.slot_state)
+            refused = {
+                "prefix_cache_blocks > 0 (the radix prefix cache)": bool(prefix_cache_blocks),
+                f"kv_quantize={kv_quantize!r} (an int8 pool)": kv_quantize is not None,
+                "mesh= (a device mesh)": mesh is not None,
+                "paged=False (dense slot caches)": not paged,
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{what} with a cache layout that keeps per-slot {held}: only its blocks "
+                        f"under the table could follow, the {held} cannot yet"
+                    )
         # quantize + mesh compose: quantization happens first (below), then
         # param_shardings assigns the int8 tree's {q, scale} leaves their specs
         # (the scale inherits the kernel's channel-axis split) and place_by_specs
@@ -499,6 +524,16 @@ class DecodeEngine:
         #: Over the ``prefill`` phase's ``entries`` it is calls a wave: 2
         #: (the dense engine's wave, several programs, is not counted)
         self.wave_device_calls = 0
+        #: sum over dispatches of (the host-active slots' lengths x steps in the
+        #: burst): with ``active_slot_steps`` and ``live_block_steps`` it gives
+        #: ``resident_byte_steps`` a live token (:meth:`pipeline_stats`)
+        self.live_token_steps = 0
+        #: admissions that began from empty per-slot state (every admission of a
+        #: layout with ``slot_state``: the wave overwrites it, a first chunk zeroes it)
+        self.state_resets = 0
+        #: prompt positions that did not enter the layout's ``tail_layers`` (the
+        #: layers a prefill runs for the position it reads alone)
+        self.cross_rows_skipped = 0
         self._walk: Optional[Tuple[Tuple[int, int], Any]] = None
         #: the model's own step counters (what it sows into its ``"stats"``
         #: collection in a decode step, e.g. a sparse model's ``expert_rows``),
@@ -836,7 +871,7 @@ class DecodeEngine:
 
             self._make_step = _make_step_paged
 
-            def _paged_insert(pool, table_rows, local_cache, lengths):
+            def _paged_insert(pool, table_rows, local_cache, lengths, slots):
                 """Write a batched bucket prefill's dense workspace into the
                 admitted slots' pool blocks through their table rows, whole
                 blocks at a time: the indexed axis leads, so XLA writes in
@@ -848,10 +883,14 @@ class DecodeEngine:
                 the full-precision write needs no per-row length mask.
                 Quantized layers DO mask: a padded column landing in an owned
                 block must not inflate that block's absmax scale, so positions
-                at/after a row's real length quantize as zeros."""
+                at/after a row's real length quantize as zeros. What the
+                layout keeps per slot and not under the table (``slot_state``)
+                it writes itself, at ``slots`` (``insert_slot_state``)."""
                 # graftlint: disable=retrace -- deliberate trace-time read: block_size is an axis of every pool leaf and fixes the table width, so any host mutation (enable_prefix_cache re-layout) changes this program's input shapes and forces the retrace that re-reads it
                 block_size = self._prefix_block_size
-                bucket = jax.tree_util.tree_leaves(local_cache)[0].shape[2]
+                # the workspace's layers under the table, by the pool's names (k beside v in one leaf)
+                joined = layout.join(local_cache)
+                bucket = jax.tree_util.tree_leaves(joined)[0].shape[2]
                 nb = -(-bucket // block_size)
                 dst_blocks = table_rows[:, :nb]  # (rows, nb)
                 valid = (
@@ -878,10 +917,9 @@ class DecodeEngine:
                     q, scale = quantize_blockwise(src, reduce_axes=(3, 4))
                     return pool_q.at[dst_blocks].set(q), pool_scale.at[dst_blocks].set(scale)
 
-                # the workspace under the pool's names (k beside v in one leaf)
-                joined = layout.join(local_cache)
-                new_pool = {}
-                for name, layer in pool.items():
+                new_pool = dict(pool)
+                for name in joined:
+                    layer = pool[name]
                     if "k_scale" in layer:
                         out = {}
                         for key in ("k", "v"):
@@ -893,6 +931,7 @@ class DecodeEngine:
                         new_pool[name] = {
                             key: put_full(leaf, joined[name][key]) for key, leaf in layer.items()
                         }
+                new_pool = layout.insert_slot_state(new_pool, local_cache, slots, lengths)
                 return _constrain_cache(new_pool)
 
             def _prefill_wave(
@@ -915,7 +954,7 @@ class DecodeEngine:
                 table_rows = wave[:, bucket + _WAVE_SCALARS:]
                 local_cache, local_logits = _prefill(variables, prompt_ids, lengths)
                 return (
-                    _paged_insert(pool, table_rows, local_cache, lengths),
+                    _paged_insert(pool, table_rows, local_cache, lengths, slots),
                     tables.at[slots].set(table_rows),
                     lens.at[slots].set(lengths),
                     last_logits.at[slots].set(local_logits.astype(jnp.float32)),
@@ -939,7 +978,8 @@ class DecodeEngine:
                 queued chunk would otherwise hold a chunk x vocab array)."""
                 variables = maybe_dequant(variables)
                 row = jax.lax.dynamic_slice_in_dim(tables, slot, 1, axis=0)  # (1, width)
-                cache = {"table": row, **pool}
+                # "slots": whose chunk this is, for a layout that keeps per-slot state
+                cache = {"table": row, "slots": jnp.reshape(slot, (1,)), **pool}
                 logits, new_cache = model.apply(
                     variables, chunk_ids, cache=cache, position=position,
                     logit_rows=jnp.reshape(pick, (1,)),
@@ -990,6 +1030,7 @@ class DecodeEngine:
                 self._prefix_block_size,
                 kv_quantize=self.kv_quantize,
                 kv_quantize_skip_layers=self.kv_quantize_skip_layers,
+                num_slots=self.num_slots,
             )
             tables = init_block_tables(
                 self.num_slots, self.max_len, self._prefix_block_size, self._scratch_block
@@ -1059,6 +1100,12 @@ class DecodeEngine:
 
         if self.prefix_cache is not None:
             raise RuntimeError("prefix cache is already enabled on this engine")
+        if self._layout.slot_state:
+            raise ValueError(
+                "the radix prefix cache with a cache layout that keeps per-slot "
+                f"{' and '.join(self._layout.slot_state)}: a shared prefix's blocks hold its keys, "
+                "and the state at its end is in no block"
+            )
         block_size = int(block_size)
         if not 1 <= block_size < self.max_len:
             raise ValueError(
@@ -1111,6 +1158,13 @@ class DecodeEngine:
         self._pool = self._layout.init_block_pool(int(num_blocks), block_size)
         if self._mesh is not None:
             self._pool = jax.device_put(self._pool, self._cache_sharding)
+
+    @property
+    def preemptible(self) -> bool:
+        """Whether :meth:`preempt` can checkpoint a running slot: the prefix
+        cache is on (a layout with per-slot state refuses it, so such an engine
+        never is). The SLO scheduler asks before it picks a victim."""
+        return self.prefix_cache is not None
 
     @property
     def free_slots(self) -> List[int]:
@@ -1599,6 +1653,7 @@ class DecodeEngine:
                     prompt, budget, temp, top_k, top_p = slot_to_norm[slot]
                     activate(slot, int(prompt.size), budget, temp, top_k, top_p)
                     self.prefill_tokens_computed += int(prompt.size)
+                    self._note_state_reset(int(prompt.size))
                     self._index_prompt(slot, prompt)
                     if self._telemetry is not None:
                         self._telemetry.prefill_tokens_total.inc(float(prompt.size))
@@ -1654,6 +1709,15 @@ class DecodeEngine:
         except Exception:
             self._device_poisoned = True
             raise
+
+    def _note_state_reset(self, tokens: int, first: bool = True) -> None:
+        """Count one prefill call of ``tokens`` real tokens for a layout with
+        per-slot state: the admission's reset (its ``first`` call) and the
+        positions that the tail layers did not see (all but the one read)."""
+        if self._layout.slot_state and first:
+            self.state_resets += 1
+        if self._layout.tail_layers:
+            self.cross_rows_skipped += tokens - 1
 
     def _defer_for_sibling(self, prompt: np.ndarray, sibling_prefixes: set) -> bool:
         """True when an earlier request in THIS admit_many call is about to
@@ -2034,6 +2098,7 @@ class DecodeEngine:
                 continue
             self.prefill_dispatches += 1
             self.prefill_tokens_computed += int(take)
+            self._note_state_reset(int(take), first=consumed == 0)
             state["consumed"] = consumed + take
             if self._telemetry is not None:
                 self._telemetry.prefill_tokens_total.inc(float(take))
@@ -2393,8 +2458,36 @@ class DecodeEngine:
             "live_block_steps": self.live_block_steps,
             "kernel_grid_steps": self.kernel_grid_steps,
             "wave_device_calls": self.wave_device_calls,
+            **self._residency_stats(),
             **self.model_counters,
             "phases": self.timeline.snapshot(),
+        }
+
+    def _residency_stats(self) -> Dict[str, Any]:
+        """What the active slots' caches hold, for :meth:`pipeline_stats`: the
+        integrals over dispatched steps ``resident_byte_steps`` (per-slot state
+        and ring of every active slot, and its live blocks under the table) and
+        ``live_token_steps``, whose quotient over a window is resident bytes a
+        live token; the gauges ``state_bytes``, ``ring_bytes`` (all slots',
+        whatever they hold) and ``kv_live_bytes`` (the active slots' live
+        blocks now); and the admission counters. Empty on a dense engine."""
+        if not self.paged:
+            return {}
+        block = self._layout.block_bytes(
+            self._prefix_block_size, kv_quantize=self.kv_quantize,
+            kv_quantize_skip_layers=self.kv_quantize_skip_layers,
+        )
+        per_slot = self._layout.slot_bytes(self._prefix_block_size)
+        fixed = per_slot["state"] + per_slot["ring"]
+        live_now = int(np.sum(self._lens_host[self._active] // self._prefix_block_size + 1))
+        return {
+            "resident_byte_steps": self.active_slot_steps * fixed + self.live_block_steps * block,
+            "live_token_steps": self.live_token_steps,
+            "state_bytes": per_slot["state"] * self.num_slots,
+            "ring_bytes": per_slot["ring"] * self.num_slots,
+            "kv_live_bytes": live_now * block,
+            "state_resets": self.state_resets,
+            "cross_rows_skipped": self.cross_rows_skipped,
         }
 
     def robustness_stats(self) -> Dict[str, Any]:
@@ -2722,10 +2815,8 @@ class DecodeEngine:
         # steady-state tick performs ZERO host→device transfers (pinned by the
         # transfer-guard regression test).
         active = int(np.count_nonzero(self._active))
-        live_blocks = (
-            int(np.sum(self._lens_host[self._active] // self._prefix_block_size + 1))
-            if self.paged else 0
-        )
+        live_lens = self._lens_host[self._active]
+        live_blocks = int(np.sum(live_lens // self._prefix_block_size + 1)) if self.paged else 0
         kernel_steps = self._kernel_steps() if self.paged_attn_impl == "pallas" else 0
         timeline.enter("dispatch", active=active)
         device_was_idle = self._inflight is None
@@ -2743,6 +2834,7 @@ class DecodeEngine:
         self.step_dispatches += 1
         self.active_slot_steps += active * lookahead
         self.live_block_steps += live_blocks * lookahead
+        self.live_token_steps += int(live_lens.sum()) * lookahead
         self.kernel_grid_steps += kernel_steps * lookahead
         if device_was_idle and self._last_fetch_done is not None:
             self.idle_dispatches += 1
@@ -2769,7 +2861,7 @@ class DecodeEngine:
 
             layer = {
                 name: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
-                for name, leaf in next(iter(self._pool.values())).items()
+                for name, leaf in next(iter(self._layout.paged(self._pool).values())).items()
             }
             k = layer.get("kv", layer.get("k"))
             heads = self._layout.kernel_key[0]
@@ -2869,6 +2961,12 @@ class DecodeEngine:
         a transcript beyond the bucket ladder). Raises ``RuntimeError`` when
         the prefix cache is disabled.
         """
+        if self._layout.slot_state:
+            raise ValueError(
+                "preempt (checkpointing a running slot into the prefix cache) with a cache layout "
+                f"that keeps per-slot {' and '.join(self._layout.slot_state)}: the checkpoint is blocks "
+                "of keys, and a resumed slot would start from empty state"
+            )
         if self.prefix_cache is None:
             raise RuntimeError("preempt requires the prefix cache (prefix_cache_blocks > 0)")
         self._ensure_usable()
@@ -3400,7 +3498,7 @@ class ContinuousBatcher:
         if (
             self.scheduler.config.fifo
             or not self.scheduler.config.preempt
-            or self._engine.prefix_cache is None
+            or not self._engine.preemptible
         ):
             return
         if self._engine.free_slots and not self._block_starved():

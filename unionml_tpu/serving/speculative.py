@@ -121,6 +121,13 @@ class SpeculativeEngine(DecodeEngine):
         from unionml_tpu.models.gpt import KVCacheLayout
 
         for role, net in (("target", model), ("draft", draft)):
+            held = net.cache_layout().slot_state
+            if held:
+                raise ValueError(
+                    f"SpeculativeEngine with a {role} whose cache layout keeps per-slot "
+                    f"{' and '.join(held)}: a rejected draft token is rolled back by a length, "
+                    "and state that has taken the token in cannot be"
+                )
             if not isinstance(net.cache_layout(), KVCacheLayout):
                 raise ValueError(
                     f"SpeculativeEngine with a {type(net.cache_layout()).__name__} {role}: the "
