@@ -524,6 +524,12 @@ class DecodeEngine:
         #: Over the ``prefill`` phase's ``entries`` it is calls a wave: 2
         #: (the dense engine's wave, several programs, is not counted)
         self.wave_device_calls = 0
+        #: counted by the batcher's fan-out (``ContinuousBatcher._dispatch_events``):
+        #: ``sink_handoffs`` +1 a cross-thread call to a consumer's event loop,
+        #: ``sink_deliveries`` +1 an item one carried. Their quotient over a
+        #: window is deliveries a hand-over: about a step's tokens
+        self.sink_handoffs = 0
+        self.sink_deliveries = 0
         #: sum over dispatches of (the host-active slots' lengths x steps in the
         #: burst): with ``active_slot_steps`` and ``live_block_steps`` it gives
         #: ``resident_byte_steps`` a live token (:meth:`pipeline_stats`)
@@ -2446,7 +2452,8 @@ class DecodeEngine:
     def pipeline_stats(self) -> Dict[str, Any]:
         """Pipeline observability for ``GET /stats``: configured depth, whether a
         step is currently in flight, the dispatch/idle counters, the active-slot
-        and live-block integrals and the loop thread's phase counters
+        and live-block integrals, the fan-out's hand-over counters and the loop
+        thread's phase counters
         (:meth:`~unionml_tpu.profiling.PhaseTimeline.snapshot`). Everything but
         ``inflight`` only grows, so two reads difference into a window."""
         return {
@@ -2458,6 +2465,8 @@ class DecodeEngine:
             "live_block_steps": self.live_block_steps,
             "kernel_grid_steps": self.kernel_grid_steps,
             "wave_device_calls": self.wave_device_calls,
+            "sink_handoffs": self.sink_handoffs,
+            "sink_deliveries": self.sink_deliveries,
             **self._residency_stats(),
             **self.model_counters,
             "phases": self.timeline.snapshot(),
@@ -3063,33 +3072,6 @@ class DecodeEngine:
         return out
 
 
-class _FutureSink:
-    """Buffers emitted tokens; resolves an asyncio future with the full list."""
-
-    #: set by the consumer when it abandons the request (disconnect/early exit);
-    #: the worker cancels the slot instead of delivering to a dead consumer
-    cancelled = False
-
-    def __init__(self, loop: asyncio.AbstractEventLoop, future: asyncio.Future) -> None:
-        self._loop = loop
-        self._future = future
-        self._tokens: List[int] = []
-
-    def emit(self, token: int) -> None:
-        self._tokens.append(token)
-
-    def finish(self) -> None:
-        tokens = list(self._tokens)
-        self._loop.call_soon_threadsafe(
-            lambda: self._future.done() or self._future.set_result(tokens)
-        )
-
-    def fail(self, exc: BaseException) -> None:
-        self._loop.call_soon_threadsafe(
-            lambda: self._future.done() or self._future.set_exception(exc)
-        )
-
-
 def _as_engine_failure(
     exc: BaseException, *, reason: str = "engine_failure", retryable: bool = True
 ) -> EngineFailure:
@@ -3105,25 +3087,84 @@ def _as_engine_failure(
 
 
 _STREAM_DONE = object()
+#: what :meth:`_LoopSink.stage` returns for a call that crosses no thread
+_IN_PLACE = object()
 
 
-class _QueueSink:
-    """Forwards each token to an asyncio queue as it decodes (streaming)."""
+class _LoopSink:
+    """A sink whose consumer is an asyncio event loop on another thread.
 
+    ``stage`` (worker side) turns a sink call into the value ``deliver`` (loop
+    side) applies there, or ``_IN_PLACE`` where nothing has to cross.
+    ``emit``, ``finish`` and ``fail`` hand one value over each;
+    :meth:`ContinuousBatcher._dispatch_events` stages a step's calls and hands
+    them to ``loop`` together (:func:`_deliver_batch`).
+    """
+
+    #: set by the consumer when it abandons the request (disconnect/early exit);
+    #: the worker cancels the slot instead of delivering to a dead consumer
     cancelled = False
 
-    def __init__(self, loop: asyncio.AbstractEventLoop, queue: "asyncio.Queue") -> None:
-        self._loop = loop
-        self._queue = queue
+    def __init__(self, loop: asyncio.AbstractEventLoop) -> None:
+        self.loop = loop
+
+    def _send(self, method: str, *args: Any) -> None:
+        value = self.stage(method, *args)
+        if value is not _IN_PLACE:
+            self.loop.call_soon_threadsafe(self.deliver, value)
 
     def emit(self, token: int) -> None:
-        self._loop.call_soon_threadsafe(self._queue.put_nowait, token)
+        self._send("emit", token)
 
     def finish(self) -> None:
-        self._loop.call_soon_threadsafe(self._queue.put_nowait, _STREAM_DONE)
+        self._send("finish")
 
     def fail(self, exc: BaseException) -> None:
-        self._loop.call_soon_threadsafe(self._queue.put_nowait, exc)
+        self._send("fail", exc)
+
+
+class _FutureSink(_LoopSink):
+    """Buffers emitted tokens; resolves an asyncio future with the full list."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop, future: asyncio.Future) -> None:
+        super().__init__(loop)
+        self._future = future
+        self._tokens: List[int] = []
+
+    def stage(self, method: str, *args: Any) -> Any:
+        if method == "emit":
+            self._tokens.append(args[0])
+            return _IN_PLACE
+        return list(self._tokens) if method == "finish" else args[0]
+
+    def deliver(self, value: Any) -> None:
+        if self._future.done():
+            return
+        if isinstance(value, BaseException):
+            self._future.set_exception(value)
+        else:
+            self._future.set_result(value)
+
+
+class _QueueSink(_LoopSink):
+    """Forwards each token to an asyncio queue as it decodes (streaming)."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop, queue: "asyncio.Queue") -> None:
+        super().__init__(loop)
+        self.deliver = queue.put_nowait
+
+    def stage(self, method: str, *args: Any) -> Any:
+        return _STREAM_DONE if method == "finish" else args[0]
+
+
+def _deliver_batch(items: Sequence[Tuple[_LoopSink, Any]]) -> None:
+    """Loop side of one hand-over: apply its deliveries in order. One that
+    raises is logged and skips none after it."""
+    for sink, value in items:
+        try:
+            sink.deliver(value)
+        except Exception:
+            logger.warning("sink delivery failed on the consumer's loop", exc_info=True)
 
 
 class ContinuousBatcher:
@@ -3877,23 +3918,20 @@ class ContinuousBatcher:
 
     def _dispatch_events(self, events) -> None:
         """Fan one step's events out to their sinks (cancel on dead consumers;
-        engine-terminated requests fail with their structured reason)."""
+        engine-terminated requests fail with their structured reason).
+
+        The bookkeeping runs here, on the worker; what crosses to a consumer's
+        event loop is staged and handed over once a loop, after the events,
+        in their order (one wake-up of the server's thread a step, not one a
+        token). Sinks with no event loop are called in place."""
         self._timeline.enter("fan_out")
+        batches: Dict[Any, List[Tuple[Any, Any]]] = {}
         for event in events:
             sink = self._sinks.get(event.slot)
             if sink is None:
                 continue
             if sink.cancelled:  # consumer abandoned the stream mid-decode
-                del self._sinks[event.slot]
-                meta = self._slot_meta.pop(event.slot, None)
-                if meta is not None:
-                    self._tel_end(meta, "cancelled")
-                # a FINISHED event's slot already retired engine-side — and may
-                # even hold a newly admitted request by the time a pipeline-
-                # flushed event is delivered, so cancelling it would kill the
-                # wrong occupant. Only a still-running slot needs the cancel.
-                if not event.finished:
-                    self._engine.cancel(event.slot)
+                self._drop_consumer(event.slot, event.finished)
                 continue
             if event.error is not None:
                 # the engine terminated this request (NaN quarantine, chunked-
@@ -3906,31 +3944,70 @@ class ContinuousBatcher:
                     self._tel_end(meta, "error", event.error)
                 if self.supervisor is not None:
                     self.supervisor.note_request_failed()
-                self._deliver(
-                    sink, "fail",
+                self._stage(
+                    batches, sink, "fail",
                     EngineFailure(
                         f"request terminated by the engine: {event.error}",
                         reason=event.error,
                     ),
                 )
                 continue
-            ok = True
-            if event.emit:
-                ok = self._deliver(sink, "emit", event.token)
-            if not ok:
-                del self._sinks[event.slot]
-                meta = self._slot_meta.pop(event.slot, None)
-                if meta is not None:
-                    self._tel_end(meta, "cancelled")
-                if not event.finished:
-                    self._engine.cancel(event.slot)
+            if event.emit and not self._stage(batches, sink, "emit", event.token):
+                self._drop_consumer(event.slot, event.finished)
                 continue
             if event.finished:
                 del self._sinks[event.slot]
                 meta = self._slot_meta.pop(event.slot, None)
                 if meta is not None:
                     self._tel_end(meta, "ok")
-                self._deliver(sink, "finish")
+                self._stage(batches, sink, "finish")
+        engine = self._engine
+        for loop, items in batches.items():
+            try:
+                loop.call_soon_threadsafe(_deliver_batch, items)
+            except Exception:
+                # the loop closed since it was staged: its requests end as a
+                # failed delivery would have ended each
+                logger.warning("sink hand-over failed (consumer gone?); dropping its requests")
+                staged = {sink for sink, _ in items}
+                for slot, sink in list(self._sinks.items()):
+                    if sink in staged:
+                        self._drop_consumer(slot, finished=False)
+                continue
+            engine.sink_handoffs += 1
+            engine.sink_deliveries += len(items)
+
+    def _stage(self, batches: Dict[Any, List[Tuple[Any, Any]]], sink: Any, method: str, *args) -> bool:
+        """One sink call of :meth:`_dispatch_events`: in place for a sink with
+        no event loop, else staged in ``batches`` for its loop's hand-over.
+        False where the consumer is gone (a closed loop, a call that raised)."""
+        loop = getattr(sink, "loop", None)
+        if loop is None:
+            return self._deliver(sink, method, *args)
+        value = sink.stage(method, *args)
+        if value is _IN_PLACE:
+            return True
+        items = batches.get(loop)
+        if items is None:
+            if loop.is_closed():
+                logger.warning("sink %s delivery failed (consumer gone?); dropping request", method)
+                return False
+            items = batches[loop] = []
+        items.append((sink, value))
+        return True
+
+    def _drop_consumer(self, slot: int, finished: bool) -> None:
+        """Forget a request whose consumer is gone: sink and meta go, its trace
+        ends "cancelled". A FINISHED event's slot already retired engine-side —
+        and may even hold a newly admitted request by the time a pipeline-
+        flushed event is delivered, so cancelling it would kill the wrong
+        occupant. Only a still-running slot needs the cancel."""
+        del self._sinks[slot]
+        meta = self._slot_meta.pop(slot, None)
+        if meta is not None:
+            self._tel_end(meta, "cancelled")
+        if not finished:
+            self._engine.cancel(slot)
 
     def _run(self) -> None:  # graftlint: hot-path
         while True:
